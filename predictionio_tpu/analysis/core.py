@@ -343,6 +343,13 @@ class _FunctionWalker:
             vchain = (attr_chain(value.func)
                       if isinstance(value, ast.Call) else None) or ()
             for t in targets:
+                if isinstance(t, (ast.Tuple, ast.List)):
+                    # `a, b = f(a, b)` rebinds both names (JAX004's
+                    # donation-hazard window ends here)
+                    for e in t.elts:
+                        if isinstance(e, ast.Name):
+                            self._emit("tuplestore", line, name=e.id)
+                    continue
                 tc = attr_chain(t)
                 if tc and len(tc) == 2 and tc[0] == "self":
                     self._emit("selfstore", line, name=tc[1],

@@ -3,8 +3,8 @@
 "Hot zone" = modules whose path has a ``serving``/``ops``/``guard``
 segment or is ``fold_in.py`` — the code that runs per query or per fold
 tick, where one stray ``.item()`` stalls the dispatch pipeline and one
-uncached ``jax.jit`` recompiles for minutes (BENCH_r01: warmup 231 s vs
-3.9 ms steady-state).
+uncached ``jax.jit`` recompiles for seconds to minutes against
+milliseconds of steady state.
 
 Device-value taint is per-function and syntactic: a local assigned from
 a ``jnp.*``/``jax.*`` call or a known-jitted callable is device-
@@ -43,7 +43,7 @@ JAX003 = register_rule(
     "jax.jit(...) executed inside a function body without a visible "
     "cache (no lru_cache decorator, result not stored in a cache "
     "container). On a per-request or per-tick path this recompiles "
-    "every invocation — minutes of XLA time per BENCH_r01.")
+    "every invocation — seconds to minutes of XLA time each.")
 
 JAX004 = register_rule(
     "JAX004", "donated buffer reused after dispatch",
@@ -458,12 +458,17 @@ def check_jax004(repo: RepoModel) -> List[Finding]:
                 # are safe. Only loads between donation and the next
                 # store of the name are findings.
                 restore = min((s.line for s in fn.events
-                               if s.kind == "store" and s.name == arg.id
+                               if s.kind in ("store", "tuplestore")
+                               and s.name == arg.id
                                and s.line >= ev.line),
                               default=None)
+                # the call's own argument lines are the donation itself,
+                # not a reuse (a multi-line call lists its args below
+                # the line the call starts on)
+                call_end = getattr(call, "end_lineno", None) or ev.line
                 for later in fn.events:
                     if later.kind != "load" or later.name != arg.id \
-                            or later.line <= ev.line:
+                            or later.line <= call_end:
                         continue
                     if restore is not None and later.line > restore:
                         continue

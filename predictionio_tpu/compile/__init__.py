@@ -1,16 +1,14 @@
 """The compile plane (ISSUE 9): kill cold-start.
 
-BENCH_r01 — the only real-TPU capture — put ``warmup_s`` at 231.6
-against ``train_s_per_iteration`` of 0.0039: XLA compilation is ~5
-orders of magnitude above steady-state, and every ``pio deploy``,
+XLA compilation on a TPU costs seconds to minutes per program against
+milliseconds of steady-state execution, and every ``pio deploy``,
 hot-swap, canary stage and rollback used to pay it. This package is
 the subsystem that amortizes it away:
 
 - :mod:`predictionio_tpu.compile.cache` — JAX's persistent compilation
-  cache, managed: a versioned directory under ``base_dir()/xla_cache``
-  whose salt fingerprints the kernel sources (a kernel change rolls
-  the directory, so stale entries never shadow fresh code), plus the
-  ``pio cache {status,clear}`` surface.
+  cache, managed: ``$JAX_COMPILATION_CACHE_DIR`` when set, else the
+  fixed ``<checkout>/.xla_cache``, plus the ``pio cache
+  {status,clear}`` surface.
 - :mod:`predictionio_tpu.compile.buckets` — the shape-bucket ladder:
   next-pow2-style buckets for vocabulary rows, touched-row counts and
   query batch sizes, so growth INSIDE a bucket never changes a traced

@@ -69,7 +69,8 @@ class AOTRegistry:
         #: buckets whose compile failed — never retried this process
         #: (a reliably-failing spec would otherwise respawn a minutes-
         #: long XLA compile on every dispatch miss); the jit fallback
-        #: keeps serving them
+        #: keeps serving them. A deploy-time warm reports them as
+        #: ``failed`` and the deploy fails (serving/server.py).
         self._failed: set = set()
         self._threads: set = set()
         self._jits: Dict[str, Any] = {}
@@ -253,7 +254,7 @@ class AOTRegistry:
         """Compile every (label, dims) in ``specs``; returns a summary
         the caller can log/record. Blocking unless ``background``."""
         t0 = time.perf_counter()
-        compiled = skipped = 0
+        compiled = skipped = failed = 0
         for label, dims in specs:
             if not self.has_spec(label):
                 skipped += 1
@@ -262,7 +263,10 @@ class AOTRegistry:
             self.ensure(label, dims, background=background)
             if not before and self.lookup(label, dims) is not None:
                 compiled += 1
+            elif (label, bucket_key(dims)) in self._failed:
+                failed += 1
         return {"compiled": compiled, "skipped": skipped,
+                "failed": failed,
                 "wallS": round(time.perf_counter() - t0, 4)}
 
     # -- introspection ------------------------------------------------------
@@ -329,11 +333,10 @@ _registry: Optional[AOTRegistry] = None
 
 def _drop_executables_at_exit():
     # held Compiled objects must be released (and in-flight background
-    # compiles joined) BEFORE the jax backend tears down — interpreter-
-    # finalization destruction of the module global after the runtime
-    # is gone segfaults, and a daemon compile thread killed mid-XLA
-    # aborts (both observed on jaxlib 0.4.x CPU at aot_smoke.sh exit).
-    # atexit runs pre-finalization, before jax's own handlers unwind.
+    # compiles joined) BEFORE the jax backend tears down: a Compiled
+    # destructed after the runtime is gone, or a daemon compile thread
+    # killed mid-XLA, takes the process down at exit. atexit runs
+    # pre-finalization, before jax's own handlers unwind.
     try:
         if _registry is not None:
             _registry.shutdown()
@@ -400,14 +403,16 @@ def warm_models(algorithms, models, batch_hint: int = 16,
     """Warm the serving executables for a (algorithms, models) pair —
     the deploy/hot-swap/canary hook. Each algorithm exposing
     ``aot_warm_specs(model, batch_hint)`` contributes (label, dims)
-    rows; everything is fail-soft (a warm failure must never block a
-    swap — the fallback path still serves)."""
+    rows. Nothing here raises (a warm failure must never block a swap
+    — the fallback path still serves); what did not compile is counted
+    in ``failed``, which the deploy-time load treats as fatal."""
     if not aot_enabled() or not warm_enabled():
-        return {"compiled": 0, "skipped": 0, "wallS": 0.0,
+        return {"compiled": 0, "skipped": 0, "failed": 0, "wallS": 0.0,
                 "disabled": True}
     from predictionio_tpu.compile.cache import enable_persistent_cache
     enable_persistent_cache()
     specs: List[Tuple[str, Dict[str, int]]] = []
+    hook_failures = 0
     for algo, model in zip(algorithms, models):
         hook = getattr(algo, "aot_warm_specs", None)
         if hook is None:
@@ -415,8 +420,10 @@ def warm_models(algorithms, models, batch_hint: int = 16,
         try:
             specs.extend(hook(model, batch_hint))
         except Exception:
+            hook_failures += 1
             logger.warning("aot_warm_specs failed for %s",
                            type(algo).__name__, exc_info=True)
     out = get_aot().warm(specs, background=background)
+    out["failed"] += hook_failures
     out["specs"] = len(specs)
     return out

@@ -5,16 +5,14 @@ deserialize it in any later process whose computation hashes the same
 (``jax_compilation_cache_dir``). This module owns that cache for the
 whole product:
 
-- **Location** — ``<root>/<salt>/`` where root is
-  ``$PIO_XLA_CACHE_DIR`` or ``base_dir()/xla_cache``; an explicit
-  ``$JAX_COMPILATION_CACHE_DIR`` wins outright (operator override,
-  unsalted — they own its lifecycle). ``PIO_XLA_CACHE=off`` disables.
-- **Salt** — a fingerprint of the kernel sources (``ops/*.py``,
-  ``online/fold_in.py``, ``compile/aot.py``) plus the jax version.
-  JAX's own cache key already hashes the exact computation, so a stale
-  entry can never be *wrong* — the salt keeps the lifecycle clean: a
-  kernel change rolls the directory, ``pio cache clear`` removes dead
-  salts, and disk growth is bounded by live-kernel programs.
+- **Location** — one rule, placeable from outside. If
+  ``$JAX_COMPILATION_CACHE_DIR`` is set, that directory, exactly, is
+  the cache and no code path sets another (an ``enable_persistent_cache
+  (root=...)`` argument loses to it). If it is unset, the cache is
+  ``<checkout>/.xla_cache`` — a fixed path that does not depend on
+  ``PIO_FS_BASEDIR``, ``$HOME``, the pid or any temp name, because the
+  directory is part of JAX's cache key and a cache that moves never
+  hits. ``PIO_XLA_CACHE=off`` disables.
 - **Thresholds** — min-compile-time and min-entry-size are zeroed:
   the serve/fold programs this repo cares about are small and fast to
   compile on CPU but minutes on TPU; caching everything costs little
@@ -30,9 +28,9 @@ jax's first use — config updates apply to every later compile.
 
 from __future__ import annotations
 
-import hashlib
 import logging
 import os
+import shutil
 import threading
 from typing import Dict, Optional
 
@@ -40,11 +38,11 @@ logger = logging.getLogger(__name__)
 
 _lock = threading.Lock()
 _enabled_dir: Optional[str] = None
-_salt_memo: Optional[str] = None
 
-#: modules whose source changes must roll the cache directory — the
-#: files that define traced programs (keep in sync with the docstring)
-_KERNEL_GLOBS = ("ops", "online/fold_in.py", "compile/aot.py")
+#: the in-checkout default (listed in .gitignore)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".xla_cache")
 
 
 def cache_disabled() -> bool:
@@ -52,82 +50,40 @@ def cache_disabled() -> bool:
         "off", "0", "false", "no")
 
 
-def cache_root() -> str:
-    env = os.environ.get("PIO_XLA_CACHE_DIR")
-    if env:
-        return env
-    from predictionio_tpu.data.storage.registry import base_dir
-    return os.path.join(base_dir(), "xla_cache")
-
-
-def _kernel_files():
-    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for rel in _KERNEL_GLOBS:
-        p = os.path.join(pkg, rel)
-        if os.path.isdir(p):
-            for name in sorted(os.listdir(p)):
-                if name.endswith(".py"):
-                    yield os.path.join(p, name)
-        elif os.path.isfile(p):
-            yield p
-
-
-def cache_salt() -> str:
-    """12-hex fingerprint of the kernel sources + jax version. Memoized
-    — the sources cannot change under a running process."""
-    global _salt_memo
-    if _salt_memo is not None:
-        return _salt_memo
-    h = hashlib.sha256()
-    try:
-        import jax
-        h.update(jax.__version__.encode())
-    except Exception:
-        pass
-    for path in _kernel_files():
-        h.update(os.path.basename(path).encode())
-        try:
-            with open(path, "rb") as f:
-                h.update(f.read())
-        except OSError:
-            continue
-    _salt_memo = h.hexdigest()[:12]
-    return _salt_memo
+def cache_dir(root: Optional[str] = None) -> str:
+    """Where the cache lives: ``$JAX_COMPILATION_CACHE_DIR`` when set,
+    else ``root`` (tests pointing at their own tmp dir), else the
+    in-checkout default."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR") or root
+            or DEFAULT_CACHE_DIR)
 
 
 def enable_persistent_cache(root: Optional[str] = None) -> Optional[str]:
-    """Point jax at the salted persistent cache directory. Idempotent;
-    returns the active directory, or None when disabled/unavailable.
-    An explicit ``JAX_COMPILATION_CACHE_DIR`` is honored as-is."""
+    """Point jax at the persistent cache directory (:func:`cache_dir`).
+    Idempotent; returns the active directory, or None when disabled."""
     global _enabled_dir
     if cache_disabled():
         return None
-    if _enabled_dir is not None and root is None:
+    target = cache_dir(root)
+    if _enabled_dir == target:
         return _enabled_dir
-    # salt hashing and mkdir are file I/O — do them before taking the
-    # lock (first callers race harmlessly: same dir, idempotent config)
-    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if env_dir and root is None:
-        cache_dir = env_dir
-    else:
-        cache_dir = os.path.join(root or cache_root(), cache_salt())
     try:
-        os.makedirs(cache_dir, exist_ok=True)
-        import jax
-        with _lock:
-            if _enabled_dir is not None and root is None:
-                return _enabled_dir
+        os.makedirs(target, exist_ok=True)
+    except OSError as e:
+        logger.warning("compile cache dir %s unusable (%s); compiling "
+                       "without a persistent cache", target, e)
+        return None
+    import jax
+    from jax._src import compilation_cache as _cc
+    with _lock:
+        if _enabled_dir != target:
             # jax latches cache usability at the FIRST compile of the
             # process (and the directory at first initialization): a
-            # process that already compiled before this call — or a dir
-            # change from tests/operator re-point — must reset, or the
-            # new configuration is silently ignored
-            try:
-                from jax._src import compilation_cache as _cc
-                _cc.reset_cache()
-            except Exception:
-                logger.debug("cache reset unavailable", exc_info=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
+            # process that already compiled before this call — or a
+            # test re-pointing the directory — must reset, or the new
+            # configuration is silently ignored
+            _cc.reset_cache()
+            jax.config.update("jax_compilation_cache_dir", target)
             # cache EVERYTHING: the serve/fold programs are small on
             # CPU (the test container) but minutes of XLA on TPU, and
             # the acceptance tests measure the same code path on both
@@ -135,11 +91,7 @@ def enable_persistent_cache(root: Optional[str] = None) -> Optional[str]:
                 "jax_persistent_cache_min_compile_time_secs", 0.0)
             jax.config.update(
                 "jax_persistent_cache_min_entry_size_bytes", -1)
-            _enabled_dir = cache_dir
-    except Exception:
-        logger.debug("persistent compile cache unavailable",
-                     exc_info=True)
-        return None
+            _enabled_dir = target
     # per-executable hit/miss attribution rides costmon's label
     from predictionio_tpu.obs import costmon
     costmon.install()
@@ -154,23 +106,15 @@ def disable_persistent_cache() -> None:
     with _lock:
         if _enabled_dir is None:
             return
-        try:
-            import jax
-            jax.config.update("jax_compilation_cache_dir", None)
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:
-            logger.debug("persistent cache disable failed",
-                         exc_info=True)
+        import jax
+        from jax._src import compilation_cache as _cc
+        jax.config.update("jax_compilation_cache_dir", None)
+        _cc.reset_cache()
         _enabled_dir = None
 
 
 def persistent_cache_enabled() -> bool:
     return _enabled_dir is not None
-
-
-def active_cache_dir() -> Optional[str]:
-    return _enabled_dir
 
 
 def _dir_stats(path: str):
@@ -190,61 +134,29 @@ def _dir_stats(path: str):
 def cache_status() -> Dict:
     """Operator view for ``pio cache status`` / ``/stats.json``."""
     from predictionio_tpu.obs import costmon
+    totals = costmon.pcache_totals()
     out = {
         "enabled": persistent_cache_enabled(),
         "disabledByEnv": cache_disabled(),
         "dir": _enabled_dir,
-        "root": None if cache_disabled() else cache_root(),
-        "salt": cache_salt(),
         "entries": 0,
         "bytes": 0,
-        "hits": costmon.pcache_totals()["hits"],
-        "misses": costmon.pcache_totals()["misses"],
+        "hits": totals["hits"],
+        "misses": totals["misses"],
     }
     if _enabled_dir:
         out["entries"], out["bytes"] = _dir_stats(_enabled_dir)
-    # dead salts left behind by kernel changes (pio cache clear --all
-    # removes them)
-    root = out["root"]
-    if root and os.path.isdir(root):
-        out["staleSalts"] = sorted(
-            d for d in os.listdir(root)
-            if d != cache_salt()
-            and os.path.isdir(os.path.join(root, d)))
     return out
 
 
-def clear_cache(all_salts: bool = False) -> Dict:
-    """Remove cached executables. Default scope is the ACTIVE salt
-    directory (safe while processes run — jax re-creates entries on
-    the next compile); ``all_salts`` also removes dead-salt dirs."""
-    import shutil
-    removed = 0
-    nbytes = 0
-    targets = []
-    active = _enabled_dir or (
-        None if cache_disabled()
-        else os.path.join(cache_root(), cache_salt()))
-    if active and os.path.isdir(active):
-        targets.append(active)
-    if all_salts:
-        root = cache_root()
-        if os.path.isdir(root):
-            for d in sorted(os.listdir(root)):
-                p = os.path.join(root, d)
-                if os.path.isdir(p) and p not in targets:
-                    targets.append(p)
-    for t in targets:
-        e, b = _dir_stats(t)
-        removed += e
-        nbytes += b
-        try:
-            shutil.rmtree(t)
-            if t == active:
-                # the live process keeps writing here: re-create it
-                os.makedirs(t, exist_ok=True)
-        except OSError:
-            logger.warning("pio cache clear: could not remove %s", t,
-                           exc_info=True)
-    return {"removed": removed, "bytes": nbytes,
-            "dirs": [t for t in targets]}
+def clear_cache() -> Dict:
+    """Remove the cached executables (safe while processes run — jax
+    re-creates entries on the next compile)."""
+    target = _enabled_dir or (None if cache_disabled() else cache_dir())
+    removed = nbytes = 0
+    if target and os.path.isdir(target):
+        removed, nbytes = _dir_stats(target)
+        shutil.rmtree(target)
+        # a live process keeps writing here: re-create it
+        os.makedirs(target, exist_ok=True)
+    return {"removed": removed, "bytes": nbytes, "dir": target}

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import logging
 import pickle
+import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Type
 
@@ -64,6 +65,9 @@ class EngineParams:
 class TrainResult:
     models: List[Any]                # one per algorithm
     algorithms: List[Algorithm]      # the instances that trained them
+    # wall seconds of the read / prepare / train stages of Engine.train
+    # (empty for a prepare_deploy restore)
+    stage_seconds: Dict[str, float] = field(default_factory=dict)
 
 
 def _params_class_of(cls) -> Optional[Type[Params]]:
@@ -124,18 +128,24 @@ class Engine:
     def train(self, engine_params: EngineParams,
               workflow_params: WorkflowParams = WorkflowParams()) -> TrainResult:
         check = not workflow_params.skip_sanity_check
+        stage_seconds = {}
+        t0 = time.perf_counter()
         data_source = self.make_data_source(engine_params)
         td = data_source.read_training()
         run_sanity_check(td, check)
+        stage_seconds["read"] = time.perf_counter() - t0
         if workflow_params.stop_after_read:
             raise StopAfterReadInterruption()
 
+        t0 = time.perf_counter()
         preparator = self.make_preparator(engine_params)
         pd = preparator.prepare(td)
         run_sanity_check(pd, check)
+        stage_seconds["prepare"] = time.perf_counter() - t0
         if workflow_params.stop_after_prepare:
             raise StopAfterPrepareInterruption()
 
+        t0 = time.perf_counter()
         algorithms = self.make_algorithms(engine_params)
         models = []
         for i, algo in enumerate(algorithms):
@@ -144,7 +154,9 @@ class Engine:
             model = algo.train(pd)
             run_sanity_check(model, check)
             models.append(model)
-        return TrainResult(models=models, algorithms=algorithms)
+        stage_seconds["train"] = time.perf_counter() - t0
+        return TrainResult(models=models, algorithms=algorithms,
+                           stage_seconds=stage_seconds)
 
     # -- eval (Engine.scala:726-816) ---------------------------------------
     def eval(self, engine_params: EngineParams,

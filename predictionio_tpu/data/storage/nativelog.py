@@ -54,9 +54,8 @@ _LIB = None
 #: path calls (small group appends, flush). A CDLL call releases the
 #: GIL and must re-acquire it on return — under 8 concurrent writers
 #: that handoff costs ~1 ms per call (measured), dwarfing the ~90 us
-#: of C work and inverting the concurrent-vs-serial ordering
-#: (BENCH_r05). Holding the GIL for a sub-100 us append is cheaper for
-#: everyone. Long calls (bulk blocks, scans) stay on _LIB. Safe
+#: of C work and inverting the concurrent-vs-serial ordering.
+#: Holding the GIL for a sub-100 us append is cheaper for everyone. Long calls (bulk blocks, scans) stay on _LIB. Safe
 #: because the Python wrapper serializes per-handle access with its
 #: own locks, so a GIL-holding call never waits on the C mutex.
 _PYLIB = None
@@ -570,8 +569,8 @@ class _GroupCommitter:
     Group formation is natural: records accumulate while the current
     leader commits, so a lone writer commits inline at single-insert
     latency (no thread handoff) while concurrent writers batch
-    automatically instead of convoying on the append lock (BENCH_r05's
-    concurrent-8 < serial regression). fsync rides the
+    automatically instead of convoying on the append lock (which made
+    8 concurrent writers slower than one serial writer). fsync rides the
     PIO_INGEST_GROUP_COMMIT_MS cadence (see _group_commit_ms); the ack
     itself only ever waits for the group's flush."""
 
@@ -982,8 +981,8 @@ class NativeLogEvents(base.Events):
         self._fsync_loop: Optional[_FsyncLoop] = None
         # contention probe (ISSUE 6): writer wait on the per-handle
         # lock, as pio_lock_wait_seconds{lock=nativelog_append} — the
-        # instrument that localizes BENCH_r05's concurrent-8 ingest
-        # regression (slower than serial) to this lock or below it
+        # instrument that localizes a concurrent-8 ingest slower than
+        # serial to this lock or below it
         self._append_lock_wait = lock_probe("nativelog_append")
         # (in-flight single-event writers are counted PER COMMITTER —
         # _GroupCommitter.writers — so a partitioned store's formation

@@ -1,8 +1,8 @@
 """Per-executable compile/cost attribution (extends obs/jaxmon).
 
 ISSUE 6 tentpole piece 3. jaxmon counts compiles process-wide; that
-tells an operator THAT the 231.6 s warmup (BENCH_r01) exists, not
-where it goes. This module attributes compile wall time to a stable
+tells an operator THAT a warm-up of minutes exists, not where it
+goes. This module attributes compile wall time to a stable
 **executable label** — the handful of jitted programs the system
 actually runs (``als_sweep``, ``fold_side``, ``batch_predict``,
 ``gates_probe``) — which is the evidence base for the AOT/compile-
@@ -421,9 +421,9 @@ def device_timed(label: str, fn, *args):
             # fallback — the backend_compile listener fired on this
             # thread): the wall is compile, not steady-state device
             # time, and extrapolating it by N would poison the
-            # attribution for the process lifetime (BENCH_r01: one
-            # compile is ~5 orders over an iteration). Skip the
-            # estimate — the next sampled dispatch is warm.
+            # attribution for the process lifetime (one compile is
+            # orders of magnitude over a steady-state dispatch). Skip
+            # the estimate — the next sampled dispatch is warm.
             return out
         est = wall * st.every
         st.device_s.inc(est)

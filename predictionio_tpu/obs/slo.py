@@ -30,9 +30,9 @@ Also home to the **lock-wait contention probes**
 cached per-label histogram child and ``timed_acquire`` wraps a lock
 acquisition in two ``perf_counter`` reads — cheap enough for the
 nativelog append path and the micro-batcher's admission lock, the two
-suspects in BENCH_r05's concurrent-8 ingest regression (1,994 vs
-2,604 ev/s serial): the histogram localizes whether writers queue on
-the Python handle lock or below it.
+suspects when 8 concurrent ingest writers run slower than one serial
+writer: the histogram localizes whether writers queue on the Python
+handle lock or below it.
 """
 
 from __future__ import annotations

@@ -203,10 +203,7 @@ def begin_fetch(*arrays, tenant: Optional[str] = None
     for a in arrays:
         start = getattr(a, "copy_to_host_async", None)
         if start is not None:
-            try:
-                start()
-            except Exception:
-                pass  # backend without async d2h: wait() still works
+            start()
     submit_s = time.perf_counter() - t0
 
     def wait() -> Tuple[np.ndarray, ...]:

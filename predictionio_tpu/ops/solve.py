@@ -39,14 +39,6 @@ import functools
 import numpy as np
 
 
-def _tpu_compiler_params(**kw):
-    """pltpu.CompilerParams across jax versions (older: TPUCompilerParams)."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
-
-
 def _schulz_iters_default(rank: int) -> int:
     # quadratic convergence: error after k steps ~ (1 - 1/kappa)^(2^k);
     # 18 doublings resolve kappa ~ 1e4 to f32 eps with margin
@@ -276,7 +268,7 @@ def cg_solve_pallas(A, b, iters: int = 48, tile: int = 16):
         ],
         out_specs=pl.BlockSpec((tile, rank), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
     )(A.astype(jnp.float32), b)
     return x[:B]
@@ -449,7 +441,7 @@ def cholesky_solve_pallas(A, b, tile: int = 8, panel: int = 8,
         ],
         out_specs=pl.BlockSpec((tile, R2), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
     )(A.astype(jnp.float32), b)

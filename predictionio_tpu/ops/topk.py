@@ -20,15 +20,6 @@ import numpy as np
 from predictionio_tpu.parallel.mesh import MeshContext, current_mesh
 
 
-def _shard_map():
-    try:
-        from jax import shard_map
-        return shard_map, {"check_vma": False}
-    except ImportError:   # jax < 0.5 spelling (and check_rep keyword)
-        from jax.experimental.shard_map import shard_map
-        return shard_map, {"check_rep": False}
-
-
 def sharded_top_k(item_factors_sharded, query_vec, k: int,
                   mesh: Optional[MeshContext] = None,
                   allowed_mask_sharded=None
@@ -38,7 +29,6 @@ def sharded_top_k(item_factors_sharded, query_vec, k: int,
     """
     import jax
     import jax.numpy as jnp
-    shard_map, _vma_kw = _shard_map()
     from jax.sharding import PartitionSpec as P
 
     mesh = mesh or current_mesh()
@@ -53,10 +43,10 @@ def sharded_top_k(item_factors_sharded, query_vec, k: int,
     k_final = min(k, mp * k_local)
 
     @functools.partial(
-        shard_map, mesh=mesh.mesh,
+        jax.shard_map, mesh=mesh.mesh,
         in_specs=(P("model", None), P(), P("model")),
         out_specs=(P(), P()),
-        **_vma_kw)
+        check_vma=False)
     def _local_then_global(v_shard, q, mask_shard):
         scores = jnp.einsum("ir,r->i", v_shard, q,
                             preferred_element_type=jnp.float32)
@@ -127,16 +117,15 @@ def make_batched_sharded_topk(mesh: MeshContext, k_local: int,
     import jax.numpy as jnp
     from predictionio_tpu.compile.aot import get_aot
 
-    shard_map, vma_kw = _shard_map()
     P = jax.sharding.PartitionSpec
     in_specs = [P(), P("model", None), P()]
     if has_mask:
         in_specs.append(P(None, "model"))
     out_specs = P() if pack else (P(), P())
 
-    @functools.partial(shard_map, mesh=mesh.mesh,
+    @functools.partial(jax.shard_map, mesh=mesh.mesh,
                        in_specs=tuple(in_specs), out_specs=out_specs,
-                       **vma_kw)
+                       check_vma=False)
     def _kernel(q, v_shard, n_items, *mask):
         scores = jnp.einsum("br,ir->bi", q, v_shard,
                             preferred_element_type=jnp.float32)

@@ -39,6 +39,7 @@ from predictionio_tpu.obs import (FLIGHT, MetricsRegistry, SLOEngine,
                                   traces_response)
 from predictionio_tpu.obs.slowlog import (capture_slow_query,
                                           slow_threshold_s)
+from predictionio_tpu.parallel.mesh import device_platform, device_stats
 from predictionio_tpu.serving.plugins import EngineServerPluginContext
 from predictionio_tpu.utils.http import (HttpServer, Request, Response,
                                          Router)
@@ -137,6 +138,9 @@ class EngineServer:
             from predictionio_tpu.obs.tenantctx import register_tenant
             register_tenant(self.tenant)
         self._lock = threading.RLock()
+        # the one backend initialization of this process: anything but
+        # a TPU is an error unless JAX_PLATFORMS=cpu says otherwise
+        self.device = device_platform()
         # multi-process mesh serving: under a >1-process JAX mesh every
         # process must run each query's SPMD program, so the primary
         # broadcasts payloads and workers mirror the pipeline
@@ -190,6 +194,7 @@ class EngineServer:
         # collectors at scrape time; latency distributions are native
         # registry histograms.
         jaxmon.install()
+        jaxmon.install_device_memory_gauge()
         self.metrics = MetricsRegistry(parent=get_registry())
         self._h_query = self.metrics.histogram(
             "pio_engine_query_seconds",
@@ -455,8 +460,10 @@ class EngineServer:
             self.result_cache.invalidate_all("reload")
         # compile plane (ISSUE 9): AOT-compile the serving executables
         # at deploy time — outside the serving lock (an in-flight query
-        # during /reload keeps answering from the jit path meanwhile)
-        self._warm_aot(self.models, instance.id)
+        # during /reload keeps answering from the jit path meanwhile).
+        # The FIRST load is the deploy: a bucket that does not compile
+        # there fails the deploy; a /reload stays fail-soft.
+        self._warm_aot(self.models, instance.id, strict=not was_loaded)
         self._arm_swap_marker(instance.id, models_token=self.models)
         FLIGHT.record("hot_swap" if was_loaded else "model_load",
                       model_version=instance.id, source="load")
@@ -472,12 +479,15 @@ class EngineServer:
         return device_cache.tenant_scope(self.tenant)
 
     # -- compile plane (ISSUE 9) --------------------------------------------
-    def _warm_aot(self, models, version: Optional[str]):
+    def _warm_aot(self, models, version: Optional[str],
+                  strict: bool = False):
         """AOT-compile the serving executables for ``models`` BEFORE
         they take a request (the caller — scheduler publish thread,
         canary stage, deploy load — pays the compile, never a query).
-        Fail-soft: a warm failure leaves the jit fallback path serving
-        correctly."""
+        Hot-swaps are fail-soft: a warm failure leaves the jit fallback
+        path serving correctly. ``strict`` (the deploy-time load) turns
+        any failed bucket into an error — a server whose executables do
+        not compile on this device must not come up looking healthy."""
         try:
             from predictionio_tpu.compile.aot import warm_models
             with self._tenant_cm():
@@ -491,8 +501,18 @@ class EngineServer:
                                  ("compiled", "skipped", "wallS")
                                  if k in summary})
         except Exception:
+            if strict:
+                raise
             logger.warning("AOT warm failed; serving falls back to "
                            "jit dispatch", exc_info=True)
+            return
+        if strict and summary.get("failed"):
+            raise RuntimeError(
+                f"deploy-time AOT warm: {summary['failed']} serving "
+                f"executable bucket(s) failed to compile on "
+                f"{self.device['platform']} "
+                f"({self.device['device_kind']}); see the warnings "
+                f"above for the failing specs")
 
     def _arm_swap_marker(self, version: Optional[str],
                          candidate_only: bool = False,
@@ -1234,7 +1254,13 @@ class EngineServer:
             self._apply_canary_decision()
         with self._lock:
             n = self.request_count
+            trained = getattr(self.engine_instance, "env", None) or {}
             out = {
+                # the device THIS process computes on, as JAX reported
+                # it at start-up, and what trained the loaded model
+                **device_stats(),
+                "solver": trained.get("solver"),
+                "computeDtype": trained.get("compute_dtype"),
                 "requestCount": n,
                 "avgServingSec": self.serving_seconds / n if n else 0.0,
                 "lastServingSec": self.last_serving_sec,

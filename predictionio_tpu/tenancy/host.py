@@ -36,6 +36,7 @@ from typing import Dict, Optional
 from predictionio_tpu.obs import FLIGHT, MetricsRegistry, fleet, \
     get_registry
 from predictionio_tpu.obs.tenantctx import register_tenant, tenant_scope
+from predictionio_tpu.parallel.mesh import device_platform, device_stats
 from predictionio_tpu.serving.server import EngineServer, ServerConfig
 from predictionio_tpu.tenancy import props as tenant_props
 from predictionio_tpu.tenancy.auth import AccessKeyGate, auth_enabled
@@ -182,6 +183,9 @@ class HostConfig:
 class ServingHost:
     def __init__(self, config: Optional[HostConfig] = None):
         self.config = config or HostConfig()
+        # a host owns the device for all its tenants: fail at
+        # construction, not at the first admit, when it cannot have it
+        device_platform()
         self._lock = threading.RLock()
         self.slots: Dict[str, TenantSlot] = {}
         self.start_time = time.time()
@@ -473,6 +477,7 @@ class ServingHost:
             total = sum(s.requests for s in self.slots.values())
         out = {
             "role": "serving_host",
+            **device_stats(),
             "startTime": self.start_time,
             "requestCount": total,
             "tenants": self._tenants_block(),

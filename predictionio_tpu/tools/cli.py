@@ -53,13 +53,7 @@ def cmd_status(args) -> int:
         _print(f"  {repo}: {'OK' if ok else 'FAILED'} "
                f"({Storage.config_summary().get(repo, '?')})")
     _print("Inspecting device mesh...")
-    try:
-        import jax
-        devices = jax.devices()
-        _print(f"  {len(devices)} device(s): "
-               f"{[d.platform + ':' + str(d.id) for d in devices]}")
-    except Exception as e:
-        _print(f"  device init failed: {e}")
+    if not _print_devices(args):
         return 1
     if getattr(args, "telemetry", False):
         _print_telemetry(args)
@@ -69,6 +63,48 @@ def cmd_status(args) -> int:
         _print("Your system is all ready to go.")
         return 0
     return 1
+
+
+_DEVICE_PROBE = (
+    "import json; "
+    "from predictionio_tpu.parallel.mesh import device_platform; "
+    "print(json.dumps(device_platform()))")
+
+
+def _print_devices(args) -> bool:
+    """`pio status` owns no device (main() pinned it to the CPU), so it
+    reports the chip without taking it: a live engine server says what
+    it holds through /stats.json; with none listening, a short-lived
+    child resolves the platform and exits, releasing the chip again."""
+    import os
+    import subprocess
+    from predictionio_tpu.utils.http import fetch_json
+    ip = getattr(args, "ip", None) or "127.0.0.1"
+    port = getattr(args, "engine_port", 8000)
+    st = fetch_json(f"http://{ip}:{port}/stats.json")
+    if "platform" in st:
+        _print(f"  held by the engine server at {ip}:{port} "
+               f"(pid {st.get('pid')}): {st.get('deviceCount')} x "
+               f"{st.get('deviceKind')} [{st['platform']}]")
+        return True
+    # `python -c` puts its cwd on sys.path: run it from the checkout
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    try:
+        probe = subprocess.run(
+            [sys.executable, "-c", _DEVICE_PROBE], cwd=repo,
+            capture_output=True, text=True, timeout=120)
+    except subprocess.TimeoutExpired:
+        _print("  device probe timed out after 120s")
+        return False
+    if probe.returncode != 0:
+        tail = (probe.stderr.strip().splitlines() or ["no output"])[-1]
+        _print(f"  device init failed: {tail}")
+        return False
+    dev = json.loads(probe.stdout.strip().splitlines()[-1])
+    _print(f"  {dev['n']} x {dev['device_kind']} [{dev['platform']}] "
+           f"(free: no engine server at {ip}:{port})")
+    return True
 
 
 def _status_targets(args):
@@ -163,7 +199,7 @@ def _print_telemetry(args) -> None:
         if xc:
             _print(f"  xlaCache: entries={xc.get('entries')} "
                    f"hits={xc.get('hits')} misses={xc.get('misses')} "
-                   f"salt={xc.get('salt')}")
+                   f"dir={xc.get('dir')}")
         if st.get("swapToFirstQueryMs") is not None:
             _print(f"  swapToFirstQuery="
                    f"{st['swapToFirstQueryMs']:.1f}ms")
@@ -316,28 +352,45 @@ def _serve_foreground(server, label: str) -> int:
     return 0
 
 
+def _stop_stale_server(ip: str, port: int, wait_s: float = 30.0) -> None:
+    """Undeploy a stale server occupying the target port, as the
+    reference MasterActor does (CreateServer.scala:288-310), and WAIT
+    for its listener to go away: the stale server is the process
+    holding the chip, so the new one must not touch JAX before the old
+    one is gone."""
+    import http.client
+    import socket
+    import time
+    try:
+        req = urllib.request.Request(f"http://{ip}:{port}/stop",
+                                     method="POST", data=b"")
+        urllib.request.urlopen(req, timeout=3).read()
+    except (OSError, http.client.HTTPException):
+        return  # nothing listening (or not ours): nothing to stop
+    _print(f"Undeployed a stale engine server on port {port}.")
+    deadline = time.monotonic() + wait_s
+    while time.monotonic() < deadline:
+        try:
+            socket.create_connection((ip, port), timeout=1).close()
+        except OSError:
+            return
+        time.sleep(0.2)
+    raise RuntimeError(
+        f"stale engine server on {ip}:{port} acknowledged /stop but is "
+        f"still listening after {wait_s:g}s; it still holds the chip")
+
+
 def cmd_deploy(args) -> int:
+    import os
+    # primary only (mesh workers own no port, and probing from every
+    # process could kill a peer's live server) — decided from the
+    # launch environment, BEFORE the first JAX call
+    if int(os.environ.get("PIO_PROCESS_ID", "0") or 0) == 0:
+        _stop_stale_server(
+            args.ip if args.ip != "0.0.0.0" else "127.0.0.1", args.port)
     from predictionio_tpu.parallel.mesh import init_distributed
     from predictionio_tpu.serving import EngineServer, ServerConfig
     init_distributed()  # no-op unless PIO_COORDINATOR/... are set
-    import jax
-    is_primary = jax.process_index() == 0
-    # undeploy a stale server occupying the target port first, as the
-    # reference MasterActor does (CreateServer.scala:288-310) — primary
-    # only: mesh workers own no port, and probing from every process
-    # could kill a peer's live server
-    if is_primary:
-        try:
-            stop_ip = args.ip if args.ip != "0.0.0.0" else "127.0.0.1"
-            req = urllib.request.Request(
-                f"http://{stop_ip}:{args.port}/stop", method="POST",
-                data=b"")
-            urllib.request.urlopen(req, timeout=3).read()
-            _print(f"Undeployed a stale engine server on port {args.port}.")
-            import time
-            time.sleep(1)
-        except Exception:
-            pass
     config = ServerConfig(
         ip=args.ip, port=args.port,
         engine_instance_id=args.engine_instance_id,
@@ -381,12 +434,30 @@ def cmd_update(args) -> int:
 
     # resolve engine + latest model exactly like deploy does, without
     # starting an HTTP frontend (EngineServer is the loader)
-    loader = EngineServer(ServerConfig(
-        ip="127.0.0.1", port=0,
-        engine_id=args.engine_id or "default",
-        engine_version=args.engine_version or "0",
-        engine_variant=args.engine_json,
-        micro_batch=0))
+    from predictionio_tpu.parallel.mesh import DeviceUnavailable
+    try:
+        loader = EngineServer(ServerConfig(
+            ip="127.0.0.1", port=0,
+            engine_id=args.engine_id or "default",
+            engine_version=args.engine_version or "0",
+            engine_variant=args.engine_json,
+            micro_batch=0))
+    except DeviceUnavailable as e:
+        # `pio update` folds on the device in ITS OWN process; on a
+        # one-chip machine the deployed server already holds the chip
+        from predictionio_tpu.utils.http import fetch_json
+        _print(f"pio update cannot get a device: {e}")
+        st = fetch_json(
+            f"http://{args.engine_ip}:{args.engine_port}/stats.json")
+        if st.get("platform") == "tpu":
+            _print(f"The engine server at {args.engine_ip}:"
+                   f"{args.engine_port} (pid {st.get('pid')}) holds "
+                   f"{st.get('deviceCount')} x {st.get('deviceKind')}. "
+                   "On a one-chip machine the fold runs inside the "
+                   "serving process: admit the engine as a tenant of "
+                   "tenancy.ServingHost with a scheduler "
+                   "(docs/operations.md, \"Who owns the chip\").")
+        return 1
     loader.load()
     _, ds_params = loader.engine_params.data_source_params
     app_name = args.app_name or getattr(ds_params, "app_name", None)
@@ -774,6 +845,8 @@ def cmd_bootstrap(args) -> int:
 def cmd_run(args) -> int:
     """(Console run — execute a main class/module in the pio environment)"""
     import runpy
+    from predictionio_tpu.parallel.mesh import device_platform
+    device_platform()
     sys.argv = [args.main_py] + (args.args or [])
     runpy.run_path(args.main_py, run_name="__main__")
     return 0
@@ -1123,11 +1196,11 @@ def cmd_lint(args) -> int:
 
 def cmd_cache(args) -> int:
     """`pio cache {status,clear}` (ISSUE 9): the persistent XLA compile
-    cache under base_dir()/xla_cache/<salt>. `status` reports the
-    active salted directory, entry count/bytes, dead-salt dirs left by
-    kernel changes, and the process's hit/miss counters; `clear`
-    removes the active salt's entries (safe live — jax re-creates them
-    on the next compile), `clear --all` also removes dead salts."""
+    cache — `$JAX_COMPILATION_CACHE_DIR` when set, else
+    `<checkout>/.xla_cache`. `status` reports the directory, entry
+    count/bytes and the process's hit/miss counters; `clear` removes
+    the entries (safe live — jax re-creates them on the next
+    compile)."""
     import json as _json
     from predictionio_tpu.compile.cache import (cache_status, clear_cache,
                                                 enable_persistent_cache)
@@ -1136,7 +1209,7 @@ def cmd_cache(args) -> int:
         _print(_json.dumps(cache_status(), indent=2, default=str))
         return 0
     if args.cache_cmd == "clear":
-        out = clear_cache(all_salts=args.all)
+        out = clear_cache()
         _print(_json.dumps(out))
         return 0
     _print("cache command must be status|clear")
@@ -1702,14 +1775,12 @@ def build_parser() -> argparse.ArgumentParser:
 
     ca = sub.add_parser(
         "cache", help="persistent XLA compile cache (ISSUE 9): the "
-        "salted executable store under base_dir()/xla_cache that makes "
-        "warmup compiles a once-per-machine cost")
+        "executable store at $JAX_COMPILATION_CACHE_DIR (default "
+        "<checkout>/.xla_cache) that makes warmup compiles a "
+        "once-per-machine cost")
     casub = ca.add_subparsers(dest="cache_cmd", required=True)
     casub.add_parser("status")
-    cacl = casub.add_parser("clear")
-    cacl.add_argument("--all", action="store_true",
-                      help="also remove dead-salt directories left by "
-                           "kernel changes")
+    casub.add_parser("clear")
     ca.set_defaults(func=cmd_cache)
 
     tn = sub.add_parser(
@@ -1875,11 +1946,21 @@ def _add_variant_arg(sp):
                     help="engine variant JSON (reference: --variant/-v)")
 
 
+#: verbs whose process computes on the device; each resolves the
+#: platform through parallel.mesh.device_platform. Every other verb is
+#: pinned to the CPU before it can touch JAX.
+DEVICE_VERBS = frozenset(
+    {"train", "eval", "deploy", "update", "run", "bootstrap"})
+
+
 def main(argv: Optional[List[str]] = None) -> int:
     logging.basicConfig(
         level=logging.INFO,
         format="[%(levelname)s] [%(name)s] %(message)s")
     args = build_parser().parse_args(argv)
+    if args.command not in DEVICE_VERBS:
+        from predictionio_tpu.parallel.mesh import host_only
+        host_only()
     return args.func(args)
 
 

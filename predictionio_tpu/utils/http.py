@@ -182,6 +182,13 @@ class Router:
         return Response(404, {"message": "not found"})
 
 
+class _BurstTolerantServer(ThreadingHTTPServer):
+    # socketserver's default accept backlog is 5: a burst of as many
+    # simultaneous connects as one micro-batch holds (16) overflows it
+    # and some clients see "connection reset by peer"
+    request_queue_size = 128
+
+
 class HttpServer:
     def __init__(self, router: Router, host: str = "0.0.0.0",
                  port: int = 8000):
@@ -292,8 +299,8 @@ class HttpServer:
         last_err = None
         for attempt in range(bind_retries):
             try:
-                self._httpd = ThreadingHTTPServer((self.host, self.port),
-                                                  self._make_handler())
+                self._httpd = _BurstTolerantServer(
+                    (self.host, self.port), self._make_handler())
                 break
             except OSError as e:
                 last_err = e

@@ -24,6 +24,7 @@ from predictionio_tpu.data.storage.base import (EngineInstance,
                                                 EvaluationInstance, Model)
 from predictionio_tpu.data.storage.registry import Storage
 from predictionio_tpu.obs import TRACER, get_registry, jaxmon
+from predictionio_tpu.parallel.mesh import device_platform
 
 logger = logging.getLogger(__name__)
 
@@ -57,6 +58,44 @@ def _timed_stage(hist, stage: str):
     return cm()
 
 
+def _train_report(device: dict, result) -> dict:
+    """What ran where: the platform this process resolved, what each
+    device holds and held at peak (where the backend reports it), the
+    stage walls ``Engine.train`` measured, and — from algorithms that expose
+    ``last_train_telemetry`` (the ALS family) — the solver / compute
+    dtype ``auto`` resolved to plus the per-phase train walls."""
+    report = {"platform": device["platform"],
+              "device_kind": device["device_kind"],
+              "device_count": device["n"],
+              "devices": [dict(device=dev, **kinds) for dev, kinds
+                          in sorted(jaxmon.device_memory().items())],
+              "stages": {k: round(v, 3)
+                         for k, v in result.stage_seconds.items()}}
+    for algo in result.algorithms:
+        tel = getattr(algo, "last_train_telemetry", None)
+        if tel:
+            report["algorithm"] = {
+                k: (round(v, 4) if isinstance(v, float) else v)
+                for k, v in tel.items()}
+            break
+    return report
+
+
+def _report_env(report: dict) -> dict:
+    """The slice of the train report persisted on the EngineInstance
+    (``env`` is a str->str map): the deploy side reads it back into
+    ``/stats.json`` so an operator can tell which device, solver and
+    dtype produced the model being served."""
+    env = {"platform": report["platform"],
+           "device_kind": report["device_kind"],
+           "device_count": str(report["device_count"])}
+    algo = report.get("algorithm", {})
+    for key in ("solver", "compute_dtype"):
+        if key in algo:
+            env[key] = str(algo[key])
+    return env
+
+
 def run_train(engine: Engine, engine_params: EngineParams,
               engine_id: str = "default", engine_version: str = "0",
               engine_variant: str = "default",
@@ -76,10 +115,14 @@ def run_train(engine: Engine, engine_params: EngineParams,
         preparator_params=json.dumps(ep_json.get("preparator", {})),
         algorithms_params=json.dumps(ep_json.get("algorithms", [])),
         serving_params=json.dumps(ep_json.get("serving", {})))
+    # resolve the platform BEFORE the INIT record: a trainer that cannot
+    # get the chip fails here, not after minutes of host-side read
+    device = device_platform()
     instance_id = instances.insert(instance)
     instance = instances.get(instance_id)
     hist = _stage_hist()
     jaxmon.install()
+    jaxmon.install_device_memory_gauge()
     from predictionio_tpu.obs.flight import FLIGHT
     FLIGHT.record("train_start", model_version=instance_id,
                   engine=engine_id)
@@ -88,6 +131,8 @@ def run_train(engine: Engine, engine_params: EngineParams,
                           engine=engine_id):
             with _timed_stage(hist, "train"):
                 result = engine.train(engine_params, workflow_params)
+            report = _train_report(device, result)
+            logger.info("Train report: %s", json.dumps(report))
             if workflow_params.save_model:
                 with _timed_stage(hist, "serialize"):
                     serializable = engine.make_serializable_models(
@@ -96,8 +141,9 @@ def run_train(engine: Engine, engine_params: EngineParams,
                 with _timed_stage(hist, "persist"):
                     Storage.get_model_data_models().insert(
                         Model(instance_id, blob))
-            instances.update(instance.with_(status="COMPLETED",
-                                            end_time=_now()))
+            instances.update(instance.with_(
+                status="COMPLETED", end_time=_now(),
+                env={**instance.env, **_report_env(report)}))
         FLIGHT.record("train_end", model_version=instance_id,
                       status="COMPLETED")
         logger.info("Training completed: engine instance %s", instance_id)
@@ -119,6 +165,7 @@ def run_evaluation(engine: Engine, evaluation: Evaluation,
                    workflow_params: WorkflowParams = WorkflowParams()) -> str:
     """Evaluate a params sweep and record results; returns the
     EvaluationInstance id (CoreWorkflow.runEvaluation)."""
+    device_platform()
     dao = Storage.get_meta_data_evaluation_instances()
     instance = EvaluationInstance(
         status="INIT", start_time=_now(), end_time=_now(),

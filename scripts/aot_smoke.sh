@@ -16,16 +16,17 @@
 # (the wall the cache exists to eliminate) rather than process wall:
 # on the CPU container, trace/lowering — which the XLA cache does not
 # cover, by design — dominates these small programs, capping the
-# end-to-end wall gain near 2-3x; on a real TPU (BENCH_r01: 231.6 s
-# warmup) backend compile dominates both, and the same mechanism
-# carries the full deploy-to-first-query ratio. Both walls are printed
-# for the log.
+# end-to-end wall gain near 2-3x; on a TPU backend compile dominates
+# both, and the same mechanism carries the full deploy-to-first-query
+# ratio (chip_smoke.py reports the deploy process's cache hits there).
+# Both walls are printed for the log.
 #
 # Chaos-class tooling: never part of the tier-1 lane; this script is
 # the CI/operator entry point next to chaos_smoke.sh / obs_smoke.sh.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
+# CPU on purpose: a behaviour smoke, not a device run (chip_smoke.py is)
 export JAX_PLATFORMS=cpu
 export PYTHONHASHSEED=0
 export PYTHONPATH="$PWD${PYTHONPATH:+:$PYTHONPATH}"

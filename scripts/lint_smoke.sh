@@ -17,6 +17,7 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
+# CPU on purpose: a behaviour smoke, not a device run (chip_smoke.py is)
 export JAX_PLATFORMS=cpu
 export PYTHONHASHSEED=0
 

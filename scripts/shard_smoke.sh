@@ -1,6 +1,7 @@
 #!/usr/bin/env bash
 # Sharded online plane smoke (ISSUE 12): the over-budget acceptance
-# scenario on a FORCED 4-device CPU mesh.
+# scenario on the suite's virtual 8-device CPU mesh (JAX_PLATFORMS=cpu;
+# tests/conftest.py sets jax_num_cpu_devices=8).
 #
 # tests/test_sharded_scale.py trains, folds >= 3 consecutive ticks and
 # serves a vocabulary whose factor-table bytes exceed the enforced
@@ -18,17 +19,13 @@
 #     extended to the sharded executables).
 #
 # The test is slow-marked (never tier-1); this script is its CI /
-# operator entry point. The 4-device count is forced through
-# XLA_FLAGS BEFORE the suite conftest runs (conftest only appends its
-# own 8-device default when the flag is absent), so the same scenario
-# the 8-device dev box runs is rehearsed at the smallest mesh the
-# acceptance allows.
+# operator entry point.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
+# CPU on purpose: a behaviour smoke, not a device run (chip_smoke.py is)
 export JAX_PLATFORMS=cpu
 export PYTHONHASHSEED=0
-export XLA_FLAGS="--xla_force_host_platform_device_count=4"
 # hermetic: no ambient chaos, guard kill switch, or stale budget
 unset PIO_FAULTS 2>/dev/null || true
 unset PIO_GUARD 2>/dev/null || true

@@ -423,8 +423,9 @@ def test_dual_iters_cap_converges_like_uncapped():
 
 
 def test_train_telemetry_phases():
-    """als_train(telemetry=) reports every phase with sane values and
-    does not perturb the result (bench.py's product-path split)."""
+    """als_train(telemetry=) reports what `auto` resolved to and every
+    phase with sane values, and does not perturb the result (the train
+    report `pio train` logs and chip_smoke.py reads)."""
     rng = np.random.default_rng(3)
     n_u, n_i, nnz = 300, 80, 4000
     ui = rng.integers(0, n_u, nnz)
@@ -435,9 +436,15 @@ def test_train_telemetry_phases():
     tel = {}
     m1 = als_train(r, cfg, telemetry=tel)
     m2 = als_train(r, cfg)
-    assert set(tel) == {"plan_s", "upload_s", "iters_s", "s_per_iter",
-                        "fetch_s"}
-    assert all(v >= 0 for v in tel.values())
+    phases = {"plan_s", "upload_s", "iters_s", "compile_s", "sweeps_s",
+              "s_per_iter", "fetch_s"}
+    assert set(tel) == phases | {"solver", "compute_dtype", "sweep_chunk",
+                                 "n_devices"}
+    assert all(tel[k] >= 0 for k in phases)
+    assert (tel["solver"], tel["compute_dtype"], tel["sweep_chunk"]) == (
+        "cholesky", "float32", 1)
+    assert tel["compile_s"] + tel["sweeps_s"] == pytest.approx(
+        tel["iters_s"])
     assert tel["s_per_iter"] * cfg.iterations == pytest.approx(
         tel["iters_s"])
     np.testing.assert_allclose(m1.user_factors, m2.user_factors,

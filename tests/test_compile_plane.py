@@ -12,6 +12,8 @@ The acceptance criteria these tests pin:
   caches cleared, executables deserialized from disk).
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -270,10 +272,54 @@ def rank_of(model):
 # ---------------------------------------------------------------------------
 
 class TestPersistentCache:
-    def test_salt_is_stable_and_short(self):
-        from predictionio_tpu.compile.cache import cache_salt
-        assert cache_salt() == cache_salt()
-        assert len(cache_salt()) == 12
+    def test_env_dir_is_the_cache_exactly(self, tmp_path, monkeypatch,
+                                          request):
+        """$JAX_COMPILATION_CACHE_DIR, when set, IS the cache — no
+        salt level under it, and an explicit root= argument loses."""
+        import jax
+        from predictionio_tpu.compile import cache as C
+        placed = tmp_path / "placed"
+        monkeypatch.delenv("PIO_XLA_CACHE", raising=False)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(placed))
+        request.addfinalizer(C.disable_persistent_cache)
+        assert C.enable_persistent_cache() == str(placed)
+        assert C.enable_persistent_cache(
+            root=str(tmp_path / "elsewhere")) == str(placed)
+        assert jax.config.jax_compilation_cache_dir == str(placed)
+        assert C.cache_status()["dir"] == str(placed)
+        jax.jit(lambda x: (x * 7.0 - 2.0).sum())(
+            np.arange(31, dtype=np.float32))
+        assert [p for p in placed.iterdir() if p.is_file()], \
+            "entries land directly in the placed directory"
+        assert not (tmp_path / "elsewhere").exists()
+
+    def test_default_dir_is_in_the_checkout(self, monkeypatch):
+        """Unset, the cache is <checkout>/.xla_cache: independent of
+        PIO_FS_BASEDIR, $HOME, the pid or any temp name (the directory
+        is part of the cache key — a cache that moves never hits)."""
+        from predictionio_tpu.compile import cache as C
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        for basedir, home in (("/a/store", "/home/x"), ("/b", "/root")):
+            monkeypatch.setenv("PIO_FS_BASEDIR", basedir)
+            monkeypatch.setenv("HOME", home)
+            assert C.cache_dir() == os.path.join(repo, ".xla_cache")
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".xla_cache/" in f.read().split()
+
+    def test_failed_warm_bucket_is_counted(self):
+        """A spec that does not compile is memoised as failed (serving
+        falls back to jit) AND reported by warm() — the count the
+        deploy-time load turns into a deploy failure."""
+        from predictionio_tpu.obs.metrics import MetricsRegistry
+
+        def broken(**dims):
+            raise RuntimeError("Mosaic says no")
+        reg = AOTRegistry(registry=MetricsRegistry())
+        reg.register("broken", broken)
+        out = reg.warm([("broken", {"b": 1}), ("absent", {"b": 1})])
+        assert (out["compiled"], out["failed"], out["skipped"]) == (0, 1, 1)
+        assert reg.snapshot()["failedBuckets"] == 1
 
     def test_disabled_by_env(self, monkeypatch):
         from predictionio_tpu.compile import cache as C
@@ -290,6 +336,7 @@ class TestPersistentCache:
         # fully detach afterwards (a latched jax cache dir would make
         # every later compile in the suite write to disk)
         monkeypatch.delenv("PIO_XLA_CACHE", raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         request.addfinalizer(C.disable_persistent_cache)
         d = C.enable_persistent_cache(root=str(tmp_path))
         if d is None:
@@ -313,6 +360,7 @@ class TestPersistentCache:
         import jax
         from predictionio_tpu.compile import cache as C
         monkeypatch.delenv("PIO_XLA_CACHE", raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         request.addfinalizer(C.disable_persistent_cache)
         d = C.enable_persistent_cache(root=str(tmp_path / "c2"))
         if d is None:

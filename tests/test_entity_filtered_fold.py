@@ -192,6 +192,17 @@ class _OneApp:
         return App(1, name)
 
 
+def _release_and_join(wedged):
+    """Release the wedged backend and wait for its stranded workers:
+    a worker still on its way out returns its permit to whatever
+    semaphore the NEXT test has patched in (this flaked under load)."""
+    wedged.release.set()
+    for t in threading.enumerate():
+        if t.name == "pio-point-read":
+            t.join(timeout=10)
+            assert not t.is_alive()
+
+
 class TestPointReadDeadline:
     def _store(self, events):
         from predictionio_tpu.data.store.event_store import EventStore
@@ -210,7 +221,7 @@ class TestPointReadDeadline:
                 store.find_by_entity("app", "user", "u1", timeout_ms=50)
             assert counter.value == before + 1
         finally:
-            wedged.release.set()
+            _release_and_join(wedged)
 
     def test_wedged_workers_are_bounded(self, monkeypatch):
         """Each timed-out read strands one worker; past the permit cap,
@@ -238,7 +249,7 @@ class TestPointReadDeadline:
             assert 0.25 <= waited < 2.0
             assert threading.active_count() <= n_before + 2
         finally:
-            wedged.release.set()
+            _release_and_join(wedged)
 
     def test_healthy_burst_past_permits_still_answers(self, monkeypatch):
         """Permit contention from HEALTHY concurrent reads queues within

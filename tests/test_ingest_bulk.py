@@ -37,7 +37,7 @@ def _event(tag, i):
 
 @pytest.mark.slow
 class TestConcurrentIngestBeatsSerial:
-    """BENCH_r05's regression bar: 8 concurrent writers must complete
+    """The contention regression bar: 8 concurrent writers must complete
     with zero lost/duplicated events and aggregate throughput >= the
     serial run (the group committer batches them instead of convoying
     on the append lock)."""
@@ -97,7 +97,7 @@ class TestConcurrentIngestBeatsSerial:
             serial = min(serial, self._serial_rate(tmp_path))
         assert conc >= serial, (
             f"concurrent-8 {conc:,.0f} ev/s < serial {serial:,.0f} ev/s "
-            "— the BENCH_r05 contention regression is back")
+            "— the append-lock contention regression is back")
 
 
 _KILL_CHILD = r"""

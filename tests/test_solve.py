@@ -135,8 +135,8 @@ class TestCGSolve:
     def test_cg_pallas_interpret_new_ladder_ks(self, k):
         """The round-4 bucket ladder feeds the kernel K values that are
         multiples of 8 but not 16 (24, 40, 56, ...) — check the kernel
-        math at each (Mosaic layout behavior at these K is gated
-        separately by scripts/tpu_kernel_probe.py on the real chip)."""
+        math at each (that Mosaic compiles them is what chip_smoke.py
+        shows on the chip: the rank-200 plan's dual route runs them)."""
         import functools
         import jax
         import jax.numpy as jnp

@@ -3,6 +3,7 @@ query over HTTP -> feedback -> reload (mirrors the reference's
 CreateWorkflow/CreateServer behavior)."""
 
 import json
+import os
 import time
 import urllib.error
 import urllib.request
@@ -147,6 +148,23 @@ class TestEngineServer:
         assert "Request count" in html
         assert server.request_count == 1
         assert server.last_serving_sec > 0
+
+    def test_stats_json_carries_device_and_training_resolution(self, server):
+        """/stats.json names the device THIS process computes on, as JAX
+        reported it, and what `auto` resolved to when the loaded model
+        was trained (read back from the EngineInstance env the trainer
+        wrote) — the fields chip_smoke.py takes the platform from."""
+        import jax
+        status, body = call(server.config.port, "GET", "/stats.json")
+        assert status == 200
+        dev = jax.devices()
+        assert (body["platform"], body["deviceKind"], body["deviceCount"]) \
+            == (dev[0].platform, dev[0].device_kind, len(dev))
+        assert body["pid"] == os.getpid()
+        assert (body["solver"], body["computeDtype"]) == ("cholesky",
+                                                          "float32")
+        env = server.engine_instance.env
+        assert env["platform"] == "cpu" and env["device_count"] == "8"
 
     def test_plugins_endpoint(self, server):
         status, body = call(server.config.port, "GET", "/plugins.json")
