@@ -411,11 +411,16 @@ def device_timed(label: str, fn, *args):
         if _block_until_ready is None:
             from jax import block_until_ready
             _block_until_ready = block_until_ready
-        try:
-            _block_until_ready(out)
-        except Exception:
-            pass   # host-side fallback output: already complete
+        from predictionio_tpu.obs.trace import TRACER
+        with TRACER.region("device_sync"):
+            try:
+                _block_until_ready(out)
+            except Exception:
+                pass   # host-side fallback output: already complete
         wall = time.perf_counter() - t0
+        # what the sync alone held this thread for (the dispatch wall is
+        # paid either way): the serving account reads it per dispatch
+        _tls.sync_s = getattr(_tls, "sync_s", 0.0) + wall - dispatch_dt
         if getattr(_tls, "compile_s", 0.0) > compile_before:
             # the sampled dispatch paid an XLA compile (cold jit
             # fallback — the backend_compile listener fired on this
@@ -431,13 +436,16 @@ def device_timed(label: str, fn, *args):
         with _dev_lock:   # scrape-time percentile reads copy under it
             st.ring.append(wall)
         _note_device_time(est, st.tenant)
-        try:
-            from predictionio_tpu.obs.trace import TRACER
-            TRACER.annotate(deviceMs=round(wall * 1000.0, 3),
-                            deviceSampled=st.every)
-        except Exception:
-            pass
+        TRACER.annotate(deviceMs=round(wall * 1000.0, 3),
+                        deviceSampled=st.every)
     return out
+
+
+def thread_sync_s() -> float:
+    """Seconds THIS thread has spent blocked in :func:`device_timed`'s
+    sampled sync, cumulative: the batcher samples the delta around a
+    window's begin() for the dispatch's account record."""
+    return getattr(_tls, "sync_s", 0.0)
 
 
 def occupancy() -> float:

@@ -250,16 +250,18 @@ class FlightRecorder:
                     pending = n
                 if pending:
                     fields["coalesced"] = pending
-            rec = self._build(kind, model_version, fields)
-            # += on an attribute is LOAD/ADD/STORE — concurrent
-            # recorders would lose increments, so the self-accounting
-            # counters ride the ring lock
-            with self._lock:
-                self._ring.append(rec)
-                self.records += 1
-            if os.environ.get("PIO_FLIGHT", "").strip().lower() \
-                    not in ("off", "0", "false"):
-                self._enqueue(rec)
+            from predictionio_tpu.obs.trace import TRACER
+            with TRACER.region("flight.write", kind=kind):
+                rec = self._build(kind, model_version, fields)
+                # += on an attribute is LOAD/ADD/STORE — concurrent
+                # recorders would lose increments, so the
+                # self-accounting counters ride the ring lock
+                with self._lock:
+                    self._ring.append(rec)
+                    self.records += 1
+                if os.environ.get("PIO_FLIGHT", "").strip().lower() \
+                        not in ("off", "0", "false"):
+                    self._enqueue(rec)
             return rec
         except Exception:
             logger.debug("flight record failed", exc_info=True)

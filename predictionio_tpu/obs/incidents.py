@@ -276,11 +276,16 @@ class IncidentManager:
         out.extend(t for t in rest if t["traceId"] in linked)
         return out[:self.traces_limit]
 
-    def _write_bundle(self, incident_id, kind, reason, context,
-                      flight, trace_ids, tenant=None):
+    def _write_bundle(self, incident_id, kind, *rest):
+        if self.trace_settle_s > 0:
+            time.sleep(self.trace_settle_s)
+        from predictionio_tpu.obs.trace import TRACER
+        with TRACER.region("incident.capture", kind=kind):
+            self._write_bundle_now(incident_id, kind, *rest)
+
+    def _write_bundle_now(self, incident_id, kind, reason, context,
+                          flight, trace_ids, tenant=None):
         try:
-            if self.trace_settle_s > 0:
-                time.sleep(self.trace_settle_s)
             traces = self._matching_traces(trace_ids)
             if tenant is not None:
                 traces = _tenant_trace_slice(traces, tenant)

@@ -8,13 +8,17 @@ threshold (the serve-p99 latency bound: ``PIO_SLOW_QUERY_MS``, else
 ``PIO_SLO_SERVE_P99_MS``, default 250 ms) auto-captures a **stage
 waterfall**:
 
-    queue_wait -> batch_formation -> supplement -> dispatch
-    [-> device_sync] -> post_process -> serialize
+    queue_wait -> batch_formation [-> gate] -> supplement -> dispatch
+    [-> device_sync] [-> turnaround] -> post_process -> serialize
 
 built from the spans the serving path already records (the query
 trace's ``batch_wait``, plus the linked ``batch_predict`` trace's
 ``supplement``/``predict``/``post_process`` spans; ``device_sync``
-appears when the costmon 1-in-N sampled sync landed on this window).
+appears when the costmon 1-in-N sampled sync landed on this window;
+``gate``, the wait on the in-flight cap, and ``turnaround``, begin
+returned -> d2h ready, which holds the ``completion_wait`` and
+``d2h_wait`` that follow it, come from the dispatch's record in the
+serving account, obs/trace ``DISPATCH_FIELDS``).
 Captures land in a bounded ring served at ``GET /slow.json`` on the
 engine server and as a ``slow_query`` flight record — and the
 ``slow_queries`` incident provider puts the top waterfalls into every
@@ -34,6 +38,8 @@ import os
 import threading
 import time
 from typing import Dict, List, Optional
+
+from predictionio_tpu.obs.trace import DISPATCH_FIELDS
 
 #: span-name -> waterfall-stage mapping; order is the waterfall order.
 #: completion_wait/readback appear on the pipelined executor's windows
@@ -78,13 +84,16 @@ def _find_span(trace, name: str):
 
 
 def build_waterfall(query_trace, batch_trace=None,
-                    serialize_s: Optional[float] = None) -> List[dict]:
+                    serialize_s: Optional[float] = None,
+                    dispatch: Optional[tuple] = None) -> List[dict]:
     """The stage list for one slow request. ``query_trace`` is the
     (possibly still-open) ingress trace on the request thread;
     ``batch_trace`` the committed ``batch_predict`` trace that answered
     it, when the micro-batcher coalesced it (None = unbatched, the
-    stages live in the query trace itself)."""
+    stages live in the query trace itself); ``dispatch`` that window's
+    record in the serving account, when its ring still holds it."""
     stages: List[dict] = []
+    acct = dict(zip(DISPATCH_FIELDS, dispatch)) if dispatch else None
 
     def add(stage: str, seconds: Optional[float]):
         if seconds is None:
@@ -102,7 +111,11 @@ def build_waterfall(query_trace, batch_trace=None,
         fm = batch_trace.root.attrs.get("formationMs")
         if fm is not None:
             add("batch_formation", float(fm) / 1000.0)
+    if acct is not None:
+        add("gate", acct["t_gate"] - acct["t_closed"])
     for span_name, stage in _STAGE_SPANS:
+        if stage == "readback" and acct is not None:
+            add("turnaround", acct["t_ready"] - acct["t_begin"])
         if stage == "readback" and batch_trace is not None:
             # pipelined executor (ISSUE 14): the window's time in the
             # completion queue precedes its readback
@@ -211,7 +224,8 @@ def capture_slow_query(query_trace, total_s: float,
                        model_version: Optional[str] = None,
                        serialize_s: Optional[float] = None,
                        batch_trace_id: Optional[str] = None,
-                       tenant: Optional[str] = None) -> dict:
+                       tenant: Optional[str] = None,
+                       dispatch: Optional[tuple] = None) -> dict:
     """Build + record one slow-query entry (request thread, slow path
     only). Resolves the answering batch trace from the query trace's
     links, emits the ``slow_query`` flight record (which stamps the
@@ -227,7 +241,7 @@ def capture_slow_query(query_trace, total_s: float,
     if batch_trace_id:
         batch_trace = TRACER.get(batch_trace_id)
     stages = build_waterfall(query_trace, batch_trace,
-                             serialize_s=serialize_s)
+                             serialize_s=serialize_s, dispatch=dispatch)
     entry = {
         "traceId": query_trace.trace_id,
         "t": time.time(),

@@ -18,9 +18,29 @@ process-wide view — query traces at serving QPS must not evict the
 day's fold ticks) served at ``GET /traces.json`` on both HTTP servers:
 last N, filterable by kind, sortable by slowest.
 
-Hot-path cost: ``span()`` outside any active trace is a no-op context
-manager (~1 µs); inside a trace it is one object append + two
-``perf_counter`` calls (guarded by tests/test_obs_overhead.py).
+One clock for host and device (ISSUE 25): ``trace``/``resume``/``span``
+enter a ``jax.profiler.TraceAnnotation("pio.<name>")`` beside the
+``Span`` they make, so whenever a ``jax.profiler`` session runs (the
+benchmark's ``--trace 1`` slice, ``pio profile trace start``,
+``/profile.json``) every program span lies in the profiler's own trace,
+on the clock of the device's operations. With no session the annotation
+is an inactive TraceMe; "off" is "no profiler session" and nothing else.
+``region()`` is the same for the loops that have no request context
+(the batcher's threads, the training driver): a span inside a trace, the
+annotation alone outside one. JAX is never imported for the sake of a
+span: the annotation class is taken from ``sys.modules`` at first use,
+so a process that never loads JAX (the event server) pays nothing.
+
+The serving account (ISSUE 25): beside the trace rings the tracer keeps
+two bounded rings of plain tuples, one per dispatch and one per request
+(``DISPATCH_FIELDS`` / ``REQUEST_FIELDS``), written by the batcher, the
+engine server and the HTTP layer and read with ``recent(kind, n)``.
+They outlive the server that wrote them.
+
+Hot-path cost: ``span()`` outside any active trace returns a shared
+no-op context manager (~0.3 µs); inside a trace it is one object append,
+two ``perf_counter`` calls and the annotation (guarded by
+tests/test_obs_overhead.py).
 """
 
 from __future__ import annotations
@@ -30,12 +50,118 @@ import contextvars
 import itertools
 import os
 import re
+import sys
 import threading
 import time
 from contextlib import contextmanager
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 _span_seq = itertools.count(1)
+
+# -- the profiler's clock (ISSUE 25) -----------------------------------
+_annotation_cls = None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` (entering it yields None, as a
+    span outside a trace does) once some other module has loaded JAX,
+    else None. Never imports JAX: the event server has no use for it."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        base = getattr(getattr(sys.modules.get("jax"), "profiler", None),
+                       "TraceAnnotation", None)
+        if base is None:
+            return None   # not latched: JAX may still be loaded later
+
+        class _Annotation(base):
+            def __enter__(self):
+                super().__enter__()
+
+        _annotation_cls = _Annotation
+    return _annotation_cls
+
+
+class _NoScope:
+    """``span()`` outside a trace, ``region()`` with no JAX loaded."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SCOPE = _NoScope()
+
+
+def _annotate(name: str, attrs: Optional[dict] = None):
+    """The ``pio.<name>`` annotation, not yet entered; the shared no-op
+    where JAX is not loaded."""
+    cls = _annotation()
+    if cls is None:
+        return _NO_SCOPE
+    return cls("pio." + name, **attrs) if attrs else cls("pio." + name)
+
+
+class _SpanScope:
+    """A child span of the current trace, with its annotation: what
+    ``span()`` and ``region()`` return inside a trace."""
+
+    __slots__ = ("_var", "_ctx", "_name", "_attrs", "_span", "_token",
+                 "_ann")
+
+    def __init__(self, var, ctx, name: str, attrs: dict):
+        self._var, self._ctx, self._name, self._attrs = (var, ctx, name,
+                                                         attrs)
+
+    def __enter__(self):
+        trace, parent = self._ctx
+        self._span = s = Span(self._name, parent.span_id)
+        if self._attrs:
+            s.attrs.update(self._attrs)
+        trace.spans.append(s)
+        self._token = self._var.set((trace, s))
+        cls = _annotation()
+        if cls is None:
+            self._ann = None
+        else:
+            self._ann = cls("pio." + self._name, **self._attrs)
+            self._ann.__enter__()
+        return s
+
+    def __exit__(self, exc_type, exc, tb):
+        s = self._span
+        ann = self._ann
+        if ann is not None:
+            if len(s.attrs) > len(self._attrs) and ann.is_enabled():
+                # what the body learned (a wait, a count of evictions)
+                # goes into the profiler's event too
+                ann.set_metadata(**{k: v for k, v in s.attrs.items()
+                                    if k not in self._attrs})
+            ann.__exit__(exc_type, exc, tb)
+        if exc is not None:
+            s.error = f"{type(exc).__name__}: {exc}"
+        self._var.reset(self._token)
+        s.end()
+        return False
+
+
+# -- the serving account (ISSUE 25) ------------------------------------
+# One plain tuple per dispatch and per request: floats of
+# time.perf_counter(), ints, and the tenant id the traces carry (or
+# None), so the collector does not track them.
+DISPATCH = "serve.dispatch"
+REQUEST = "serve.request"
+DISPATCH_FIELDS = ("seq", "t_enqueue", "t_dequeue", "t_closed", "t_gate",
+                   "t_begin", "t_pickup", "t_ready", "t_done", "batch",
+                   "bucket", "sync_s", "tenant")
+REQUEST_FIELDS = ("t_start", "t_enqueue", "t_result", "t_written",
+                  "dispatch_seq", "tenant")
+_ACCOUNT_CAPACITY = {DISPATCH: 4096, REQUEST: 16384}
+_dispatch_seq = itertools.count(1)
+_request_note = threading.local()
 
 # -- cross-process propagation (ISSUE 13) ------------------------------
 # The header contract every HTTP hop in the stack speaks: an ingress
@@ -242,6 +368,13 @@ class Tracer:
         self._event_traces: "collections.OrderedDict[str, str]" = \
             collections.OrderedDict()
         self._event_map_capacity = event_map_capacity
+        # the serving account: appends are atomic under the interpreter
+        # lock and the readers copy, so the rings take no lock
+        self._account: Dict[str, collections.deque] = {
+            kind: collections.deque(maxlen=cap)
+            for kind, cap in _ACCOUNT_CAPACITY.items()}
+        self._gc_ann = None
+        self._gc_watchers = 0
 
     # -- context -------------------------------------------------------
     def current_trace(self) -> Optional[Trace]:
@@ -264,7 +397,8 @@ class Tracer:
         _stamp_tenant(t.root)
         token = self._ctx.set((t, t.root))
         try:
-            yield t
+            with _annotate(kind):
+                yield t
         except BaseException as e:
             t.root.error = f"{type(e).__name__}: {e}"
             raise
@@ -296,7 +430,8 @@ class Tracer:
         mark the root span and re-raise (matching :meth:`trace`)."""
         token = self._ctx.set((t, t.root))
         try:
-            yield t
+            with _annotate(t.kind):
+                yield t
         except BaseException as e:
             t.root.error = f"{type(e).__name__}: {e}"
             raise
@@ -307,28 +442,107 @@ class Tracer:
                 if not t.discard:
                     self._commit(t)
 
-    @contextmanager
     def span(self, name: str, **attrs):
-        """A child span of the current trace; a cheap no-op when no
-        trace is active (so instrumented code needs no caller checks)."""
+        """A child span of the current trace, entered with its
+        ``pio.<name>`` annotation; a shared no-op when no trace is
+        active (so instrumented code needs no caller checks)."""
         ctx = self._ctx.get()
         if ctx is None:
-            yield None
+            return _NO_SCOPE
+        return _SpanScope(self._ctx, ctx, name, attrs)
+
+    def region(self, name: str, **attrs):
+        """:meth:`span` for code with no request context (the batcher's
+        loops, the training driver, the HTTP layer): inside a trace it
+        IS ``span()``; outside one it is the ``pio.<name>`` annotation
+        alone (entering it yields None: no ``Span``, no ring), and
+        nothing at all where JAX is not loaded."""
+        ctx = self._ctx.get()
+        if ctx is not None:
+            return _SpanScope(self._ctx, ctx, name, attrs)
+        return _annotate(name, attrs)
+
+    # -- the serving account (ISSUE 25) ---------------------------------
+    @staticmethod
+    def next_dispatch_seq() -> int:
+        """Process-wide, so that hosts with several batchers still join
+        a request to its dispatch by this number alone."""
+        return next(_dispatch_seq)
+
+    def record(self, kind: str, rec: tuple):
+        """Append one account record (``DISPATCH_FIELDS`` or
+        ``REQUEST_FIELDS`` order) to its bounded ring."""
+        self._account[kind].append(rec)
+
+    def recent(self, kind: str, n: Optional[int] = None) -> List[tuple]:
+        """The newest ``n`` records of ``DISPATCH`` or ``REQUEST``
+        (all that the ring holds by default), oldest first."""
+        recs = list(self._account[kind])
+        return recs if n is None else recs[max(0, len(recs) - int(n)):]
+
+    def dispatch_record(self, seq: int) -> Optional[tuple]:
+        """The dispatch a request's ``dispatch_seq`` names, while the
+        ring still holds it."""
+        for rec in reversed(self._account[DISPATCH]):
+            if rec[0] == seq:
+                return rec
+            if rec[0] < seq:
+                break
+        return None
+
+    @staticmethod
+    def note_request(t_enqueue: float = 0.0, t_result: float = 0.0,
+                     dispatch_seq: int = -1,
+                     tenant: Optional[str] = None):
+        """What the layers under the HTTP handler know of the request
+        this thread is answering: the engine server opens the note (a
+        cache hit or a refusal keeps ``dispatch_seq`` -1), the batcher's
+        ``submit`` fills it in; :meth:`request_written` closes it."""
+        _request_note.rec = (t_enqueue, t_result, dispatch_seq, tenant)
+
+    @staticmethod
+    def noted_dispatch_seq() -> int:
+        rec = getattr(_request_note, "rec", None)
+        return rec[2] if rec is not None else -1
+
+    def request_written(self, t_start: float, t_written: float):
+        """The HTTP layer, after a response's last byte: one request
+        record if a query was answered on this thread, nothing for any
+        other route."""
+        rec = getattr(_request_note, "rec", None)
+        if rec is not None:
+            _request_note.rec = None
+            self._account[REQUEST].append(
+                (t_start, rec[0], rec[1], t_written, rec[2], rec[3]))
+
+    # -- collector pauses on the profiler's clock ------------------------
+    def _on_gc(self, phase: str, info: dict):
+        if info.get("generation") != 2:
             return
-        trace, parent = ctx
-        s = Span(name, parent.span_id)
-        if attrs:
-            s.attrs.update(attrs)
-        trace.spans.append(s)
-        token = self._ctx.set((trace, s))
-        try:
-            yield s
-        except BaseException as e:
-            s.error = f"{type(e).__name__}: {e}"
-            raise
-        finally:
-            self._ctx.reset(token)
-            s.end()
+        if phase == "start":
+            cls = _annotation()
+            if cls is not None:
+                self._gc_ann = cls("pio.gc.gen2")
+                self._gc_ann.__enter__()
+        elif self._gc_ann is not None:
+            ann, self._gc_ann = self._gc_ann, None
+            ann.__exit__(None, None, None)
+
+    def watch_gc(self, on: bool):
+        """Each serving process puts its generation-2 collections into
+        the profiler's trace as ``pio.gc.gen2`` from ``start()`` to
+        ``stop()`` (counted: a host runs several engine servers). A
+        collection holds the interpreter's lock on the thread that
+        tripped it, so its start and stop come on one thread."""
+        import gc
+        with self._lock:
+            self._gc_watchers += 1 if on else -1
+            hooked = self._on_gc in gc.callbacks
+            if self._gc_watchers > 0 and not hooked:
+                gc.callbacks.append(self._on_gc)
+            elif self._gc_watchers <= 0 and hooked:
+                self._gc_watchers = 0
+                gc.callbacks.remove(self._on_gc)
 
     def annotate(self, **attrs):
         """Attach attributes to the current span, if any."""
@@ -431,6 +645,8 @@ class Tracer:
             self._done.clear()
             self._by_id.clear()
             self._event_traces.clear()
+        for ring in self._account.values():
+            ring.clear()
 
 
 # The process-wide tracer.

@@ -36,6 +36,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from predictionio_tpu.obs import TRACER
 from predictionio_tpu.ops.ratings import (RatingsCOO, SolvePlan,
                                           plan_for_items, plan_for_users)
 from predictionio_tpu.parallel.mesh import MeshContext, current_mesh
@@ -206,15 +207,19 @@ def _dual_system_solve(M, y, K: int, solver: str,
         # run MORE iterations than uncapped — reject it loudly
         raise ValueError(f"dual_iters_cap must be >= 1, got {iters_cap}")
     iters = K + 8 if iters_cap is None else min(K + 8, iters_cap)
-    return spd_solve(M, y, method=method, iters=iters)
+    with jax.named_scope("pio.sweep.solve.jnp_cg" if method == "cg"
+                         else "pio.sweep.solve.dual"):
+        return spd_solve(M, y, method=method, iters=iters, system="dual")
 
 
 def _scatter_rows(factors_out, rows, x):
     """Scatter solved rows; padding rows (-1) land on the dummy tail."""
     import jax.numpy as jnp
-    safe = jnp.where(rows < 0, factors_out.shape[0] - 1, rows)
-    return factors_out.at[safe].set(x.astype(factors_out.dtype),
-                                    mode="drop")
+    import jax
+    with jax.named_scope("pio.sweep.scatter"):
+        safe = jnp.where(rows < 0, factors_out.shape[0] - 1, rows)
+        return factors_out.at[safe].set(x.astype(factors_out.dtype),
+                                        mode="drop")
 
 
 def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
@@ -234,12 +239,15 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
     from predictionio_tpu.ops.solve import spd_solve
 
     cd = jnp.dtype(compute_dtype)
-    Vg = counter_factors[idx]                       # [B, K, R] gather
-    Vc = Vg.astype(cd)
+    with jax.named_scope("pio.sweep.gather"):
+        Vg = counter_factors[idx]                   # [B, K, R] gather
+        Vc = Vg.astype(cd)
     K = idx.shape[1]
-    eye = jnp.eye(rank, dtype=jnp.float32)
-    n = mask.sum(axis=-1)                            # ratings per entity
-    reg = lam * jnp.maximum(n, 1.0) if nratings_reg else jnp.full_like(n, lam)
+    with jax.named_scope("pio.sweep.gram"):
+        eye = jnp.eye(rank, dtype=jnp.float32)
+        n = mask.sum(axis=-1)                        # ratings per entity
+        reg = (lam * jnp.maximum(n, 1.0) if nratings_reg
+               else jnp.full_like(n, lam))
 
     if solver == "diag_gather":
         # perf diagnostic, NOT a solver (wrong math by design): gather +
@@ -254,81 +262,93 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
         # dual/Woodbury: with M = mask-weighted factor rows [K, R],
         # (M^T M + reg I_R)^-1 M^T y == M^T (M M^T + reg I_K)^-1 y.
         # Gram is K^2*R instead of K*R^2, solve is K-dimensional.
-        Vm = Vc * mask[..., None].astype(cd)
-        Ad = jnp.einsum("bkr,blr->bkl", Vm, Vm,
-                        preferred_element_type=jnp.float32)
-        Ad = Ad + reg[:, None, None] * jnp.eye(K, dtype=jnp.float32)
-        y = (val * mask)
+        with jax.named_scope("pio.sweep.gram"):
+            Vm = Vc * mask[..., None].astype(cd)
+            Ad = jnp.einsum("bkr,blr->bkl", Vm, Vm,
+                            preferred_element_type=jnp.float32)
+            Ad = Ad + reg[:, None, None] * jnp.eye(K, dtype=jnp.float32)
+            y = (val * mask)
         z = _dual_system_solve(Ad, y, K, solver,
                                iters_cap=dual_iters_cap)
-        x = jnp.einsum("bkr,bk->br", Vm, z.astype(cd),
-                       preferred_element_type=jnp.float32)
+        with jax.named_scope("pio.sweep.gram"):
+            x = jnp.einsum("bkr,bk->br", Vm, z.astype(cd),
+                           preferred_element_type=jnp.float32)
         return _scatter_rows(factors_out, rows, x)
 
     if implicit:
-        G, gram_w, gram_q = gram if isinstance(gram, tuple) \
-            else (gram, None, None)
-        absval = jnp.abs(val)
-        conf_minus_1 = (alpha * absval) * mask       # c - 1, zero on padding
-        # preference p = 1(r>0): negative signals add confidence to A only
-        pos = (val > 0).astype(val.dtype) * mask
-        b = jnp.einsum("bk,bkr->br",
-                       ((1.0 + alpha * absval) * pos).astype(cd), Vc,
-                       preferred_element_type=jnp.float32)
+        with jax.named_scope("pio.sweep.gram"):
+            G, gram_w, gram_q = gram if isinstance(gram, tuple) \
+                else (gram, None, None)
+            absval = jnp.abs(val)
+            conf_minus_1 = (alpha * absval) * mask   # c - 1, 0 on padding
+            # preference p = 1(r>0): negative signals add confidence to
+            # A only
+            pos = (val > 0).astype(val.dtype) * mask
+            b = jnp.einsum("bk,bkr->br",
+                           ((1.0 + alpha * absval) * pos).astype(cd), Vc,
+                           preferred_element_type=jnp.float32)
         if gram_w is not None and dual_solve == "auto" and K < rank:
-            # implicit dual: B = G + reg I = Q (w + reg) Q^T (eig shared
-            # across the whole half-sweep); Woodbury for the K-rank
-            # confidence update, all R-dim work as eigenbasis einsums.
-            # G is PSD, so clamp eigh's roundoff-negative tail: a small
-            # reg (constant lambda_scaling grid points) must never meet
-            # a negative w and flip the sign of 1/denom.
-            denom = (jnp.maximum(gram_w, 0.0)[None, :]
-                     + reg[:, None])                          # [B, R]
-            Vq = jnp.einsum("bkr,rs->bks", Vc,
-                            gram_q.astype(cd),
-                            preferred_element_type=jnp.float32)  # V~ Q
-            bq = jnp.einsum("br,rs->bs", b.astype(cd),
-                            gram_q.astype(cd),
-                            preferred_element_type=jnp.float32)
-            bq_d = bq / denom
-            u = jnp.einsum("bs,rs->br", bq_d.astype(cd),
-                           gram_q.astype(cd),
-                           preferred_element_type=jnp.float32)  # B^-1 b
-            W = jnp.einsum("bks,bs,bls->bkl", Vq.astype(cd),
-                           (1.0 / denom).astype(cd), Vq.astype(cd),
-                           preferred_element_type=jnp.float32)
-            dhalf = jnp.sqrt(conf_minus_1)                     # [B, K]
-            M = (jnp.eye(K, dtype=jnp.float32)
-                 + dhalf[:, :, None] * W * dhalf[:, None, :])
-            t = jnp.einsum("bks,bs->bk", Vq.astype(cd),
-                           bq_d.astype(cd),
-                           preferred_element_type=jnp.float32)  # V B^-1 b
-            z = _dual_system_solve(M, dhalf * t, K, solver,
-                                   iters_cap=dual_iters_cap)
-            s = jnp.einsum("bks,bk->bs", Vq.astype(cd),
-                           (dhalf * z).astype(cd),
-                           preferred_element_type=jnp.float32)
-            x = u - jnp.einsum("bs,rs->br", (s / denom).astype(cd),
+            with jax.named_scope("pio.sweep.gram"):
+                # implicit dual: B = G + reg I = Q (w + reg) Q^T (eig
+                # shared across the whole half-sweep); Woodbury for the
+                # K-rank confidence update, all R-dim work as eigenbasis
+                # einsums. G is PSD, so clamp eigh's roundoff-negative
+                # tail: a small reg (constant lambda_scaling grid points)
+                # must never meet a negative w and flip the sign of
+                # 1/denom.
+                denom = (jnp.maximum(gram_w, 0.0)[None, :]
+                         + reg[:, None])                      # [B, R]
+                Vq = jnp.einsum("bkr,rs->bks", Vc,            # V~ Q
+                                gram_q.astype(cd),
+                                preferred_element_type=jnp.float32)
+                bq = jnp.einsum("br,rs->bs", b.astype(cd),
+                                gram_q.astype(cd),
+                                preferred_element_type=jnp.float32)
+                bq_d = bq / denom
+                u = jnp.einsum("bs,rs->br", bq_d.astype(cd),  # B^-1 b
                                gram_q.astype(cd),
                                preferred_element_type=jnp.float32)
+                W = jnp.einsum("bks,bs,bls->bkl", Vq.astype(cd),
+                               (1.0 / denom).astype(cd), Vq.astype(cd),
+                               preferred_element_type=jnp.float32)
+                dhalf = jnp.sqrt(conf_minus_1)                # [B, K]
+                M = (jnp.eye(K, dtype=jnp.float32)
+                     + dhalf[:, :, None] * W * dhalf[:, None, :])
+                t = jnp.einsum("bks,bs->bk", Vq.astype(cd),   # V B^-1 b
+                               bq_d.astype(cd),
+                               preferred_element_type=jnp.float32)
+            z = _dual_system_solve(M, dhalf * t, K, solver,
+                                   iters_cap=dual_iters_cap)
+            with jax.named_scope("pio.sweep.gram"):
+                s = jnp.einsum("bks,bk->bs", Vq.astype(cd),
+                               (dhalf * z).astype(cd),
+                               preferred_element_type=jnp.float32)
+                x = u - jnp.einsum("bs,rs->br", (s / denom).astype(cd),
+                                   gram_q.astype(cd),
+                                   preferred_element_type=jnp.float32)
             return _scatter_rows(factors_out, rows, x)
-        A = G + jnp.einsum("bk,bkr,bks->brs", conf_minus_1.astype(cd),
-                           Vc, Vc,
-                           preferred_element_type=jnp.float32)
+        with jax.named_scope("pio.sweep.gram"):
+            A = G + jnp.einsum("bk,bkr,bks->brs", conf_minus_1.astype(cd),
+                               Vc, Vc,
+                               preferred_element_type=jnp.float32)
     else:
-        A = jnp.einsum("bk,bkr,bks->brs", mask.astype(cd), Vc, Vc,
-                       preferred_element_type=jnp.float32)
-        b = jnp.einsum("bk,bkr->br", (val * mask).astype(cd), Vc,
-                       preferred_element_type=jnp.float32)
-    A = A + reg[:, None, None] * eye
+        with jax.named_scope("pio.sweep.gram"):
+            A = jnp.einsum("bk,bkr,bks->brs", mask.astype(cd), Vc, Vc,
+                           preferred_element_type=jnp.float32)
+            b = jnp.einsum("bk,bkr->br", (val * mask).astype(cd), Vc,
+                           preferred_element_type=jnp.float32)
+    with jax.named_scope("pio.sweep.gram"):
+        A = A + reg[:, None, None] * eye
     if solver == "diag_nosolve":
         # perf diagnostic: keep A alive against algebraic simplification
         # (see the _dual_system_solve note)
         x = b + jax.lax.optimization_barrier(A).sum(axis=2) \
             * jnp.float32(1e-12)
     else:
-        x = spd_solve(A, b, method=solver, iters=solver_iters,
-                      compute_dtype=compute_dtype)
+        with jax.named_scope("pio.sweep.solve.jnp_cg" if solver == "cg"
+                             else "pio.sweep.solve.primal"):
+            x = spd_solve(A, b, method=solver, iters=solver_iters,
+                          compute_dtype=compute_dtype)
     return _scatter_rows(factors_out, rows, x)
 
 
@@ -405,19 +425,23 @@ _solve_iteration = __import__("jax").jit(
 
 
 def _gram_impl(factors):
+    import jax
     import jax.numpy as jnp
-    return jnp.einsum("ir,is->rs", factors, factors,
-                      preferred_element_type=jnp.float32)
+    with jax.named_scope("pio.sweep.gram_full"):
+        return jnp.einsum("ir,is->rs", factors, factors,
+                          preferred_element_type=jnp.float32)
 
 
 def _gram_eig_impl(factors):
     """Gram + its eigendecomposition — computed ONCE per implicit
     half-sweep and shared by every entity's Woodbury solve (the base
     B = G + reg*I diagonalizes as Q diag(w + reg) Q^T for any reg)."""
+    import jax
     import jax.numpy as jnp
-    G = jnp.einsum("ir,is->rs", factors, factors,
-                   preferred_element_type=jnp.float32)
-    w, q = jnp.linalg.eigh(G)
+    with jax.named_scope("pio.sweep.gram_full"):
+        G = jnp.einsum("ir,is->rs", factors, factors,
+                       preferred_element_type=jnp.float32)
+        w, q = jnp.linalg.eigh(G)
     return G, w, q
 
 
@@ -466,6 +490,11 @@ def _upload_plan(mesh: MeshContext, plan: SolvePlan, chunk: int = 1):
     this only amortizes the solver's per-call fixed cost over more
     systems (ALSConfig.sweep_chunk); a remainder that doesn't fill a
     chunk becomes its own group."""
+    with TRACER.region("train.upload"):
+        return _upload_plan_now(mesh, plan, chunk)
+
+
+def _upload_plan_now(mesh: MeshContext, plan: SolvePlan, chunk: int):
     by_shape = {}
     for b in plan.batches:
         by_shape.setdefault(b.shape, []).append(b)
@@ -500,10 +529,11 @@ def _upload_plan(mesh: MeshContext, plan: SolvePlan, chunk: int = 1):
 
 
 def _run_side(device_groups, factors, counter_factors, cfg: ALSConfig,
-              gram, lam=None, alpha=None):
+              gram, lam=None, alpha=None, side: Optional[str] = None):
     """One half-iteration: solve every batch of one side in one dispatch.
     `lam`/`alpha` should be device-resident scalars (uploaded once per
-    train); numpy fallbacks keep ad-hoc callers working."""
+    train); numpy fallbacks keep ad-hoc callers working. `side`
+    ("user"/"item") only labels the `pio.train.half_sweep` span."""
     if lam is None:
         lam = np.float32(cfg.lam)
     if alpha is None:
@@ -511,7 +541,9 @@ def _run_side(device_groups, factors, counter_factors, cfg: ALSConfig,
     # compile attribution (obs/costmon): sweeps dispatched from a fold
     # tick keep the fold's label; bare train sweeps book as als_sweep
     from predictionio_tpu.obs import costmon
-    with costmon.executable(costmon.ALS_SWEEP, defer_to_outer=True):
+    attrs = {"side": side} if side else {}
+    with TRACER.region("train.half_sweep", **attrs), \
+            costmon.executable(costmon.ALS_SWEEP, defer_to_outer=True):
         return _solve_sweep(
             factors, counter_factors, gram, device_groups, lam, alpha,
             nratings_reg=(cfg.lambda_scaling == "nratings"),
@@ -622,12 +654,14 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
         nonlocal U, V, last_good
         if sentinel is None:
             return True
-        fault = (sentinel.check_table(U, f"iteration {it} user table")
-                 or sentinel.check_table(V, f"iteration {it} item table"))
-        if fault is None:
-            # copies survive the next iteration's donated sweep
-            last_good = (device_copy(U), device_copy(V))
-            return True
+        with TRACER.region("train.sentinel"):
+            fault = (sentinel.check_table(U, f"iteration {it} user table")
+                     or sentinel.check_table(V,
+                                             f"iteration {it} item table"))
+            if fault is None:
+                # copies survive the next iteration's donated sweep
+                last_good = (device_copy(U), device_copy(V))
+                return True
         if last_good is None:
             raise fault
         logger.error("ALS %s — rolling back to iteration %d and "
@@ -662,11 +696,11 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
             gram_v = gram_of(V[:ratings.n_items]) if cfg.implicit_prefs \
                 else None
             U = _run_side(user_batches, U, V, cfg, gram_v, lam_dev,
-                          alpha_dev)
+                          alpha_dev, side="user")
             gram_u = gram_of(U[:ratings.n_users]) if cfg.implicit_prefs \
                 else None
             V = _run_side(item_batches, V, U, cfg, gram_u, lam_dev,
-                          alpha_dev)
+                          alpha_dev, side="item")
             if not _checked(it):
                 break
             _first_iteration_done(it)
@@ -683,6 +717,16 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
         telemetry["s_per_iter"] = (telemetry["iters_s"]
                                    / max(cfg.iterations, 1))
         t0 = _time.perf_counter()
+    with TRACER.region("train.fetch"):
+        return _fetch_model(U, V, ratings, cfg, mesh, telemetry, t0)
+
+
+def _fetch_model(U, V, ratings: RatingsCOO, cfg: ALSConfig,
+                 mesh: MeshContext, telemetry: Optional[dict],
+                 t0: float) -> ALSModel:
+    """The trained tables leave the device: `als_train`'s last step."""
+    import time as _time
+
     from predictionio_tpu.parallel.mesh import host_fetch
     if cfg.factor_sharding == "model" and cfg.keep_sharded:
         # sharded online plane: the tables leave training as
@@ -730,12 +774,15 @@ def _user_topk(user_factors, item_factors, user_ix, exclude_ix, k: int):
     host->device per query — the factor tables are device-resident."""
     import jax
     import jax.numpy as jnp
-    u = user_factors[user_ix]                                  # [R]
-    scores = jnp.einsum("ir,r->i", item_factors, u,
-                        preferred_element_type=jnp.float32)
-    safe = jnp.where(exclude_ix < 0, scores.shape[0], exclude_ix)
-    scores = scores.at[safe].set(-jnp.inf, mode="drop")
-    return jax.lax.top_k(scores, k)
+    with jax.named_scope("pio.serve.user_rows"):
+        u = user_factors[user_ix]                              # [R]
+    with jax.named_scope("pio.serve.score"):
+        scores = jnp.einsum("ir,r->i", item_factors, u,
+                            preferred_element_type=jnp.float32)
+        safe = jnp.where(exclude_ix < 0, scores.shape[0], exclude_ix)
+        scores = scores.at[safe].set(-jnp.inf, mode="drop")
+    with jax.named_scope("pio.serve.topk"):
+        return jax.lax.top_k(scores, k)
 
 
 def _pad_exclude(exclude, multiple: int = 64) -> np.ndarray:
@@ -755,10 +802,13 @@ def _users_topk(user_factors, item_factors, user_ixs, k: int):
     Serving dispatches the bucketed path."""
     import jax
     import jax.numpy as jnp
-    u = user_factors[user_ixs]                                # [B, R]
-    scores = jnp.einsum("br,ir->bi", u, item_factors,
-                        preferred_element_type=jnp.float32)
-    return jax.lax.top_k(scores, k)
+    with jax.named_scope("pio.serve.user_rows"):
+        u = user_factors[user_ixs]                            # [B, R]
+    with jax.named_scope("pio.serve.score"):
+        scores = jnp.einsum("br,ir->bi", u, item_factors,
+                            preferred_element_type=jnp.float32)
+    with jax.named_scope("pio.serve.topk"):
+        return jax.lax.top_k(scores, k)
 
 
 def _users_topk_impl(user_factors, item_factors, user_ixs, n_items,
@@ -768,12 +818,15 @@ def _users_topk_impl(user_factors, item_factors, user_ixs, n_items,
     below, so both variants rank identically)."""
     import jax
     import jax.numpy as jnp
-    u = user_factors[user_ixs]                                # [B, R]
-    scores = jnp.einsum("br,ir->bi", u, item_factors,
-                        preferred_element_type=jnp.float32)
-    valid = jnp.arange(item_factors.shape[0]) < n_items
-    scores = jnp.where(valid[None, :], scores, -jnp.inf)
-    return jax.lax.top_k(scores, k)
+    with jax.named_scope("pio.serve.user_rows"):
+        u = user_factors[user_ixs]                            # [B, R]
+    with jax.named_scope("pio.serve.score"):
+        scores = jnp.einsum("br,ir->bi", u, item_factors,
+                            preferred_element_type=jnp.float32)
+        valid = jnp.arange(item_factors.shape[0]) < n_items
+        scores = jnp.where(valid[None, :], scores, -jnp.inf)
+    with jax.named_scope("pio.serve.topk"):
+        return jax.lax.top_k(scores, k)
 
 
 @functools.partial(__import__("jax").jit, static_argnames=("k",))

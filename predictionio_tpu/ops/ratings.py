@@ -21,6 +21,8 @@ from typing import List, Sequence, Tuple
 
 import numpy as np
 
+from predictionio_tpu.obs import TRACER
+
 
 @dataclass(frozen=True)
 class RatingsCOO:
@@ -244,8 +246,12 @@ def build_solve_plan(group_idx: np.ndarray, counter_idx: np.ndarray,
 
 
 def plan_for_users(r: RatingsCOO, **kw) -> SolvePlan:
-    return build_solve_plan(r.user_idx, r.item_idx, r.rating, r.n_users, **kw)
+    with TRACER.region("train.plan", side="user"):
+        return build_solve_plan(r.user_idx, r.item_idx, r.rating,
+                                r.n_users, **kw)
 
 
 def plan_for_items(r: RatingsCOO, **kw) -> SolvePlan:
-    return build_solve_plan(r.item_idx, r.user_idx, r.rating, r.n_items, **kw)
+    with TRACER.region("train.plan", side="item"):
+        return build_solve_plan(r.item_idx, r.user_idx, r.rating,
+                                r.n_items, **kw)

@@ -51,7 +51,7 @@ from typing import Callable, Optional, Tuple
 
 import numpy as np
 
-from predictionio_tpu.obs import jaxmon, tenantctx
+from predictionio_tpu.obs import TRACER, jaxmon, tenantctx
 from predictionio_tpu.obs.metrics import get_registry
 
 # -- pack modes (the AOT bucket dim ``p``) -------------------------------
@@ -91,16 +91,18 @@ def pack_device(scores, idx, p: int):
     then 2 or 4 score bytes per slot, device-native little-endian).
     Must be traced inside the serve kernel's jit so the executable
     emits the packed aval directly (one output buffer, one transfer)."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
-    ids8 = lax.bitcast_convert_type(idx.astype(jnp.int32), jnp.uint8)
-    if p == PACK_EXACT:
-        sc8 = lax.bitcast_convert_type(scores.astype(jnp.float32),
-                                       jnp.uint8)
-    else:
-        sc8 = lax.bitcast_convert_type(scores.astype(jnp.float16),
-                                       jnp.uint8)
-    return jnp.concatenate([ids8, sc8], axis=-1)
+    with jax.named_scope("pio.serve.pack"):
+        ids8 = lax.bitcast_convert_type(idx.astype(jnp.int32), jnp.uint8)
+        if p == PACK_EXACT:
+            sc8 = lax.bitcast_convert_type(scores.astype(jnp.float32),
+                                           jnp.uint8)
+        else:
+            sc8 = lax.bitcast_convert_type(scores.astype(jnp.float16),
+                                           jnp.uint8)
+        return jnp.concatenate([ids8, sc8], axis=-1)
 
 
 def unpack_host(buf: np.ndarray, p: int
@@ -177,6 +179,13 @@ def thread_wait_s() -> float:
     return getattr(_TLS, "wait_s", 0.0)
 
 
+def thread_ready_t() -> float:
+    """``time.perf_counter()`` at which THIS thread's last readback wait
+    returned (0.0 before the first): the d2h-ready instant of the
+    serving account's dispatch record."""
+    return getattr(_TLS, "t_ready", 0.0)
+
+
 def thread_d2h_bytes() -> int:
     """Bytes THIS thread has fetched through the readback plane,
     cumulative — same delta-sampling contract as :func:`thread_wait_s`."""
@@ -208,9 +217,11 @@ def begin_fetch(*arrays, tenant: Optional[str] = None
 
     def wait() -> Tuple[np.ndarray, ...]:
         t1 = time.perf_counter()
-        host = tuple(np.asarray(a) for a in arrays)
+        with TRACER.region("readback.wait"):
+            host = tuple(np.asarray(a) for a in arrays)
         t2 = time.perf_counter()
         wait_s = t2 - t1
+        _TLS.t_ready = t2
         nbytes = sum(int(h.nbytes) for h in host)
         _TLS.wait_s = getattr(_TLS, "wait_s", 0.0) + wait_s
         _TLS.bytes = getattr(_TLS, "bytes", 0) + nbytes

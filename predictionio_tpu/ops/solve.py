@@ -113,8 +113,16 @@ def _schulz_kernel(a_ref, b_ref, x_ref, *, iters: int, compute_dtype):
     x_ref[:] = x
 
 
+def _kernel_name(kind: str, system: str, A) -> str:
+    """What a device trace calls one Pallas solve: the solver, whether
+    its systems are a sweep's primal (R x R) or dual (K x K) ones, and
+    their static size, e.g. ``pio_cg_dual_b11920_n176``."""
+    return f"pio_{kind}_{system}_b{A.shape[0]}_n{A.shape[-1]}"
+
+
 def schulz_solve_pallas(A, b, iters: int | None = None,
-                        compute_dtype="bfloat16", tile: int = 8):
+                        compute_dtype="bfloat16", tile: int = 8,
+                        system: str = "primal"):
     """TPU kernel: grid over batch tiles; each tile's inverse iterate lives
     in VMEM for all `iters` Schulz steps, so HBM traffic is one read of A +
     one write of x (vs one read/write of [B,R,R] per step for the XLA
@@ -147,6 +155,7 @@ def schulz_solve_pallas(A, b, iters: int | None = None,
         ],
         out_specs=pl.BlockSpec((tile, rank), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
+        name=_kernel_name("schulz", system, A),
     )(A.astype(jnp.float32), b)
     return x[:B]
 
@@ -235,7 +244,8 @@ def _cg_kernel(a_ref, b_ref, x_ref, *, iters: int):
     x_ref[:] = x
 
 
-def cg_solve_pallas(A, b, iters: int = 48, tile: int = 16):
+def cg_solve_pallas(A, b, iters: int = 48, tile: int = 16,
+                    system: str = "primal"):
     """TPU production solver: grid over batch tiles of 16 entities, each
     tile's [16, R, R] system VMEM-resident across all CG iterations.
     Measured (v5e, B=2048, R=200): ~27 ms vs 140 ms for XLA batched
@@ -270,6 +280,7 @@ def cg_solve_pallas(A, b, iters: int = 48, tile: int = 16):
                                memory_space=pltpu.VMEM),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
+        name=_kernel_name("cg", system, A),
     )(A.astype(jnp.float32), b)
     return x[:B]
 
@@ -400,7 +411,8 @@ def _chol_kernel(a_ref, b_ref, x_ref, *, panel: int):
 
 
 def cholesky_solve_pallas(A, b, tile: int = 8, panel: int = 8,
-                          interpret: bool = False):
+                          interpret: bool = False,
+                          system: str = "primal"):
     """MXU-packed panel factorization: grid over batch tiles; each tile's
     [tile, R, R] systems are factorized in VMEM with panel-width trailing
     updates as batched matmuls (the MXU share grows as R^3/3 while the
@@ -444,6 +456,7 @@ def cholesky_solve_pallas(A, b, tile: int = 8, panel: int = 8,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
         interpret=interpret,
+        name=_kernel_name("chol", system, A),
     )(A.astype(jnp.float32), b)
     return x[:B, :rank]
 
@@ -461,11 +474,13 @@ def resolve_solver(method: str, n_devices: int = 1) -> str:
 
 
 def spd_solve(A, b, method: str = "auto", iters: int | None = None,
-              compute_dtype: str = "bfloat16"):
+              compute_dtype: str = "bfloat16", system: str = "primal"):
     """Batched SPD solve with backend-appropriate method selection.
 
     method: 'auto' | 'cholesky' | 'cg' | 'cg_pallas' | 'schulz' |
             'schulz_pallas'
+    system: 'primal' | 'dual', which of a sweep's systems these are:
+            only names the Pallas kernels in a device trace.
     """
     if method == "auto":
         method = resolve_solver(method)
@@ -474,13 +489,14 @@ def spd_solve(A, b, method: str = "auto", iters: int | None = None,
     if method == "cg":
         return cg_solve(A, b, iters or 48)
     if method == "cg_pallas":
-        return cg_solve_pallas(A, b, iters or 48)
+        return cg_solve_pallas(A, b, iters or 48, system=system)
     if method == "schulz":
         return schulz_solve(A, b, iters, compute_dtype)
     if method == "schulz_pallas":
-        return schulz_solve_pallas(A, b, iters, compute_dtype)
+        return schulz_solve_pallas(A, b, iters, compute_dtype,
+                                   system=system)
     if method == "chol_pallas":
-        return cholesky_solve_pallas(A, b)
+        return cholesky_solve_pallas(A, b, system=system)
     if method == "chol_blocked":   # jnp form (any backend / GSPMD meshes)
         return _blocked_cholesky_solve(A, b)
     raise ValueError(f"unknown solver {method!r}")

@@ -48,17 +48,20 @@ def sharded_top_k(item_factors_sharded, query_vec, k: int,
         out_specs=(P(), P()),
         check_vma=False)
     def _local_then_global(v_shard, q, mask_shard):
-        scores = jnp.einsum("ir,r->i", v_shard, q,
-                            preferred_element_type=jnp.float32)
-        scores = jnp.where(mask_shard, scores, -jnp.inf)
-        local_s, local_i = jax.lax.top_k(scores, k_local)
-        # globalize indices: shard offset from the model-axis position
-        ax = jax.lax.axis_index("model")
-        local_i = local_i + ax * v_shard.shape[0]
-        all_s = jax.lax.all_gather(local_s, "model").reshape(-1)
-        all_i = jax.lax.all_gather(local_i, "model").reshape(-1)
-        top_s, pos = jax.lax.top_k(all_s, k_final)
-        return top_s, all_i[pos]
+        with jax.named_scope("pio.serve.score"):
+            scores = jnp.einsum("ir,r->i", v_shard, q,
+                                preferred_element_type=jnp.float32)
+            scores = jnp.where(mask_shard, scores, -jnp.inf)
+        with jax.named_scope("pio.serve.topk"):
+            local_s, local_i = jax.lax.top_k(scores, k_local)
+            # globalize indices: shard offset from the model-axis
+            # position
+            ax = jax.lax.axis_index("model")
+            local_i = local_i + ax * v_shard.shape[0]
+            all_s = jax.lax.all_gather(local_s, "model").reshape(-1)
+            all_i = jax.lax.all_gather(local_i, "model").reshape(-1)
+            top_s, pos = jax.lax.top_k(all_s, k_final)
+            return top_s, all_i[pos]
 
     if allowed_mask_sharded is None:
         allowed_mask_sharded = jax.device_put(
@@ -127,28 +130,30 @@ def make_batched_sharded_topk(mesh: MeshContext, k_local: int,
                        in_specs=tuple(in_specs), out_specs=out_specs,
                        check_vma=False)
     def _kernel(q, v_shard, n_items, *mask):
-        scores = jnp.einsum("br,ir->bi", q, v_shard,
-                            preferred_element_type=jnp.float32)
-        ax = jax.lax.axis_index("model")
-        base = ax * v_shard.shape[0]
-        # bucket-padding rows (global index >= n_items) rank last
-        valid = (jnp.arange(v_shard.shape[0]) + base) < n_items
-        allowed = valid[None, :]
-        if has_mask:
-            allowed = allowed & mask[0]
-        if filter_positive:
-            allowed = allowed & (scores > 0)
-        scores = jnp.where(allowed, scores, -jnp.inf)
-        local_s, local_i = jax.lax.top_k(scores, k_local)
-        local_i = local_i + base
-        all_s = jnp.moveaxis(
-            jax.lax.all_gather(local_s, "model"), 0, 1
-        ).reshape(local_s.shape[0], -1)
-        all_i = jnp.moveaxis(
-            jax.lax.all_gather(local_i, "model"), 0, 1
-        ).reshape(local_i.shape[0], -1)
-        top_s, pos = jax.lax.top_k(all_s, k_final)
-        top_i = jnp.take_along_axis(all_i, pos, axis=1)
+        with jax.named_scope("pio.serve.score"):
+            scores = jnp.einsum("br,ir->bi", q, v_shard,
+                                preferred_element_type=jnp.float32)
+            ax = jax.lax.axis_index("model")
+            base = ax * v_shard.shape[0]
+            # bucket-padding rows (global index >= n_items) rank last
+            valid = (jnp.arange(v_shard.shape[0]) + base) < n_items
+            allowed = valid[None, :]
+            if has_mask:
+                allowed = allowed & mask[0]
+            if filter_positive:
+                allowed = allowed & (scores > 0)
+            scores = jnp.where(allowed, scores, -jnp.inf)
+        with jax.named_scope("pio.serve.topk"):
+            local_s, local_i = jax.lax.top_k(scores, k_local)
+            local_i = local_i + base
+            all_s = jnp.moveaxis(
+                jax.lax.all_gather(local_s, "model"), 0, 1
+            ).reshape(local_s.shape[0], -1)
+            all_i = jnp.moveaxis(
+                jax.lax.all_gather(local_i, "model"), 0, 1
+            ).reshape(local_i.shape[0], -1)
+            top_s, pos = jax.lax.top_k(all_s, k_final)
+            top_i = jnp.take_along_axis(all_i, pos, axis=1)
         if pack:
             from predictionio_tpu.ops import readback
             return readback.pack_device(top_s, top_i, pack)

@@ -115,7 +115,7 @@ class ShutdownError(RuntimeError):
 
 class _Pending:
     __slots__ = ("query", "event", "result", "error", "t_enqueue",
-                 "trace_id", "batch_trace_id")
+                 "trace_id", "batch_trace_id", "seq", "t_result")
 
     def __init__(self, query):
         self.query = query
@@ -127,21 +127,31 @@ class _Pending:
         # loop links it to the batch_predict trace (and back)
         self.trace_id: Optional[str] = None
         self.batch_trace_id: Optional[str] = None
+        # the serving account (ISSUE 25): the dispatch that answered it
+        # and when its result was set, for the request's own record
+        self.seq = -1
+        self.t_result = 0.0
 
 
 class _InFlight:
     """One dispatched-not-completed window riding the completion
     queue: its members, the deferred finish() closure, the (open)
-    batch_predict trace, and the dispatch timestamps."""
+    batch_predict trace, the dispatch timestamps, and the front of its
+    record in the serving account (obs/trace DISPATCH_FIELDS up to
+    ``t_gate``), which the completion thread finishes."""
 
-    __slots__ = ("batch", "finish", "trace", "t_dispatch", "t_ready")
+    __slots__ = ("batch", "finish", "trace", "t_dispatch", "t_ready",
+                 "account", "sync_s")
 
-    def __init__(self, batch, finish, trace, t_dispatch):
+    def __init__(self, batch, finish, trace, t_dispatch, t_ready,
+                 account, sync_s):
         self.batch = batch
         self.finish = finish
         self.trace = trace
         self.t_dispatch = t_dispatch
-        self.t_ready = time.perf_counter()
+        self.t_ready = t_ready          # process_batch_begin returned
+        self.account = account
+        self.sync_s = sync_s
 
 
 class MicroBatcher:
@@ -261,13 +271,16 @@ class MicroBatcher:
                 "completion_wait = dispatched -> completion thread "
                 "pickup, readback = blocked on the in-flight d2h copy "
                 "(ops/readback wait), completion = post-process + "
-                "waiter wakeup minus the readback wait)",
+                "waiter wakeup minus the readback wait; gate = the wait "
+                "on the in-flight cap, begin = dispatch - gate, "
+                "turnaround = begin returned -> d2h ready)",
                 labelnames=("stage",))
             # children resolved eagerly (the ISSUE 6 self-metrics
             # precedent): a quiet server scrapes zeroed stage series,
             # not an empty family
             for st in ("formation", "dispatch", "completion_wait",
-                       "readback", "completion"):
+                       "readback", "completion", "gate", "begin",
+                       "turnaround"):
                 self.stage_hist.labels(stage=st)
             metrics.counter_func(
                 "pio_engine_batches_total", "Micro-batch dispatches",
@@ -425,6 +438,7 @@ class MicroBatcher:
             self._q.put(p)
         with TRACER.span("batch_wait"):
             p.event.wait()
+        TRACER.note_request(p.t_enqueue, p.t_result, p.seq, self.tenant)
         if p.batch_trace_id is not None:
             # tie this query's ingress trace to the coalesced window
             # that answered it (the dispatch loop recorded the reverse
@@ -489,6 +503,9 @@ class MicroBatcher:
             _tenant_var.set(self.tenant)
 
     def _loop(self):
+        from predictionio_tpu.compile.buckets import bucket_batch
+        from predictionio_tpu.obs import TRACER, costmon
+        from predictionio_tpu.obs.trace import DISPATCH
         self._enter_tenant()
         while not self._stop.is_set():
             try:
@@ -496,47 +513,8 @@ class MicroBatcher:
             except queue.Empty:
                 continue
             t_first = time.perf_counter()   # batch-formation stage t0
-            batch = [first]
-            # Drain-first batching: take the backlog that accumulated
-            # while the previous batch was on the device (the
-            # self-regulating coalescing), then hold the door open ONLY
-            # while more queries are known in flight (submitted,
-            # unanswered, not yet dispatched, not in this batch) —
-            # i.e. between their counter increment and queue put,
-            # microseconds away. When batch == undispatched nobody else
-            # is known to be coming: a closed-loop serial client, or an
-            # idle server, dispatches with zero window cost. The
-            # (adaptive) window bounds the hold in case a counted
-            # straggler stalls before reaching the queue; the adaptive
-            # target dispatches at a pow2 boundary once demand is
-            # covered.
-            held = False
-            exit_reason = "full"   # loop falls through => max_batch hit
-            deadline = self._window_deadline(t_first, first)
-            target = self._target_batch() if self.adaptive \
-                else self.max_batch
-            while len(batch) < self.max_batch:
-                try:
-                    batch.append(self._q.get_nowait())
-                    continue
-                except queue.Empty:
-                    pass
-                if self._undispatched <= len(batch):
-                    exit_reason = "drain_gate"
-                    break          # nobody else known in flight
-                if self.adaptive and len(batch) >= target:
-                    exit_reason = "adaptive"
-                    break          # demand target (pow2) covered
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    exit_reason = "window"
-                    break
-                held = True
-                try:
-                    batch.append(self._q.get(timeout=remaining))
-                except queue.Empty:
-                    exit_reason = "window"
-                    break
+            with TRACER.region("batch.form"):
+                batch, held, exit_reason = self._form_batch(first, t_first)
             self.n_batches += 1
             self.n_queries += len(batch)
             self.max_batch_seen = max(self.max_batch_seen, len(batch))
@@ -576,17 +554,23 @@ class MicroBatcher:
             if self.pipelined:
                 self._dispatch_pipelined(batch, t_first, t_dispatch)
                 continue
+            seq = TRACER.next_dispatch_seq()
+            sync0 = costmon.thread_sync_s()
+            t_ready = t_dispatch
             try:
-                results = self._run_batch(
-                    batch, formation_s=t_dispatch - t_first)
+                with TRACER.region("batch.begin"):
+                    results = self._run_batch(
+                        batch, formation_s=t_dispatch - t_first)
                 if len(results) != len(batch):
                     raise RuntimeError(
                         f"batch handler returned {len(results)} results "
                         f"for {len(batch)} queries")
                 with self._flight_lock:
                     self._inflight -= len(batch)
+                t_ready = time.perf_counter()
                 for p, r in zip(batch, results):
                     p.result = r
+                    p.seq, p.t_result = seq, t_ready
                     p.event.set()
             except BaseException as e:  # propagate to every waiter
                 with self._flight_lock:
@@ -594,10 +578,65 @@ class MicroBatcher:
                 for p in batch:
                     p.error = e
                     p.event.set()
+            t_done = time.perf_counter()
+            # one synchronous call is begin, device and readback at once:
+            # the account's gate is the batch's close, and begin, pickup
+            # and d2h-ready are the call's return
+            TRACER.record(DISPATCH, (
+                seq, first.t_enqueue, t_first, t_dispatch, t_dispatch,
+                t_ready, t_ready, t_ready, t_done, len(batch),
+                bucket_batch(len(batch)),
+                costmon.thread_sync_s() - sync0, self.tenant))
             # EWMA of batch service time: the queue wait bound's basis.
             # Updated on the dispatch thread only; alpha 0.2 smooths
             # device-warmup spikes without lagging a real slowdown.
-            self._note_service_time(time.perf_counter() - t_dispatch)
+            self._note_service_time(t_done - t_dispatch)
+
+    def _form_batch(self, first: _Pending, t_first: float):
+        """Coalesce one window behind ``first``: (batch, whether the
+        door was ever held open, why it closed)."""
+        batch = [first]
+        # Drain-first batching: take the backlog that accumulated
+        # while the previous batch was on the device (the
+        # self-regulating coalescing), then hold the door open ONLY
+        # while more queries are known in flight (submitted,
+        # unanswered, not yet dispatched, not in this batch) —
+        # i.e. between their counter increment and queue put,
+        # microseconds away. When batch == undispatched nobody else
+        # is known to be coming: a closed-loop serial client, or an
+        # idle server, dispatches with zero window cost. The
+        # (adaptive) window bounds the hold in case a counted
+        # straggler stalls before reaching the queue; the adaptive
+        # target dispatches at a pow2 boundary once demand is
+        # covered.
+        held = False
+        exit_reason = "full"   # loop falls through => max_batch hit
+        deadline = self._window_deadline(t_first, first)
+        target = self._target_batch() if self.adaptive \
+            else self.max_batch
+        while len(batch) < self.max_batch:
+            try:
+                batch.append(self._q.get_nowait())
+                continue
+            except queue.Empty:
+                pass
+            if self._undispatched <= len(batch):
+                exit_reason = "drain_gate"
+                break          # nobody else known in flight
+            if self.adaptive and len(batch) >= target:
+                exit_reason = "adaptive"
+                break          # demand target (pow2) covered
+            remaining = deadline - time.perf_counter()
+            if remaining <= 0:
+                exit_reason = "window"
+                break
+            held = True
+            try:
+                batch.append(self._q.get(timeout=remaining))
+            except queue.Empty:
+                exit_reason = "window"
+                break
+        return batch, held, exit_reason
 
     def _note_service_time(self, dt: float):
         self._service_ewma_s = (dt if self._service_ewma_s == 0.0
@@ -620,17 +659,20 @@ class MicroBatcher:
         at most ``inflight`` windows sit between dispatch and
         completion (backpressure onto formation, and transitively onto
         the admission queue + shed bound)."""
-        from predictionio_tpu.obs import TRACER
+        from predictionio_tpu.obs import TRACER, costmon
         if not self._inflight_sem.acquire(blocking=False):
             # the device/completion side is the bottleneck right now:
             # count the stall once, then wait (poll so stop() can't be
             # held hostage by a wedged completion)
             self.n_pipeline_stalls += 1
-            while not self._inflight_sem.acquire(timeout=0.1):
-                if self._stop.is_set():
-                    self.n_shutdown_failed += len(batch)
-                    self._fail_batch(batch, ShutdownError())
-                    return
+            with TRACER.region("batch.gate"):
+                while not self._inflight_sem.acquire(timeout=0.1):
+                    if self._stop.is_set():
+                        self.n_shutdown_failed += len(batch)
+                        self._fail_batch(batch, ShutdownError())
+                        return
+        t_gate = time.perf_counter()
+        sync0 = costmon.thread_sync_s()
         member_traces = [p.trace_id for p in batch if p.trace_id]
         bt = None
         if member_traces:
@@ -645,10 +687,11 @@ class MicroBatcher:
         try:
             queries = [p.query for p in batch]
             if bt is not None:
-                with TRACER.resume(bt):
+                with TRACER.resume(bt), TRACER.region("batch.begin"):
                     finish = self.process_batch_begin(queries)
             else:
-                finish = self.process_batch_begin(queries)
+                with TRACER.region("batch.begin"):
+                    finish = self.process_batch_begin(queries)
         except BaseException as e:
             self._inflight_sem.release()
             if bt is not None:
@@ -659,12 +702,21 @@ class MicroBatcher:
             self._fail_batch(batch, e)
             self._note_service_time(time.perf_counter() - t_dispatch)
             return
+        t_begin = time.perf_counter()
         if self.stage_hist is not None:
             self.stage_hist.labels(stage="dispatch").observe(
-                time.perf_counter() - t_dispatch)
+                t_begin - t_dispatch)
+            self.stage_hist.labels(stage="gate").observe(
+                t_gate - t_dispatch)
+            self.stage_hist.labels(stage="begin").observe(
+                t_begin - t_gate)
         with self._flight_lock:
             self._inflight_batches += 1
-        self._completions.put(_InFlight(batch, finish, bt, t_dispatch))
+        self._completions.put(_InFlight(
+            batch, finish, bt, t_dispatch, t_begin,
+            (TRACER.next_dispatch_seq(), batch[0].t_enqueue, t_first,
+             t_dispatch, t_gate),
+            costmon.thread_sync_s() - sync0))
 
     def _note_exc(self, bt):
         """Commit an open batch trace from an error path."""
@@ -684,7 +736,9 @@ class MicroBatcher:
         window, result fan-out, in-flight bookkeeping. Runs on the
         dedicated completion thread — overlapping the formation
         thread's next window and the device's current one."""
+        from predictionio_tpu.compile.buckets import bucket_batch
         from predictionio_tpu.obs import TRACER
+        from predictionio_tpu.obs.trace import DISPATCH
         from predictionio_tpu.ops import readback as _readback
         batch, finish, bt = item.batch, item.finish, item.trace
         t_c0 = time.perf_counter()
@@ -700,10 +754,12 @@ class MicroBatcher:
             if bt is not None:
                 bt.root.attrs["completionWaitMs"] = round(
                     wait_s * 1000.0, 3)
-                with TRACER.resume(bt, commit=True):
+                with TRACER.resume(bt, commit=True), \
+                        TRACER.region("batch.post"):
                     results = finish()
             else:
-                results = finish()
+                with TRACER.region("batch.post"):
+                    results = finish()
             if len(results) != len(batch):
                 raise RuntimeError(
                     f"batch handler returned {len(results)} results "
@@ -720,18 +776,30 @@ class MicroBatcher:
         with self._flight_lock:
             self._inflight -= len(batch)
             self._inflight_batches -= 1
-        for p, r in zip(batch, results):
-            p.result = r
-            p.event.set()
+        seq = item.account[0]
+        t_result = time.perf_counter()
+        with TRACER.region("batch.wake"):
+            for p, r in zip(batch, results):
+                p.result = r
+                p.seq, p.t_result = seq, t_result
+                p.event.set()
+        t_done = time.perf_counter()
+        # d2h ready: when this thread's last readback wait returned (a
+        # handler that fetched nothing was ready at pickup)
+        t_d2h = max(t_c0, _readback.thread_ready_t())
         if self.stage_hist is not None:
             rb_s = max(0.0, _readback.thread_wait_s() - rb0)
-            total_s = time.perf_counter() - t_c0
             self.stage_hist.labels(stage="completion_wait").observe(
                 wait_s)
             self.stage_hist.labels(stage="readback").observe(rb_s)
             self.stage_hist.labels(stage="completion").observe(
-                max(0.0, total_s - rb_s))
-        self._note_service_time(time.perf_counter() - item.t_dispatch)
+                max(0.0, t_done - t_c0 - rb_s))
+            self.stage_hist.labels(stage="turnaround").observe(
+                t_d2h - item.t_ready)
+        TRACER.record(DISPATCH, item.account + (
+            item.t_ready, t_c0, t_d2h, t_done, len(batch),
+            bucket_batch(len(batch)), item.sync_s, self.tenant))
+        self._note_service_time(t_done - item.t_dispatch)
 
     def _run_batch(self, batch, formation_s: float = 0.0):
         """One synchronous dispatch (non-pipelined mode). When any

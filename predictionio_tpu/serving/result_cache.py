@@ -40,6 +40,8 @@ import os
 import threading
 from typing import Dict, Iterable, List, Optional, Tuple
 
+from predictionio_tpu.obs import TRACER
+
 #: query-dict fields that name cacheable entities, and the tag prefix
 #: their values register under (the invalidation join key)
 _ENTITY_FIELDS = (("user", "user"), ("item", "item"), ("items", "item"))
@@ -216,9 +218,10 @@ class ResultCache:
         giant response must not evict the whole hot set)."""
         if key is None or len(body) > self.max_bytes // 4:
             return False
-        with self._lock:
+        with TRACER.region("cache.put") as span, self._lock:
             if generation is not None and generation != self.generation:
                 return False
+            evicted0 = self.evictions
             old = self._entries.pop(key, None)
             if old is not None:
                 self._unindex(key, old)
@@ -237,6 +240,8 @@ class ResultCache:
                 self._unindex(k, victim)
                 self._bytes -= victim.nbytes
                 self.evictions += 1
+            if span is not None and self.evictions > evicted0:
+                span.attrs["evictions"] = self.evictions - evicted0
         return True
 
     def _unindex(self, key: str, e: _Entry):

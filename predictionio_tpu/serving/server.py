@@ -1110,9 +1110,13 @@ class EngineServer:
         # client BEFORE the JSON body is even parsed.
         from predictionio_tpu.serving import result_cache as RC
         key = generation = None
+        # the serving account's request record: a cache hit or a
+        # refusal keeps dispatch -1, the batcher's submit fills it in
+        TRACER.note_request(tenant=self.tenant)
         cacheable = self._cache_usable()
         if cacheable:
-            body = self.result_cache.get_raw(req.body)
+            with TRACER.region("query.cache_lookup"):
+                body = self.result_cache.get_raw(req.body)
             if body is not None:
                 return self._serve_cache_hit(body, t_q0)
         d = req.json()
@@ -1120,7 +1124,8 @@ class EngineServer:
             raise ValueError("query must be a JSON object")
         if cacheable:
             key = RC.query_key(d)
-            body = self.result_cache.get(key)
+            with TRACER.region("query.cache_lookup"):
+                body = self.result_cache.get(key)
             if body is not None:
                 return self._serve_cache_hit(body, t_q0)
             # store-time freshness fence: any invalidation landing
@@ -1179,26 +1184,30 @@ class EngineServer:
         """Build + record the slow request's waterfall; never raises
         into the response path."""
         try:
-            # the serialize stage IS a second json.dumps of the
-            # response: tens of µs on a request that already took
-            # >=250 ms (<0.05%), paid only on the slow path — and when
-            # the payload is big enough for this to matter, a
-            # serialize-dominated tail is exactly the diagnosis the
-            # stage exists to surface
-            t0 = time.perf_counter()
-            try:
-                json.dumps(out, default=str)
-            except Exception:
-                pass
-            serialize_s = time.perf_counter() - t0
-            # the batcher's submit() linked the coalesced window's
-            # batch_predict trace onto this query trace
-            batch_tid = next(iter(qt.links), None)
-            capture_slow_query(qt, total_s, query=query_dict,
-                               model_version=self.model_version,
-                               serialize_s=serialize_s,
-                               batch_trace_id=batch_tid,
-                               tenant=self.tenant)
+            with TRACER.region("slow.capture"):
+                # the serialize stage IS a second json.dumps of the
+                # response: tens of µs on a request that already took
+                # >=250 ms (<0.05%), paid only on the slow path — and
+                # when the payload is big enough for this to matter, a
+                # serialize-dominated tail is exactly the diagnosis the
+                # stage exists to surface
+                t0 = time.perf_counter()
+                try:
+                    json.dumps(out, default=str)
+                except Exception:
+                    pass
+                serialize_s = time.perf_counter() - t0
+                # the batcher's submit() linked the coalesced window's
+                # batch_predict trace onto this query trace, and noted
+                # the dispatch's sequence number for this thread
+                batch_tid = next(iter(qt.links), None)
+                capture_slow_query(
+                    qt, total_s, query=query_dict,
+                    model_version=self.model_version,
+                    serialize_s=serialize_s, batch_trace_id=batch_tid,
+                    tenant=self.tenant,
+                    dispatch=TRACER.dispatch_record(
+                        TRACER.noted_dispatch_seq()))
         except Exception:
             logger.debug("slow-query capture failed", exc_info=True)
 
@@ -1488,6 +1497,7 @@ class EngineServer:
         # a p99 postmortem never starts with "restart with profiling"
         from predictionio_tpu.obs import profiler
         profiler.ensure_started()
+        TRACER.watch_gc(True)
         srv = HttpServer(self.router, self.config.ip, self.config.port)
         self.server = srv
 
@@ -1526,6 +1536,7 @@ class EngineServer:
         fleet.deregister_member(fleet_id)
         if self.server:
             self.server.stop()
+            TRACER.watch_gc(False)
         if self.batcher is not None:
             self.batcher.stop()
         if self.coordinator is not None:

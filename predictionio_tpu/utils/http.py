@@ -15,10 +15,13 @@ import json
 import logging
 import re
 import threading
+import time
 import urllib.parse
 from dataclasses import dataclass
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from predictionio_tpu.obs.trace import TRACER
 
 logger = logging.getLogger(__name__)
 
@@ -224,6 +227,15 @@ class HttpServer:
             disable_nagle_algorithm = True
 
             def _handle(self):
+                # pio.http.request: request parsed -> last byte written;
+                # a query answered on this thread leaves its record in
+                # the serving account (obs/trace REQUEST_FIELDS)
+                t_start = time.perf_counter()
+                with TRACER.region("http.request"):
+                    self._answer()
+                TRACER.request_written(t_start, time.perf_counter())
+
+            def _answer(self):
                 parsed = urllib.parse.urlsplit(self.path)
                 # keep_blank_values: `targetEntityType=` (empty string)
                 # is meaningful — the event API maps it to "target
@@ -294,7 +306,6 @@ class HttpServer:
               retry_delay: float = 1.0):
         # bind retry x3 mirrors the reference MasterActor
         # (CreateServer.scala:363-373)
-        import time as _time
         self._has_served = False   # new lifecycle attempt begins
         last_err = None
         for attempt in range(bind_retries):
@@ -307,7 +318,7 @@ class HttpServer:
                 logger.warning("bind %s:%d failed (%s), retry %d/%d",
                                self.host, self.port, e, attempt + 1,
                                bind_retries)
-                _time.sleep(retry_delay)
+                time.sleep(retry_delay)
         else:
             raise last_err
         self.port = self._httpd.server_address[1]  # resolve port 0
