@@ -811,15 +811,61 @@ def _users_topk(user_factors, item_factors, user_ixs, k: int):
         return jax.lax.top_k(scores, k)
 
 
+# Table rows that one pass over the whole table handles in the time of
+# one row read: ~1.5 us a read against ~1.9 ns a table row of the gather's
+# copy, on a v5e at rank 200 (PERF.md, PR 26: ~750, rounded up to a pow2).
+_ROWS_PER_READ = 1024
+
+
+def _batch_rows(table, ixs):
+    """``table[ixs]`` for a static batch of row indices, with the same
+    treatment of a bad index (a negative one wraps once, then clamps).
+    Why it is not written ``table[ixs]``: see :func:`_users_topk_impl`."""
+    import jax
+    import jax.numpy as jnp
+    n_rows, rank = table.shape
+    if ixs.shape[0] * _ROWS_PER_READ > n_rows:
+        return table[ixs]
+    return jnp.concatenate(
+        [jax.lax.dynamic_slice(table, (ixs[j], 0), (1, rank))
+         for j in range(ixs.shape[0])])
+
+
 def _users_topk_impl(user_factors, item_factors, user_ixs, n_items,
                      k: int):
     """Traced body shared by the packed and unpacked serve executables
     (unjitted — always composed under one of the two jit wrappers
-    below, so both variants rank identically)."""
+    below, so both variants rank identically).
+
+    The batch's user rows are read by :func:`_batch_rows`, one
+    ``dynamic_slice`` per (static) batch slot, and NOT as
+    ``user_factors[user_ixs]``. At a rank that is no multiple of 128
+    (200 is the default) the TPU holds an ``[n, rank]`` f32 table with
+    the row index minor, ``{0,1:T(8,128)}``, so a row is not contiguous;
+    XLA's gather wants it row-major and makes it so with a copy of the
+    WHOLE table — transposed, rounded to bf16 and padded to 256 lanes —
+    inside every dispatch: 6.7 GB read and 4.3 GB written to fetch 16
+    rows of an 8.4M-user table, two thirds of the serve executable's
+    device time (PERF.md, PR 26). Scalar-offset slices fuse into one
+    small read of the table as it lies, rounded after the pick exactly
+    as the copy rounded before it, so ids and scores do not change. Do
+    not "simplify" this back to a gather, nor wrap the slices in
+    ``lax.map`` / ``vmap`` (both become the gather again).
+
+    The unrolled read grows with the batch bucket, which has no cap
+    (``users_topk_serve`` is public), while the gather's copy grows with
+    the table: a shape test picks at trace time. Past one row read per
+    ``_ROWS_PER_READ`` table rows the pass over the table is the
+    cheaper program again (and the smaller one: 1,024 unrolled slices
+    take 8-11 s to compile), and a table that small is copied in
+    microseconds; every batch bucket a server sends (<= micro_batch 16)
+    against a table of 16,384+ rows reads row by row. At a rank that is
+    a multiple of 128 the table already lies row-major, the gather
+    carries no copy, and the two forms cost the same."""
     import jax
     import jax.numpy as jnp
     with jax.named_scope("pio.serve.user_rows"):
-        u = user_factors[user_ixs]                            # [B, R]
+        u = _batch_rows(user_factors, user_ixs)               # [B, R]
     with jax.named_scope("pio.serve.score"):
         scores = jnp.einsum("br,ir->bi", u, item_factors,
                             preferred_element_type=jnp.float32)

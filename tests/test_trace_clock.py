@@ -222,6 +222,85 @@ def test_scopes_leave_the_answers_as_they_were():
     np.testing.assert_allclose(scores, np.asarray(ref_scores), rtol=1e-6)
 
 
+# -- the batch's user rows, read row by row (PR 26) ---------------------
+
+_ROWS_U, _ROWS_LIVE, _ROWS_RANK = 16384, 16000, 13
+
+
+def _rows_tables(n_u=_ROWS_U, live=_ROWS_LIVE, n_i=96, rank=_ROWS_RANK):
+    """A user table at its row bucket (zero rows past the live ones), as
+    the serve path uploads it, at a rank that is no multiple of 8."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(26)
+    U = np.zeros((n_u, rank), np.float32)
+    U[:live] = rng.standard_normal((live, rank))
+    V = rng.standard_normal((n_i, rank)).astype(np.float32)
+    return jnp.asarray(U), jnp.asarray(V)
+
+
+def _rows_batch(b, live=_ROWS_LIVE):
+    """What users_topk_serve_begin sends at bucket b: the table's last
+    live row, a row asked for twice, and zero slots as padding."""
+    ix = np.zeros(b, np.int32)
+    asked = [live - 1, 3, 3, 17, 1024, live - 129, 5, 640, 3, 127, 128, 1]
+    n = max(1, b - b // 4)
+    ix[:n] = asked[:n]
+    return ix
+
+
+def _assert_ranks_as_the_reference(variant, U, V, ix, n_items, k=8):
+    """Either serve executable against the exact-size reference: ids
+    equal, scores to 1e-6."""
+    from predictionio_tpu.ops import als, readback
+    if variant == "packed":
+        packed = als._users_topk_b_packed(U, V, ix, np.int32(n_items),
+                                          k=k, p=readback.PACK_EXACT)
+        scores, idx = readback.unpack_host(np.asarray(packed),
+                                           readback.PACK_EXACT)
+    else:
+        scores, idx = als._users_topk_b(U, V, ix, np.int32(n_items), k=k)
+    ref_scores, ref_idx = als._users_topk(U, V[:n_items], ix, k=k)
+    assert (np.asarray(idx) == np.asarray(ref_idx)).all()
+    np.testing.assert_allclose(np.asarray(scores), np.asarray(ref_scores),
+                               rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("variant", ["packed", "unpacked"])
+@pytest.mark.parametrize("n_u,live,b,row_by_row", [
+    (_ROWS_U, _ROWS_LIVE, 1, True), (_ROWS_U, _ROWS_LIVE, 2, True),
+    (_ROWS_U, _ROWS_LIVE, 4, True), (_ROWS_U, _ROWS_LIVE, 8, True),
+    (_ROWS_U, _ROWS_LIVE, 16, True),
+    # the other side of _batch_rows' shape test: 16 reads of a 64-row
+    # table are no cheaper than the table, so it gathers
+    (64, 60, 16, False)])
+def test_the_serve_executables_rank_as_the_reference(n_u, live, b,
+                                                     row_by_row, variant):
+    from predictionio_tpu.ops import als
+    assert (b * als._ROWS_PER_READ <= n_u) == row_by_row
+    U, V = _rows_tables(n_u=n_u, live=live)
+    _assert_ranks_as_the_reference(variant, U, V, _rows_batch(b, live) % live,
+                                   n_items=90)
+
+
+@pytest.mark.parametrize("n_rows,gathers", [(16384, False), (64, True)])
+def test_batch_rows_treats_a_bad_index_as_indexing_does(n_rows, gathers):
+    """A negative index wraps once and then clamps, a too-large one
+    clamps: x[ixs]'s own treatment, on both sides of the shape test, and
+    the program holds a gather only on the far side."""
+    import jax
+    import jax.numpy as jnp
+    from predictionio_tpu.ops import als
+    table = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (n_rows, _ROWS_RANK)), jnp.float32)
+    ix = np.array([0, -1, -n_rows, -n_rows - 1, n_rows - 1, n_rows,
+                   1 << 30, -(1 << 30), 7, 7, 0, 0, 0, 0, 0, 0], np.int32)
+    got = jax.jit(als._batch_rows)(table, ix)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(table[ix]))
+    text = str(jax.make_jaxpr(als._batch_rows)(table, ix))
+    assert ("gather" in text) == gathers
+    assert ("dynamic_slice" in text) != gathers
+
+
 # -- C: the serving account --------------------------------------------
 
 def _field(rec, name, fields=DISPATCH_FIELDS):
