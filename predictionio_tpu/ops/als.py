@@ -288,26 +288,31 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
                            ((1.0 + alpha * absval) * pos).astype(cd), Vc,
                            preferred_element_type=jnp.float32)
         if gram_w is not None and dual_solve == "auto" and K < rank:
-            with jax.named_scope("pio.sweep.gram"):
-                # implicit dual: B = G + reg I = Q (w + reg) Q^T (eig
-                # shared across the whole half-sweep); Woodbury for the
-                # K-rank confidence update, all R-dim work as eigenbasis
-                # einsums. G is PSD, so clamp eigh's roundoff-negative
-                # tail: a small reg (constant lambda_scaling grid points)
-                # must never meet a negative w and flip the sign of
-                # 1/denom.
+            # implicit dual: B = G + reg I = Q (w + reg) Q^T (eig shared
+            # across the whole half-sweep); Woodbury for the K-rank
+            # confidence update, all R-dim work as eigenbasis einsums.
+            # Two precisions. b's own projections (Q^T b in, Q (.) out:
+            # [B, R] x [R, R], 1/K of the stage's products) run in f32 at
+            # `highest`: G's top eigenvalue stands ~R times over the rest
+            # (every factor positive at init), b lies mostly along its
+            # vector, and a bf16 Q leaks 2^-9 of that part into the
+            # directions that 1/denom then weights ~R times more: a row
+            # error of ~1e-3 sqrt(K). The K-rank correction (V Q, W, t, s)
+            # keeps compute_dtype operands: what it adds to x is small
+            # beside B^-1 b and carries the Gram operands' own rounding.
+            hi = jax.lax.Precision.HIGHEST
+            with jax.named_scope("pio.sweep.smw.project"):
+                # G is PSD, so clamp eigh's roundoff-negative tail: a
+                # small reg (constant lambda_scaling grid points) must
+                # never meet a negative w and flip the sign of 1/denom.
                 denom = (jnp.maximum(gram_w, 0.0)[None, :]
                          + reg[:, None])                      # [B, R]
                 Vq = jnp.einsum("bkr,rs->bks", Vc,            # V~ Q
                                 gram_q.astype(cd),
                                 preferred_element_type=jnp.float32)
-                bq = jnp.einsum("br,rs->bs", b.astype(cd),
-                                gram_q.astype(cd),
-                                preferred_element_type=jnp.float32)
-                bq_d = bq / denom
-                u = jnp.einsum("bs,rs->br", bq_d.astype(cd),  # B^-1 b
-                               gram_q.astype(cd),
-                               preferred_element_type=jnp.float32)
+                bq_d = jnp.einsum("br,rs->bs", b, gram_q,     # Q^T b
+                                  precision=hi) / denom
+            with jax.named_scope("pio.sweep.smw.assemble"):
                 W = jnp.einsum("bks,bs,bls->bkl", Vq.astype(cd),
                                (1.0 / denom).astype(cd), Vq.astype(cd),
                                preferred_element_type=jnp.float32)
@@ -319,13 +324,13 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
                                preferred_element_type=jnp.float32)
             z = _dual_system_solve(M, dhalf * t, K, solver,
                                    iters_cap=dual_iters_cap)
-            with jax.named_scope("pio.sweep.gram"):
+            with jax.named_scope("pio.sweep.smw.back"):
                 s = jnp.einsum("bks,bk->bs", Vq.astype(cd),
                                (dhalf * z).astype(cd),
                                preferred_element_type=jnp.float32)
-                x = u - jnp.einsum("bs,rs->br", (s / denom).astype(cd),
-                                   gram_q.astype(cd),
-                                   preferred_element_type=jnp.float32)
+                # x = B^-1 b - B^-1 V^T D^1/2 z = Q ((Q^T b - s) / denom)
+                x = jnp.einsum("bs,rs->br", bq_d - s / denom, gram_q,
+                               precision=hi)
             return _scatter_rows(factors_out, rows, x)
         with jax.named_scope("pio.sweep.gram"):
             A = G + jnp.einsum("bk,bkr,bks->brs", conf_minus_1.astype(cd),
@@ -399,13 +404,13 @@ def _solve_iteration_impl(U, V, user_groups, item_groups, lam, alpha, *,
                           dual_iters_cap: Optional[int] = None,
                           n_users: int = 0, n_items: int = 0):
     gram_of = _gram_eig_impl if dual_solve == "auto" else _gram_impl
-    gram_v = gram_of(V[:n_items]) if implicit else None
+    gram_v = gram_of(V, n_items) if implicit else None
     U = _solve_sweep_impl(
         U, V, gram_v, user_groups, lam, alpha, nratings_reg=nratings_reg,
         implicit=implicit, rank=rank, compute_dtype=compute_dtype,
         solver=solver, dual_solve=dual_solve, solver_iters=solver_iters,
         dual_iters_cap=dual_iters_cap)
-    gram_u = gram_of(U[:n_users]) if implicit else None
+    gram_u = gram_of(U, n_users) if implicit else None
     V = _solve_sweep_impl(
         V, U, gram_u, item_groups, lam, alpha, nratings_reg=nratings_reg,
         implicit=implicit, rank=rank, compute_dtype=compute_dtype,
@@ -424,29 +429,40 @@ _solve_iteration = __import__("jax").jit(
     donate_argnums=(0, 1))
 
 
-def _gram_impl(factors):
-    import jax
+def _live_gram(factors, n_live: Optional[int]):
+    """Y^T Y over the table's first `n_live` rows (None: all of them). A
+    training table ends in the scatter's dummy row, which is no entity:
+    the slice is taken here, inside the program, where it is an operand
+    of the product and no copy of the table (sliced by the caller it was
+    a second table in HBM for as long as the Gram ran)."""
     import jax.numpy as jnp
-    with jax.named_scope("pio.sweep.gram_full"):
-        return jnp.einsum("ir,is->rs", factors, factors,
-                          preferred_element_type=jnp.float32)
+    live = factors if n_live is None else factors[:n_live]
+    return jnp.einsum("ir,is->rs", live, live,
+                      preferred_element_type=jnp.float32)
 
 
-def _gram_eig_impl(factors):
+def _gram_impl(factors, n_live: Optional[int] = None):
+    import jax
+    with jax.named_scope("pio.sweep.gram_full.gram"):
+        return _live_gram(factors, n_live)
+
+
+def _gram_eig_impl(factors, n_live: Optional[int] = None):
     """Gram + its eigendecomposition — computed ONCE per implicit
     half-sweep and shared by every entity's Woodbury solve (the base
     B = G + reg*I diagonalizes as Q diag(w + reg) Q^T for any reg)."""
     import jax
     import jax.numpy as jnp
-    with jax.named_scope("pio.sweep.gram_full"):
-        G = jnp.einsum("ir,is->rs", factors, factors,
-                       preferred_element_type=jnp.float32)
+    with jax.named_scope("pio.sweep.gram_full.gram"):
+        G = _live_gram(factors, n_live)
+    with jax.named_scope("pio.sweep.gram_full.eigh"):
         w, q = jnp.linalg.eigh(G)
     return G, w, q
 
 
-_gram = __import__("jax").jit(_gram_impl)
-_gram_eig = __import__("jax").jit(_gram_eig_impl)
+_gram = __import__("jax").jit(_gram_impl, static_argnames=("n_live",))
+_gram_eig = __import__("jax").jit(_gram_eig_impl,
+                                  static_argnames=("n_live",))
 
 
 # ---------------------------------------------------------------------------
@@ -528,12 +544,36 @@ def _upload_plan_now(mesh: MeshContext, plan: SolvePlan, chunk: int):
     return tuple(groups)
 
 
+#: An implicit half-sweep of more batch groups than this is dispatched as
+#: several programs, the groups dealt round-robin (batches of a half-sweep
+#: are independent, so neither the split nor the order changes a row). The
+#: TPU compiler's own memory for one program grows faster than the number
+#: of eig-SMW scan groups in it: 25 GB of host memory for the 76 groups of
+#: a 4.16M-item side at rank 200 (6 GB for the same plan's explicit
+#: program), which a 40 GiB host does not survive beside the process; a
+#: third of the groups takes a fifth of it. Each program beyond the first
+#: re-pays the donated table's two layout copies (PERF.md section 7,
+#: row 6), so explicit sweeps, whose programs compile in little, stay one.
+_IMPLICIT_GROUPS_PER_PROGRAM = 32
+
+
+def _sweep_programs(device_groups, implicit: bool):
+    """`device_groups` as the tuples of groups each dispatched program of
+    one half-sweep consumes."""
+    n = -(-len(device_groups) // _IMPLICIT_GROUPS_PER_PROGRAM) \
+        if implicit else 1
+    if n <= 1:
+        return (device_groups,)
+    return tuple(tuple(device_groups[p::n]) for p in range(n))
+
+
 def _run_side(device_groups, factors, counter_factors, cfg: ALSConfig,
               gram, lam=None, alpha=None, side: Optional[str] = None):
-    """One half-iteration: solve every batch of one side in one dispatch.
-    `lam`/`alpha` should be device-resident scalars (uploaded once per
-    train); numpy fallbacks keep ad-hoc callers working. `side`
-    ("user"/"item") only labels the `pio.train.half_sweep` span."""
+    """One half-iteration: solve every batch of one side, in one dispatch
+    (explicit) or a few (`_sweep_programs`). `lam`/`alpha` should be
+    device-resident scalars (uploaded once per train); numpy fallbacks
+    keep ad-hoc callers working. `side` ("user"/"item") only labels the
+    `pio.train.half_sweep` span."""
     if lam is None:
         lam = np.float32(cfg.lam)
     if alpha is None:
@@ -544,13 +584,27 @@ def _run_side(device_groups, factors, counter_factors, cfg: ALSConfig,
     attrs = {"side": side} if side else {}
     with TRACER.region("train.half_sweep", **attrs), \
             costmon.executable(costmon.ALS_SWEEP, defer_to_outer=True):
-        return _solve_sweep(
-            factors, counter_factors, gram, device_groups, lam, alpha,
-            nratings_reg=(cfg.lambda_scaling == "nratings"),
-            implicit=cfg.implicit_prefs, rank=cfg.rank,
-            compute_dtype=cfg.compute_dtype, solver=cfg.solver,
-            dual_solve=cfg.dual_solve, solver_iters=cfg.solver_iters,
-            dual_iters_cap=cfg.dual_iters_cap)
+        for groups in _sweep_programs(device_groups, cfg.implicit_prefs):
+            factors = _solve_sweep(
+                factors, counter_factors, gram, groups, lam, alpha,
+                nratings_reg=(cfg.lambda_scaling == "nratings"),
+                implicit=cfg.implicit_prefs, rank=cfg.rank,
+                compute_dtype=cfg.compute_dtype, solver=cfg.solver,
+                dual_solve=cfg.dual_solve, solver_iters=cfg.solver_iters,
+                dual_iters_cap=cfg.dual_iters_cap)
+        return factors
+
+
+def _side_gram(cfg: ALSConfig, table, n_live: int, side: str):
+    """What an implicit half-sweep shares across its rows: the Gram of the
+    counterpart table's `n_live` entity rows (`side` names that table),
+    with its eigendecomposition where the eig-SMW dual route will read
+    it. None for explicit training."""
+    if not cfg.implicit_prefs:
+        return None
+    gram_of = _gram_eig if cfg.dual_solve == "auto" else _gram
+    with TRACER.region("train.gram", side=side):
+        return gram_of(table, n_live=n_live)
 
 
 def als_train(ratings: RatingsCOO, cfg: ALSConfig,
@@ -633,7 +687,6 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
                 item_batches[-1][2][:1, :1, :1])).ravel()[0])
         telemetry["upload_s"] = _time.perf_counter() - t0
         t0 = _time.perf_counter()
-    gram_of = _gram_eig if cfg.dual_solve == "auto" else _gram
     # train-sweep sentinel (ISSUE 5): per-iteration finite/norm check +
     # a checkpointed last-good iteration (HBM copies, never host fetch)
     sentinel = None
@@ -693,12 +746,10 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
             _first_iteration_done(it)
     else:
         for it in range(cfg.iterations):
-            gram_v = gram_of(V[:ratings.n_items]) if cfg.implicit_prefs \
-                else None
+            gram_v = _side_gram(cfg, V, ratings.n_items, "item")
             U = _run_side(user_batches, U, V, cfg, gram_v, lam_dev,
                           alpha_dev, side="user")
-            gram_u = gram_of(U[:ratings.n_users]) if cfg.implicit_prefs \
-                else None
+            gram_u = _side_gram(cfg, U, ratings.n_users, "user")
             V = _run_side(item_batches, V, U, cfg, gram_u, lam_dev,
                           alpha_dev, side="item")
             if not _checked(it):

@@ -5,8 +5,12 @@ TPU keeps an [n, 200] f32 table with the row index minor, and a gather of
 16 rows made the compiler copy all 8.39M (transposed, rounded to bf16,
 padded to 256 lanes: 4.295 GB of temporaries, 14 of a dispatch's 20 ms).
 Nothing runs, so nothing here is a time. One file, so that one test worker
-loads the TPU's library."""
+loads the TPU's library: PR 27's compiles of the implicit-feedback
+configuration's programs (`ecomm-taobao-ub-r200`: both half-sweeps and the
+Gram + eigh program, against the chip's memory) are at the end of it."""
 
+import json
+import os
 import re
 
 import pytest
@@ -91,3 +95,91 @@ def test_rank_256_is_no_worse_than_a_gather(sds, monkeypatch):
     # are 63 KB beside them
     assert (ours.memory_analysis().temp_size_in_bytes
             <= gathered.memory_analysis().temp_size_in_bytes + (1 << 20))
+
+
+# -- PR 27: the implicit configuration's programs against the chip's memory
+
+HBM = 15.75 * 2**30    # what the compiler itself allows of the chip's 16 GiB
+
+# (B, K) of the heaviest steps of each route in the program's plan of the
+# configuration's view counts at work_budget 2^20 (ops/ratings.plan_for_users
+# / plan_for_items over benchmark/lib/datagen_implicit.py's pairs; the whole
+# plan, 49 + 76 shapes, takes minutes to compile: PERF.md section 4): jnp CG
+# K < 32, eig-SMW at its smallest and largest K, primal at 208 and at the
+# longest rows
+TAOBAO_SHAPES = {
+    "user": [(65536, 16), (32768, 32), (5957, 176), (5041, 208), (500, 960)],
+    "item": [(131072, 8), (32768, 32), (5957, 176), (5041, 208),
+             (605, 1600), (1, 32000)],
+}
+
+
+def _taobao():
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "ecomm-taobao-ub-r200.json")) as f:
+        return json.load(f)
+
+
+def _gram_shapes(sds, rank):
+    import jax.numpy as jnp
+    return (sds((rank, rank), jnp.float32), sds((rank,), jnp.float32),
+            sds((rank, rank), jnp.float32))
+
+
+@pytest.mark.parametrize("side", ["user", "item"])
+def test_taobao_implicit_half_sweep_fits_at_the_files_sweep_chunk(sds, side):
+    """Tables, both plans, and the half-sweep's temporaries at the
+    configuration's `sweep_chunk`, one scan step of each shape."""
+    import jax.numpy as jnp
+    from predictionio_tpu.ops import als
+    c = _taobao()
+    rank, chunk = c["rank"], c["sweep_chunk"]
+    n_out, n_counter = ((c["n_users"], c["n_items"]) if side == "user"
+                        else (c["n_items"], c["n_users"]))
+    groups = tuple(
+        (sds((1, chunk * B), jnp.int32), sds((1, chunk * B, K), jnp.int32),
+         sds((1, chunk * B, K), jnp.float32),
+         sds((1, chunk * B, K), jnp.float32))
+        for B, K in TAOBAO_SHAPES[side])
+    compiled = als._solve_sweep.lower(
+        sds((n_out + 1, rank), jnp.float32),
+        sds((n_counter + 1, rank), jnp.float32), _gram_shapes(sds, rank),
+        groups, sds((), jnp.float32), sds((), jnp.float32),
+        nratings_reg=True, implicit=True, rank=rank,
+        compute_dtype="bfloat16", solver="cg_pallas", dual_solve="auto",
+        solver_iters=None, dual_iters_cap=None).compile()
+    m = compiled.memory_analysis()
+    tables = (c["n_users"] + c["n_items"] + 2) * rank * 4
+    # idx, val, mask of every pair on both sides, with the plans' padding
+    # (1.09 and 1.34: PERF.md section 4), and a row index a system
+    plans = c["n_ratings"] * (1.09 + 1.34) * 12 + 8e6
+    held = tables + plans + 3 * (rank * rank + rank) * 4
+    assert (held + m.temp_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes) < HBM
+    # the eig-SMW stages and the Pallas dual solve are in the program
+    text = compiled.as_text()
+    assert "pio.sweep.smw.project" in text and "pio_cg_dual_b" in text
+
+
+@pytest.mark.parametrize("table", ["item", "user"])
+def test_taobao_gram_program_reads_the_table_where_it_lies(sds, table):
+    """The Gram over the live rows inside the program: no operation of the
+    entry computation makes another table (sliced by the caller, as before
+    PR 27, the item table was a second 3.3 GB array while the Gram ran), and
+    the program's temporaries are those of a 200 x 200 eigh."""
+    import jax.numpy as jnp
+    from predictionio_tpu.ops import als
+    c = _taobao()
+    n, rank = c["n_" + table + "s"], c["rank"]
+    compiled = als._gram_eig.lower(sds((n + 1, rank), jnp.float32),
+                                   n_live=n).compile()
+    entry = compiled.as_text().split("\nENTRY ", 1)[1]
+    sized = re.compile(r"= \w+\[(%d|%d),%d\]|= \w+\[%d,(%d|%d)\]"
+                       % (n, n + 1, rank, rank, n, n + 1))
+    made = [line.strip()[:160] for line in entry.splitlines()
+            if sized.search(line) and " parameter(" not in line]
+    assert made == []
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 64 << 20
+    assert m.argument_size_in_bytes < (n + 1) * rank * 4 * 1.001

@@ -189,10 +189,69 @@ def test_the_full_grams_are_named():
     import jax
     import jax.numpy as jnp
     from predictionio_tpu.ops import als
-    for fn in (als._gram_impl, als._gram_eig_impl):
-        text = jax.jit(fn).trace(jnp.ones((9, 4))).lower().as_text(
-            debug_info=True)
-        assert "pio.sweep.gram_full" in text
+    for fn, scopes in ((als._gram_impl, {"pio.sweep.gram_full.gram"}),
+                       (als._gram_eig_impl, {"pio.sweep.gram_full.gram",
+                                             "pio.sweep.gram_full.eigh"})):
+        text = jax.jit(fn, static_argnames=("n_live",)).trace(
+            jnp.ones((9, 4)), n_live=8).lower().as_text(debug_info=True)
+        assert set(re.findall(r"pio\.[a-z_.]+", text)) == scopes
+
+
+_SMW = {"pio.sweep.smw.project", "pio.sweep.smw.assemble",
+        "pio.sweep.smw.back"}
+
+
+@pytest.mark.parametrize("K,scopes", [
+    # K < rank: the eig-SMW dual route, its three stages and the K x K solve
+    (4, _EVERY_SWEEP | _SMW | {"pio.sweep.solve.dual"}),
+    # K >= rank: the primal route; `pio.sweep.gram` holds its A and b
+    (16, _EVERY_SWEEP | {"pio.sweep.solve.primal"}),
+])
+def test_the_implicit_sweep_names_its_stages(K, scopes):
+    import jax.numpy as jnp
+    from predictionio_tpu.ops import als
+    rng = np.random.default_rng(0)
+    N, B, rank = 2, 4, 8
+    group = (np.arange(N * B, dtype=np.int32).reshape(N, B),
+             rng.integers(0, 50, (N, B, K)).astype(np.int32),
+             rng.integers(1, 4, (N, B, K)).astype(np.float32),
+             np.ones((N, B, K), np.float32))
+    gram = als._gram_eig(jnp.ones((51, rank)), n_live=50)
+    text = als._solve_sweep.trace(
+        jnp.zeros((41, rank)), jnp.ones((51, rank)), gram, (group,),
+        np.float32(0.01), np.float32(1.0), nratings_reg=True,
+        implicit=True, rank=rank, compute_dtype="float32",
+        solver="cholesky", dual_solve="auto", solver_iters=None,
+        dual_iters_cap=None).lower().as_text(debug_info=True)
+    assert set(re.findall(r"pio\.[a-z_.]+", text)) == scopes
+
+
+def test_implicit_training_puts_each_gram_under_a_host_region(monkeypatch):
+    """`pio.train.gram` (attr `side`: whose table) around each Gram + eigh
+    program, twice an iteration, before the half-sweep that reads it."""
+    from predictionio_tpu.ops import als
+    from predictionio_tpu.ops.ratings import RatingsCOO
+    seen = []
+    real = als.TRACER.region
+
+    def region(name, **attrs):
+        seen.append((name, attrs.get("side")))
+        return real(name, **attrs)
+
+    monkeypatch.setattr(als.TRACER, "region", region)
+    rng = np.random.default_rng(1)
+    coo = RatingsCOO(np.sort(rng.integers(0, 12, 60)).astype(np.int32),
+                     rng.integers(0, 9, 60).astype(np.int32),
+                     np.ones(60, np.float32), 12, 9)
+    als.als_train(coo, als.ALSConfig(rank=4, iterations=2, sentinel=False,
+                                     implicit_prefs=True))
+    sweeps = [s for s in seen if s[0] in ("train.gram", "train.half_sweep")]
+    assert sweeps == [("train.gram", "item"), ("train.half_sweep", "user"),
+                      ("train.gram", "user"),
+                      ("train.half_sweep", "item")] * 2
+    seen.clear()
+    als.als_train(coo, als.ALSConfig(rank=4, iterations=1, sentinel=False))
+    assert not [s for s in seen if s[0] == "train.gram"]
 
 
 def test_the_serve_kernel_names_its_stages():
