@@ -2300,7 +2300,7 @@ def solver_ablation():
 
         def run_iter(U, V):
             if cfg.fuse_iteration:
-                return A._solve_iteration(
+                U, V, _cg_iters = A._solve_iteration(
                     U, V, user_batches, item_batches, lam, alpha,
                     nratings_reg=True, implicit=imp, rank=rank,
                     compute_dtype=cfg.compute_dtype, solver=cfg.solver,
@@ -2308,6 +2308,7 @@ def solver_ablation():
                     solver_iters=cfg.solver_iters,
                     dual_iters_cap=cfg.dual_iters_cap,
                     n_users=n_users, n_items=n_items)
+                return U, V
             # the conditional keeps the explicit timed path free of even
             # the factor-slice dispatch the gram computation needs
             U = A._run_side(user_batches, U, V, cfg,

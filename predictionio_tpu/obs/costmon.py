@@ -98,6 +98,7 @@ _c_pc_hits = None
 _c_pc_misses = None
 _g_flops = None
 _g_bytes = None
+_g_cg_ratio = None
 _c_dispatch_s = None
 _c_device_s = None
 _c_device_syncs = None
@@ -115,7 +116,8 @@ def install(registry=None):
     """Register the listener + gauges. Idempotent; never raises."""
     global _installed, _c_seconds, _c_hits, _c_misses, _g_flops, \
         _g_bytes, _c_pc_hits, _c_pc_misses, _c_dispatch_s, \
-        _c_device_s, _c_device_syncs, _g_occupancy, _g_tenant_occ
+        _c_device_s, _c_device_syncs, _g_occupancy, _g_tenant_occ, \
+        _g_cg_ratio
     with _lock:
         if _installed:
             return
@@ -142,6 +144,12 @@ def install(registry=None):
             "pio_executable_bytes_accessed",
             "XLA cost_analysis() bytes accessed of the last analyzed "
             "executable per label", labelnames=("executable",))
+        _g_cg_ratio = reg.gauge(
+            "pio_als_cg_iterations_run_ratio",
+            "CG iterations the Pallas solves of the last ALS iteration "
+            "ran over the iterations their budgets allowed (the kernel "
+            "stops a tile whose systems have converged; 1 = every solve "
+            "ran to its cap)")
         _c_pc_hits = reg.counter(
             "pio_compile_pcache_hits_total",
             "persistent compilation-cache hits (an executable "
@@ -560,6 +568,16 @@ def record_cost_analysis(label: str, compiled) -> Optional[dict]:
     _g_flops.labels(executable=label).set(flops)
     _g_bytes.labels(executable=label).set(nbytes)
     return {"flops": flops, "bytes_accessed": nbytes}
+
+
+def record_cg_iterations(run: float, budget: float) -> None:
+    """Bank what `ops/als.last_cg_iterations` read after a train: the
+    share of their budget the Pallas CG solves ran. Nothing to bank when
+    no solve went through that kernel (budget 0)."""
+    if not _installed:
+        install()
+    if budget > 0:
+        _g_cg_ratio.set(run / budget)
 
 
 def analyze_jit(label: str, fn, *args, **kwargs) -> Optional[dict]:

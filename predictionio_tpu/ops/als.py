@@ -29,6 +29,7 @@ Math parity with MLlib 1.3:
 
 from __future__ import annotations
 
+import collections
 import functools
 import logging
 from dataclasses import dataclass
@@ -63,18 +64,21 @@ class ALSConfig:
     solver: str = "auto"  # see ops/solve.py spd_solve
     # auto = VMEM-resident CG Pallas kernel on TPU (XLA's batched cholesky
     # runs at ~0.05% MXU there), LAPACK cholesky on CPU.
-    solver_iters: Optional[int] = None  # primal CG iteration budget
-    # None = the solver default (48). The primal rank-dim CG can stall in
+    solver_iters: Optional[int] = None  # cap on the primal CG iterations
+    # None = the solver default (48). The Pallas kernel stops a tile of
+    # systems once they have converged (ops/solve._cg_kernel) and runs to
+    # this cap otherwise. The primal rank-dim CG can stall in
     # ill-conditioned implicit configs (large alpha * |r| confidences);
     # K<rank buckets are unaffected (the dual route solves a better-
     # conditioned K-dim system exactly), but large-count entities ride
     # the primal solver — raise this (or set solver='cholesky') there.
-    dual_iters_cap: Optional[int] = None  # cap on the dual CG budget
-    # None = K+8 per bucket (finite-termination bound + roundoff margin).
-    # CG converges far earlier on these well-conditioned K-dim systems;
-    # if solve time scales with the iteration count (rather than being
-    # per-call fixed), capping trades a bounded residual for wall-clock.
-    # Measured by the ablation's dualcap row before any default change.
+    dual_iters_cap: Optional[int] = None  # a lower cap on the dual CG
+    # None = the cap is K+8 per bucket (finite-termination bound +
+    # roundoff margin). Either way it is a cap: the Pallas kernel stops
+    # a tile of these well-conditioned K-dim systems once they have
+    # converged, a fifth to a third of the way there (PERF.md, PR 28),
+    # so a lower cap only cuts short the systems that had not: it trades
+    # a bounded residual on the hard ones for their wall-clock.
     dual_solve: str = "auto"  # 'auto' | 'never'
     # Woodbury/dual formulation for ALS buckets whose padded segment
     # length K < rank — exact algebra replacing the rank-dim solve with a
@@ -182,16 +186,19 @@ class ALSModel:
 def _dual_system_solve(M, y, K: int, solver: str,
                        iters_cap: Optional[int] = None):
     """Solve the K-dim dual/Woodbury system: the shared policy for both
-    explicit and implicit dual branches. K+8 iterations (CG's exact-
-    arithmetic finite termination is <= K; the margin absorbs f32
-    roundoff — capping below K silently under-solves the larger
-    buckets unless the caller opts in via `iters_cap`, whose accuracy
-    cost is ALSConfig.dual_iters_cap's to document); tiny systems skip
-    the Pallas kernel, whose per-tile overhead dominates below 32."""
+    explicit and implicit dual branches. The cap is K+8 iterations (CG's
+    exact-arithmetic finite termination is <= K; the margin absorbs f32
+    roundoff: a cap below K would under-solve a bucket's hard systems
+    unless the caller opts in via `iters_cap`, whose accuracy cost is
+    ALSConfig.dual_iters_cap's to document); under it the Pallas kernel
+    stops each tile when its systems have converged. Tiny systems skip
+    that kernel, whose per-tile overhead dominates below 32, for the jnp
+    CG, which runs the whole K+8. Returns the solution and `spd_solve`'s
+    count of CG iterations (run, allowed)."""
     import jax
     import jax.numpy as jnp
 
-    from predictionio_tpu.ops.solve import spd_solve
+    from predictionio_tpu.ops.solve import no_cg_iterations, spd_solve
     if solver == "diag_nosolve":
         # perf diagnostic, NOT a solver (wrong math by design): skip the
         # solve but keep M alive — the dual Gram einsum is the
@@ -200,7 +207,8 @@ def _dual_system_solve(M, y, K: int, solver: str,
         # cheaper contraction that never materializes the Gram. Covers
         # every dual call site (explicit Woodbury and implicit eig-SMW).
         M_live = jax.lax.optimization_barrier(M)
-        return y + M_live.sum(axis=2) * jnp.float32(1e-12)
+        return (y + M_live.sum(axis=2) * jnp.float32(1e-12),
+                no_cg_iterations())
     method = "cg" if (K < 32 and solver == "cg_pallas") else solver
     if iters_cap is not None and iters_cap < 1:
         # 0 would fall into spd_solve's `iters or 48` unset-default and
@@ -232,11 +240,13 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
     factors_out. Traced inside `_solve_sweep`'s scan body — gather ->
     einsum -> solve -> scatter fuse into one XLA program. Explicit batches
     with K < rank take the dual (Woodbury) K x K route; K is static per
-    batch group, so the choice costs nothing at runtime."""
+    batch group, so the choice costs nothing at runtime. Returns the
+    table and the batch's CG iterations (run, allowed), float32 [2]:
+    `spd_solve`'s count."""
     import jax
     import jax.numpy as jnp
 
-    from predictionio_tpu.ops.solve import spd_solve
+    from predictionio_tpu.ops.solve import no_cg_iterations, spd_solve
 
     cd = jnp.dtype(compute_dtype)
     with jax.named_scope("pio.sweep.gather"):
@@ -256,7 +266,7 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
         # diag_nosolve / full rows to locate the iteration time.
         x = jnp.einsum("bk,bkr->br", mask.astype(cd), Vc,
                        preferred_element_type=jnp.float32)
-        return _scatter_rows(factors_out, rows, x)
+        return _scatter_rows(factors_out, rows, x), no_cg_iterations()
 
     if dual_solve == "auto" and not implicit and K < rank:
         # dual/Woodbury: with M = mask-weighted factor rows [K, R],
@@ -268,12 +278,12 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
                             preferred_element_type=jnp.float32)
             Ad = Ad + reg[:, None, None] * jnp.eye(K, dtype=jnp.float32)
             y = (val * mask)
-        z = _dual_system_solve(Ad, y, K, solver,
-                               iters_cap=dual_iters_cap)
+        z, cg = _dual_system_solve(Ad, y, K, solver,
+                                   iters_cap=dual_iters_cap)
         with jax.named_scope("pio.sweep.gram"):
             x = jnp.einsum("bkr,bk->br", Vm, z.astype(cd),
                            preferred_element_type=jnp.float32)
-        return _scatter_rows(factors_out, rows, x)
+        return _scatter_rows(factors_out, rows, x), cg
 
     if implicit:
         with jax.named_scope("pio.sweep.gram"):
@@ -322,8 +332,8 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
                 t = jnp.einsum("bks,bs->bk", Vq.astype(cd),   # V B^-1 b
                                bq_d.astype(cd),
                                preferred_element_type=jnp.float32)
-            z = _dual_system_solve(M, dhalf * t, K, solver,
-                                   iters_cap=dual_iters_cap)
+            z, cg = _dual_system_solve(M, dhalf * t, K, solver,
+                                       iters_cap=dual_iters_cap)
             with jax.named_scope("pio.sweep.smw.back"):
                 s = jnp.einsum("bks,bk->bs", Vq.astype(cd),
                                (dhalf * z).astype(cd),
@@ -331,7 +341,7 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
                 # x = B^-1 b - B^-1 V^T D^1/2 z = Q ((Q^T b - s) / denom)
                 x = jnp.einsum("bs,rs->br", bq_d - s / denom, gram_q,
                                precision=hi)
-            return _scatter_rows(factors_out, rows, x)
+            return _scatter_rows(factors_out, rows, x), cg
         with jax.named_scope("pio.sweep.gram"):
             A = G + jnp.einsum("bk,bkr,bks->brs", conf_minus_1.astype(cd),
                                Vc, Vc,
@@ -349,12 +359,13 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
         # (see the _dual_system_solve note)
         x = b + jax.lax.optimization_barrier(A).sum(axis=2) \
             * jnp.float32(1e-12)
+        cg = no_cg_iterations()
     else:
         with jax.named_scope("pio.sweep.solve.jnp_cg" if solver == "cg"
                              else "pio.sweep.solve.primal"):
-            x = spd_solve(A, b, method=solver, iters=solver_iters,
-                          compute_dtype=compute_dtype)
-    return _scatter_rows(factors_out, rows, x)
+            x, cg = spd_solve(A, b, method=solver, iters=solver_iters,
+                              compute_dtype=compute_dtype)
+    return _scatter_rows(factors_out, rows, x), cg
 
 
 def _solve_sweep_impl(factors_out, counter_factors, gram, groups, lam,
@@ -363,22 +374,28 @@ def _solve_sweep_impl(factors_out, counter_factors, gram, groups, lam,
                       dual_solve: str = "auto",
                       solver_iters: Optional[int] = None,
                       dual_iters_cap: Optional[int] = None):
+    """Every batch of `groups` solved into `factors_out`. Returns the table
+    and the CG iterations the program's Pallas solves ran and were allowed
+    (float32 [2], summed over the systems: `spd_solve`)."""
     import jax
 
-    def body(f, batch):
-        rows, idx, val, mask = batch
-        f = _solve_batch(f, counter_factors, gram, rows, idx, val, mask,
-                         lam, alpha, nratings_reg=nratings_reg,
-                         implicit=implicit, rank=rank,
-                         compute_dtype=compute_dtype, solver=solver,
-                         dual_solve=dual_solve,
-                         solver_iters=solver_iters,
-                         dual_iters_cap=dual_iters_cap)
-        return f, None
+    from predictionio_tpu.ops.solve import no_cg_iterations
 
+    def body(carry, batch):
+        f, cg = carry
+        rows, idx, val, mask = batch
+        f, cg_batch = _solve_batch(
+            f, counter_factors, gram, rows, idx, val, mask, lam, alpha,
+            nratings_reg=nratings_reg, implicit=implicit, rank=rank,
+            compute_dtype=compute_dtype, solver=solver,
+            dual_solve=dual_solve, solver_iters=solver_iters,
+            dual_iters_cap=dual_iters_cap)
+        return (f, cg + cg_batch), None
+
+    carry = (factors_out, no_cg_iterations())
     for group in groups:
-        factors_out, _ = jax.lax.scan(body, factors_out, group)
-    return factors_out
+        carry, _ = jax.lax.scan(body, carry, group)
+    return carry
 
 
 _SWEEP_STATICS = ("nratings_reg", "implicit", "rank", "compute_dtype",
@@ -391,7 +408,7 @@ _ITER_STATICS = _SWEEP_STATICS + ("n_users", "n_items")
 #: factor table through every scatter (no HBM copy per sweep). Collapses
 #: ~45 dispatches per half-sweep (each with fresh host scalars) to a
 #: single device program, and the per-bucket compile count to one
-#: program per plan signature.
+#: program per plan signature. Returns (table, CG iterations [2]).
 _solve_sweep = __import__("jax").jit(
     _solve_sweep_impl, static_argnames=_SWEEP_STATICS, donate_argnums=(0,))
 
@@ -405,18 +422,18 @@ def _solve_iteration_impl(U, V, user_groups, item_groups, lam, alpha, *,
                           n_users: int = 0, n_items: int = 0):
     gram_of = _gram_eig_impl if dual_solve == "auto" else _gram_impl
     gram_v = gram_of(V, n_items) if implicit else None
-    U = _solve_sweep_impl(
+    U, cg_u = _solve_sweep_impl(
         U, V, gram_v, user_groups, lam, alpha, nratings_reg=nratings_reg,
         implicit=implicit, rank=rank, compute_dtype=compute_dtype,
         solver=solver, dual_solve=dual_solve, solver_iters=solver_iters,
         dual_iters_cap=dual_iters_cap)
     gram_u = gram_of(U, n_users) if implicit else None
-    V = _solve_sweep_impl(
+    V, cg_v = _solve_sweep_impl(
         V, U, gram_u, item_groups, lam, alpha, nratings_reg=nratings_reg,
         implicit=implicit, rank=rank, compute_dtype=compute_dtype,
         solver=solver, dual_solve=dual_solve, solver_iters=solver_iters,
         dual_iters_cap=dual_iters_cap)
-    return U, V
+    return U, V, cg_u + cg_v
 
 
 #: One FULL iteration (user sweep then item sweep, plus the implicit
@@ -567,13 +584,35 @@ def _sweep_programs(device_groups, implicit: bool):
     return tuple(tuple(device_groups[p::n]) for p in range(n))
 
 
+#: The CG iterations (run, allowed: `_solve_sweep`'s second result, still on
+#: the device) of each program of the last two half-sweeps, which is the
+#: last whole iteration of a train. Nothing on the hot path waits for them;
+#: `last_cg_iterations` fetches.
+_cg_iters_log: collections.deque = collections.deque(maxlen=2)
+
+
+def last_cg_iterations() -> Optional[Tuple[float, float]]:
+    """(run, allowed) CG iterations of the Pallas solves of the last two
+    half-sweeps this process dispatched, each summed over the systems;
+    None before any half-sweep. Fetches a few scalars: for after the
+    timed path."""
+    import jax
+    programs = [cg for half_sweep in tuple(_cg_iters_log)
+                for cg in half_sweep]
+    if not programs:
+        return None
+    run, allowed = np.sum(jax.device_get(programs), axis=0)
+    return float(run), float(allowed)
+
+
 def _run_side(device_groups, factors, counter_factors, cfg: ALSConfig,
               gram, lam=None, alpha=None, side: Optional[str] = None):
     """One half-iteration: solve every batch of one side, in one dispatch
     (explicit) or a few (`_sweep_programs`). `lam`/`alpha` should be
     device-resident scalars (uploaded once per train); numpy fallbacks
     keep ad-hoc callers working. `side` ("user"/"item") only labels the
-    `pio.train.half_sweep` span."""
+    `pio.train.half_sweep` span. Returns the table; the programs' counts
+    of CG iterations stay on the device for `last_cg_iterations`."""
     if lam is None:
         lam = np.float32(cfg.lam)
     if alpha is None:
@@ -584,14 +623,17 @@ def _run_side(device_groups, factors, counter_factors, cfg: ALSConfig,
     attrs = {"side": side} if side else {}
     with TRACER.region("train.half_sweep", **attrs), \
             costmon.executable(costmon.ALS_SWEEP, defer_to_outer=True):
+        counts = []
         for groups in _sweep_programs(device_groups, cfg.implicit_prefs):
-            factors = _solve_sweep(
+            factors, cg = _solve_sweep(
                 factors, counter_factors, gram, groups, lam, alpha,
                 nratings_reg=(cfg.lambda_scaling == "nratings"),
                 implicit=cfg.implicit_prefs, rank=cfg.rank,
                 compute_dtype=cfg.compute_dtype, solver=cfg.solver,
                 dual_solve=cfg.dual_solve, solver_iters=cfg.solver_iters,
                 dual_iters_cap=cfg.dual_iters_cap)
+            counts.append(cg)
+        _cg_iters_log.append(counts)
         return factors
 
 
@@ -622,7 +664,10 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
     and compilation or persistent-cache load). Each timing is closed by
     a hard one-element host fetch (a dispatch-queue timer would lie on
     asynchronous backends), which costs one extra tiny transfer — only
-    paid when telemetry is requested."""
+    paid when telemetry is requested. It also receives cg_iters_run and
+    cg_iters_budget: the CG iterations the last iteration's Pallas solves
+    ran and were allowed, summed over their systems (0 and 0 under a
+    solver that is not cg_pallas)."""
     import time as _time
 
     import jax
@@ -728,11 +773,12 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
             telemetry["first_iter_s"] = _time.perf_counter() - t0
 
     from predictionio_tpu.obs import costmon
+    _cg_iters_log.clear()          # an earlier train's, or a fold tick's
     if cfg.fuse_iteration:
         for it in range(cfg.iterations):
             with costmon.executable(costmon.ALS_SWEEP,
                                     defer_to_outer=True):
-                U, V = _solve_iteration(
+                U, V, cg = _solve_iteration(
                     U, V, user_batches, item_batches, lam_dev, alpha_dev,
                     nratings_reg=(cfg.lambda_scaling == "nratings"),
                     implicit=cfg.implicit_prefs, rank=cfg.rank,
@@ -741,6 +787,7 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
                     solver_iters=cfg.solver_iters,
                     dual_iters_cap=cfg.dual_iters_cap,
                     n_users=ratings.n_users, n_items=ratings.n_items)
+            _cg_iters_log.append([cg])
             if not _checked(it):
                 break
             _first_iteration_done(it)
@@ -769,7 +816,20 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
                                    / max(cfg.iterations, 1))
         t0 = _time.perf_counter()
     with TRACER.region("train.fetch"):
+        _fetch_cg_iterations(telemetry)
         return _fetch_model(U, V, ratings, cfg, mesh, telemetry, t0)
+
+
+def _fetch_cg_iterations(telemetry: Optional[dict]) -> None:
+    """The last iteration's count of CG iterations leaves the device with
+    the tables: into `telemetry` and the registry's gauge."""
+    counted = last_cg_iterations()
+    if counted is None:
+        return
+    from predictionio_tpu.obs import costmon
+    costmon.record_cg_iterations(*counted)
+    if telemetry is not None:
+        telemetry["cg_iters_run"], telemetry["cg_iters_budget"] = counted
 
 
 def _fetch_model(U, V, ratings: RatingsCOO, cfg: ALSConfig,

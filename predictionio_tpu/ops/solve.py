@@ -7,14 +7,14 @@ of a 9.8 s ML-20M ALS iteration; see docs/benchmarks.md). Iterative
 methods whose only primitive is multiply-accumulate map to the hardware
 instead, and the ALS normal matrix A = Gram + lam*n*I arrives
 pre-regularized — its condition number is bounded by
-~rank*E[v^2]/lam — so fixed iteration counts converge to f32 working
-precision.
+~rank*E[v^2]/lam — so a bounded number of iterations converges to f32
+working precision.
 
 Production path (TPU): batched conjugate gradient in a Pallas kernel,
 grid over 16-entity tiles whose [16, R, R] systems stay VMEM-resident for
-every iteration (HBM reads A exactly once). Measured on v5e at B=2048,
-R=200, cond~230: 27 ms and rel err 3e-6, vs 140 ms for XLA
-cholesky+trsm.
+every iteration (HBM reads A exactly once); a tile stops when its systems
+have converged, under an iteration cap (`_cg_kernel`; PERF.md, PR 28,
+has the chip's timings).
 
 Also provided: the Schulz/Hotelling–Bodewig inverse iteration
 X_{k+1} = X_k(2I - A X_k) (pure batched MXU matmuls, bf16-safe because
@@ -206,11 +206,43 @@ def cg_solve(A, b, iters: int = 48):
     return x
 
 
-def _cg_kernel(a_ref, b_ref, x_ref, *, iters: int):
+#: Iterations the Pallas CG kernel runs between two looks at its
+#: residuals. A look is one reduction of the tile to a scalar and a branch
+#: on it; a tile runs on average half a block past the iteration at which
+#: its slowest system converged (PERF.md, PR 28, has the chip's timing of
+#: 4 against 8).
+_CG_BLOCK = 4
+
+#: The Pallas CG kernel leaves when every system of its tile has brought
+#: the preconditioned residual r^T M^-1 r under _CG_TOL^2 times what it
+#: started from (or when its budget ends). One rule for every caller, set
+#: from a study through the interpreter on the kernel itself (PERF.md,
+#: PR 28): of a half-decade grid the loosest value at which every probed
+#: rung of ALS systems as ops/als builds them (explicit dual K 32-176 and
+#: primal K 208-5,120, the implicit eig-SMW dual and primal, before each
+#: of three iterations) ended within 1e-5 of a float64 solve of the same
+#: float32 system, or where the whole budget does not reach that (the
+#: explicit primal rungs under K 320: 3e-5 to 9e-5 at their 48) within
+#: what the whole budget reaches. 3e-7 left four rungs up to 1.3 times
+#: over; the residual stands that far under the error because the error
+#: is the residual through A^-1, up to sqrt(cond) times larger.
+_CG_TOL = 1e-7
+
+
+def _cg_kernel(a_ref, b_ref, x_ref, n_ref, *, iters: int,
+               tol: float = _CG_TOL, block: int = _CG_BLOCK):
     """Per-tile Jacobi-PCG: A stays VMEM-resident for every iteration; the
     matvec contracts over the sublane axis (A is symmetric, so A[t,s,:]
     rows serve as columns), which reduces to cheap vreg adds instead of
-    cross-lane shuffles."""
+    cross-lane shuffles.
+
+    `iters` is the cap. Between blocks of `block` iterations the tile
+    leaves once none of its systems has r^T M^-1 r above tol^2 times its
+    first value: a system that needs the whole budget gets it, and so do
+    the tile's others beside it (their extra passes move them by less
+    than their rounding, as the whole fixed budget did). A system with
+    b = 0 (a batch's padding) starts at 0 and holds nothing back.
+    `n_ref` takes the iterations the tile ran."""
     import jax
     import jax.numpy as jnp
 
@@ -228,8 +260,12 @@ def _cg_kernel(a_ref, b_ref, x_ref, *, iters: int):
     z = dinv * r
     p = z
     rz = jnp.sum(r * z, axis=1)
+    enough = (tol * tol) * rz
 
-    def body(_, c):
+    def unconverged(rz):
+        return jnp.max(jnp.where(rz > enough, 1, 0)) > 0
+
+    def step(_, c):
         x, r, p, rz = c
         Ap = mv(p)
         alpha = rz / jnp.maximum(jnp.sum(p * Ap, axis=1), 1e-30)
@@ -240,17 +276,30 @@ def _cg_kernel(a_ref, b_ref, x_ref, *, iters: int):
         p = z + (rz2 / jnp.maximum(rz, 1e-30))[:, None] * p
         return (x, r, p, rz2)
 
-    x, *_ = jax.lax.fori_loop(0, iters, body, (x, r, p, rz))
+    def go_on(c):
+        done, live = c[:2]
+        return jnp.logical_and(done < iters, live)
+
+    def run_block(c):
+        done, _, *state = c
+        n = jnp.minimum(block, iters - done)
+        state = jax.lax.fori_loop(0, n, step, tuple(state))
+        return (done + n, unconverged(state[-1]), *state)
+
+    done, _, x, *_ = jax.lax.while_loop(
+        go_on, run_block, (jnp.int32(0), unconverged(rz), x, r, p, rz))
     x_ref[:] = x
+    n_ref[:] = jnp.full(n_ref.shape, done, jnp.int32)
 
 
 def cg_solve_pallas(A, b, iters: int = 48, tile: int = 16,
-                    system: str = "primal"):
+                    system: str = "primal", interpret: bool = False):
     """TPU production solver: grid over batch tiles of 16 entities, each
-    tile's [16, R, R] system VMEM-resident across all CG iterations.
-    Measured (v5e, B=2048, R=200): ~27 ms vs 140 ms for XLA batched
-    cholesky+trsm — and the full ALS sweep goes from 9.8 s to ~2 s per
-    ML-20M iteration."""
+    tile's [16, R, R] systems VMEM-resident across its CG iterations, of
+    which `iters` is the cap: a tile stops when its systems have converged
+    (`_cg_kernel`). Returns the solutions and float32 [2]: the iterations
+    the tiles ran and the iterations `iters` allowed them, each times the
+    tile's systems (the batch's padding among them)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -265,24 +314,30 @@ def cg_solve_pallas(A, b, iters: int = 48, tile: int = 16,
             [A, jnp.broadcast_to(jnp.eye(rank, dtype=A.dtype),
                                  (pad, rank, rank))], axis=0)
         b = jnp.concatenate([b, jnp.zeros((pad, rank), b.dtype)], axis=0)
+    tiles = A.shape[0] // tile
     kernel = functools.partial(_cg_kernel, iters=iters)
-    x = pl.pallas_call(
+    x, ran = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((A.shape[0], rank), jnp.float32),
-        grid=(A.shape[0] // tile,),
+        out_shape=(jax.ShapeDtypeStruct((A.shape[0], rank), jnp.float32),
+                   jax.ShapeDtypeStruct((tiles, 1, 128), jnp.int32)),
+        grid=(tiles,),
         in_specs=[
             pl.BlockSpec((tile, rank, rank), lambda i: (i, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((tile, rank), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((tile, rank), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
+        out_specs=(pl.BlockSpec((tile, rank), lambda i: (i, 0),
+                                memory_space=pltpu.VMEM),
+                   pl.BlockSpec((1, 1, 128), lambda i: (i, 0, 0),
+                                memory_space=pltpu.VMEM)),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=100 * 1024 * 1024),
+        interpret=interpret,
         name=_kernel_name("cg", system, A),
     )(A.astype(jnp.float32), b)
-    return x[:B]
+    return x[:B], tile * jnp.stack(
+        [ran[:, 0, 0].sum(), iters * tiles]).astype(jnp.float32)
 
 
 def _blocked_cholesky_solve(A, b, panel: int = 8):
@@ -473,30 +528,44 @@ def resolve_solver(method: str, n_devices: int = 1) -> str:
     return "cholesky"
 
 
+def no_cg_iterations():
+    """`spd_solve`'s count for systems that no counting solver solved."""
+    import jax.numpy as jnp
+    return jnp.zeros((2,), jnp.float32)
+
+
 def spd_solve(A, b, method: str = "auto", iters: int | None = None,
               compute_dtype: str = "bfloat16", system: str = "primal"):
     """Batched SPD solve with backend-appropriate method selection.
+    Returns the solutions and float32 [2]: the CG iterations these systems
+    ran and the iterations their budget allowed, each summed over the
+    systems. Only 'cg_pallas' stops early and counts; every other method
+    reports (0, 0).
 
     method: 'auto' | 'cholesky' | 'cg' | 'cg_pallas' | 'schulz' |
             'schulz_pallas'
+    iters:  the CG methods' budget. 'cg' runs all of it; for 'cg_pallas'
+            it is the cap (a tile stops once its systems have converged).
     system: 'primal' | 'dual', which of a sweep's systems these are:
             only names the Pallas kernels in a device trace.
     """
     if method == "auto":
         method = resolve_solver(method)
+    counted = no_cg_iterations()
     if method == "cholesky":
-        return cholesky_solve(A, b)
-    if method == "cg":
-        return cg_solve(A, b, iters or 48)
-    if method == "cg_pallas":
-        return cg_solve_pallas(A, b, iters or 48, system=system)
-    if method == "schulz":
-        return schulz_solve(A, b, iters, compute_dtype)
-    if method == "schulz_pallas":
-        return schulz_solve_pallas(A, b, iters, compute_dtype,
-                                   system=system)
-    if method == "chol_pallas":
-        return cholesky_solve_pallas(A, b, system=system)
-    if method == "chol_blocked":   # jnp form (any backend / GSPMD meshes)
-        return _blocked_cholesky_solve(A, b)
-    raise ValueError(f"unknown solver {method!r}")
+        x = cholesky_solve(A, b)
+    elif method == "cg":
+        x = cg_solve(A, b, iters or 48)
+    elif method == "cg_pallas":
+        x, counted = cg_solve_pallas(A, b, iters or 48, system=system)
+    elif method == "schulz":
+        x = schulz_solve(A, b, iters, compute_dtype)
+    elif method == "schulz_pallas":
+        x = schulz_solve_pallas(A, b, iters, compute_dtype, system=system)
+    elif method == "chol_pallas":
+        x = cholesky_solve_pallas(A, b, system=system)
+    elif method == "chol_blocked":   # jnp form (any backend / GSPMD meshes)
+        x = _blocked_cholesky_solve(A, b)
+    else:
+        raise ValueError(f"unknown solver {method!r}")
+    return x, counted

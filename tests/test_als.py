@@ -439,7 +439,10 @@ def test_train_telemetry_phases():
     phases = {"plan_s", "upload_s", "iters_s", "compile_s", "sweeps_s",
               "s_per_iter", "fetch_s"}
     assert set(tel) == phases | {"solver", "compute_dtype", "sweep_chunk",
-                                 "n_devices"}
+                                 "n_devices", "cg_iters_run",
+                                 "cg_iters_budget"}
+    # no solve went through the Pallas CG, the one solver that counts
+    assert (tel["cg_iters_run"], tel["cg_iters_budget"]) == (0.0, 0.0)
     assert all(tel[k] >= 0 for k in phases)
     assert (tel["solver"], tel["compute_dtype"], tel["sweep_chunk"]) == (
         "cholesky", "float32", 1)

@@ -22,6 +22,53 @@ def make_spd(b, r, cond, seed=0):
     return A, rhs, x_true
 
 
+def cg_kernel_tile(A, rhs, iters, **kw):
+    """ops/solve._cg_kernel over one tile through the Pallas interpreter:
+    (x, the iterations the tile ran). `tol=0.0` is the kernel without its
+    exit: the whole fixed budget."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from predictionio_tpu.ops import solve as S
+
+    x, ran = pl.pallas_call(
+        functools.partial(S._cg_kernel, iters=iters, **kw),
+        out_shape=(jax.ShapeDtypeStruct(rhs.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((1, 1, 128), jnp.int32)),
+        interpret=True,
+    )(jnp.asarray(A), jnp.asarray(rhs))
+    ran = np.asarray(ran)
+    assert (ran == ran[0, 0, 0]).all()
+    return np.asarray(x), int(ran[0, 0, 0])
+
+
+def als_dual_systems(b, k, rank=200, seed=0):
+    """K x K dual systems as an explicit half-sweep builds them from
+    fresh factors (ops/als._solve_batch): M M^T + lam n I over rows
+    |N(0,1)| / sqrt(rank), lam 0.01, against ratings 1..5; the last
+    eighth of every system is the plan's padding (zero rows, reg on the
+    diagonal, y = 0)."""
+    rng = np.random.default_rng(seed)
+    n = k - k // 8
+    M = np.zeros((b, k, rank), np.float32)
+    M[:, :n] = np.abs(rng.standard_normal((b, n, rank))) / np.sqrt(rank)
+    A = np.einsum("bkr,blr->bkl", M, M) + \
+        np.float32(0.01 * n) * np.eye(k, dtype=np.float32)
+    y = np.zeros((b, k), np.float32)
+    y[:, :n] = rng.integers(1, 6, (b, n))
+    return A.astype(np.float32), y
+
+
+def float64_solve(A, rhs):
+    return np.linalg.solve(A.astype(np.float64),
+                           rhs.astype(np.float64)[..., None])[..., 0]
+
+
+def row_errors(x, want):
+    return np.linalg.norm(x - want, axis=1) / np.linalg.norm(want, axis=1)
+
+
 class TestSchulzSolve:
     @pytest.mark.parametrize("cond", [10.0, 1e3, 1e4])
     def test_matches_truth_well_conditioned(self, cond):
@@ -54,8 +101,7 @@ class TestSchulzSolve:
     def test_spd_solve_dispatch(self):
         A, rhs, _ = make_spd(4, 8, 10.0)
         for method in ("cholesky", "schulz"):
-            x = np.asarray(spd_solve(A, rhs, method=method,
-                                     compute_dtype="float32"))
+            x, _ = spd_solve(A, rhs, method=method, compute_dtype="float32")
             np.testing.assert_allclose(
                 x, np.linalg.solve(A, rhs[..., None])[..., 0],
                 rtol=1e-3, atol=1e-4)
@@ -94,42 +140,20 @@ class TestCGSolve:
 
     def test_cg_pallas_interpret_smoke(self):
         """Pallas CG kernel math check via the interpreter (no TPU)."""
-        import functools
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-        from predictionio_tpu.ops import solve as S
-
         A, rhs, x_true = make_spd(4, 16, 50.0)
-        kernel = functools.partial(S._cg_kernel, iters=32)
-        x = pl.pallas_call(
-            kernel,
-            out_shape=jax.ShapeDtypeStruct((4, 16), jnp.float32),
-            interpret=True,
-        )(jnp.asarray(A), jnp.asarray(rhs))
-        rel = np.linalg.norm(np.asarray(x) - x_true) / \
-            np.linalg.norm(x_true)
+        x, ran = cg_kernel_tile(A, rhs, iters=32)
+        rel = np.linalg.norm(x - x_true) / np.linalg.norm(x_true)
         assert rel < 1e-3
+        assert 0 < ran <= 32
 
     def test_cg_pallas_interpret_dual_shapes(self):
         """The dual path feeds the kernel [B, K, K] systems with K down to
         32 — check the kernel math at a representative small K."""
-        import functools
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-        from predictionio_tpu.ops import solve as S
-
         A, rhs, x_true = make_spd(16, 48, 80.0)
-        kernel = functools.partial(S._cg_kernel, iters=56)
-        x = pl.pallas_call(
-            kernel,
-            out_shape=jax.ShapeDtypeStruct((16, 48), jnp.float32),
-            interpret=True,
-        )(jnp.asarray(A), jnp.asarray(rhs))
-        rel = np.linalg.norm(np.asarray(x) - x_true) / \
-            np.linalg.norm(x_true)
+        x, ran = cg_kernel_tile(A, rhs, iters=56)
+        rel = np.linalg.norm(x - x_true) / np.linalg.norm(x_true)
         assert rel < 1e-3
+        assert 0 < ran <= 56
 
     @pytest.mark.parametrize("k", [24, 40, 56, 144])
     def test_cg_pallas_interpret_new_ladder_ks(self, k):
@@ -137,22 +161,11 @@ class TestCGSolve:
         multiples of 8 but not 16 (24, 40, 56, ...) — check the kernel
         math at each (that Mosaic compiles them is what chip_smoke.py
         shows on the chip: the rank-200 plan's dual route runs them)."""
-        import functools
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
-        from predictionio_tpu.ops import solve as S
-
         A, rhs, x_true = make_spd(8, k, 60.0)
-        kernel = functools.partial(S._cg_kernel, iters=k + 8)
-        x = pl.pallas_call(
-            kernel,
-            out_shape=jax.ShapeDtypeStruct((8, k), jnp.float32),
-            interpret=True,
-        )(jnp.asarray(A), jnp.asarray(rhs))
-        rel = np.linalg.norm(np.asarray(x) - x_true) / \
-            np.linalg.norm(x_true)
+        x, ran = cg_kernel_tile(A, rhs, iters=k + 8)
+        rel = np.linalg.norm(x - x_true) / np.linalg.norm(x_true)
         assert rel < 1e-3
+        assert 0 < ran <= k + 8
 
     def test_als_with_cg_matches_cholesky(self, mesh8):
         from predictionio_tpu.ops.als import ALSConfig, als_rmse, als_train
@@ -170,6 +183,142 @@ class TestCGSolve:
         assert abs(als_rmse(m_chol, r) - als_rmse(m_cg, r)) < 5e-3
         np.testing.assert_allclose(m_cg.user_factors, m_chol.user_factors,
                                    rtol=0.05, atol=0.05)
+
+
+@pytest.mark.parametrize("fuse", [False, True])
+def test_als_with_cg_pallas_matches_cholesky_and_counts(monkeypatch, fuse):
+    """als_train through the Pallas CG (the interpreter here), dual K x K
+    and primal systems both: the factors of the Cholesky train, and a
+    telemetry that carries the last iteration's CG iterations, run under
+    allowed."""
+    import functools
+    import jax
+    from predictionio_tpu.obs.metrics import get_registry
+    from predictionio_tpu.ops import solve as S
+    from predictionio_tpu.ops.als import ALSConfig, als_train
+    from predictionio_tpu.ops.ratings import RatingsCOO
+    from predictionio_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(S, "cg_solve_pallas", functools.partial(
+        S.cg_solve_pallas, interpret=True))
+    rng = np.random.default_rng(11)
+    n_u, n_i = 48, 120
+    # 36 ratings a user (K 40 < rank: Pallas dual), 14.4 an item (jnp CG)
+    # and a few heavy users (K >= rank: Pallas primal)
+    deg = np.where(np.arange(n_u) < 4, 100, 36)
+    ui = np.repeat(np.arange(n_u), deg).astype(np.int32)
+    ii = np.concatenate([rng.choice(n_i, d, replace=False)
+                         for d in deg]).astype(np.int32)
+    r = RatingsCOO(ui, ii, rng.integers(1, 6, ui.size).astype(np.float32),
+                   n_u, n_i)
+    mesh = make_mesh(devices=jax.devices()[:1])
+    kw = dict(rank=48, iterations=2, lam=0.1, seed=2, work_budget=2048,
+              fuse_iteration=fuse)
+    tel = {}
+    m_cg = als_train(r, ALSConfig(solver="cg_pallas", **kw), mesh,
+                     telemetry=tel)
+    m_chol = als_train(r, ALSConfig(solver="cholesky", **kw), mesh)
+    np.testing.assert_allclose(m_cg.user_factors, m_chol.user_factors,
+                               rtol=2e-3, atol=2e-4)
+    assert 0 < tel["cg_iters_run"] <= tel["cg_iters_budget"]
+    # whole tiles of 16 systems, the batches' padding among them
+    assert tel["cg_iters_run"] % 16 == 0 == tel["cg_iters_budget"] % 16
+    gauge = get_registry().get("pio_als_cg_iterations_run_ratio")
+    assert gauge.value == pytest.approx(
+        tel["cg_iters_run"] / tel["cg_iters_budget"])
+
+
+class TestCGStopsWhenConverged:
+    """The Pallas CG kernel leaves once its tile has converged, and its
+    iteration budget is the cap (ISSUE 28)."""
+
+    @pytest.mark.parametrize("k", [40, 88, 176])
+    def test_als_dual_systems_leave_early_and_exact(self, k):
+        A, y = als_dual_systems(16, k)
+        x, ran = cg_kernel_tile(A, y, iters=k + 8)
+        assert row_errors(x, float64_solve(A, y)).max() < 1e-5
+        assert 0 < ran < (k + 8) // 2
+
+    def test_hard_system_runs_to_the_cap_as_the_fixed_budget_does(self):
+        """Condition 1e6: no early exit, so bit for bit the answer of the
+        same kernel with no exit at all. Against the jnp CG, which sums
+        in another order, 40 unconverged float32 iterations of such a
+        system agree in how far they got (the tile's median residual: a
+        single system's swings by several times), not in x."""
+        A, rhs, _ = make_spd(16, 32, 1e6)
+        x, ran = cg_kernel_tile(A, rhs, iters=40)
+        assert ran == 40
+        whole, ran_whole = cg_kernel_tile(A, rhs, iters=40, tol=0.0)
+        assert ran_whole == 40 and np.array_equal(x, whole)
+
+        def residual(x):
+            return np.linalg.norm(np.einsum("brs,bs->br", A, x) - rhs,
+                                  axis=1) / np.linalg.norm(rhs, axis=1)
+        got = np.median(residual(x))
+        fixed = np.median(residual(np.asarray(cg_solve(A, rhs, iters=40))))
+        assert fixed / 2 < got < 2 * fixed
+
+    def test_cap_that_is_no_whole_number_of_blocks_is_kept(self):
+        A, rhs, _ = make_spd(16, 32, 1e6)
+        _, ran = cg_kernel_tile(A, rhs, iters=10)
+        assert ran == 10
+
+    def test_tile_of_padding_leaves_at_once(self):
+        """A = I, b = 0, as cg_solve_pallas pads a batch: zeros, no NaN,
+        and the tile holds nothing back (it leaves at the first look at
+        its residuals, before any block)."""
+        A = np.broadcast_to(np.eye(48, dtype=np.float32), (16, 48, 48))
+        x, ran = cg_kernel_tile(A, np.zeros((16, 48), np.float32), iters=56)
+        assert np.array_equal(x, np.zeros((16, 48), np.float32))
+        assert ran == 0
+
+    def test_mixed_tile_runs_as_long_as_its_hard_system_needs(self):
+        k = 88
+        easy, y = als_dual_systems(16, k)
+        hard, rhs, _ = make_spd(1, k, 100.0, seed=4)
+        _, ran_easy = cg_kernel_tile(easy, y, iters=k + 8)
+        alone = np.concatenate(
+            [hard, np.broadcast_to(np.eye(k, dtype=np.float32),
+                                   (15, k, k))])
+        _, ran_hard = cg_kernel_tile(
+            alone, np.concatenate([rhs, np.zeros((15, k), np.float32)]),
+            iters=k + 8)
+        A, b = easy.copy(), y.copy()
+        A[5], b[5] = hard[0], rhs[0]
+        x, ran = cg_kernel_tile(A, b, iters=k + 8)
+        assert k + 8 > ran == ran_hard > 2 * ran_easy
+        assert row_errors(x, float64_solve(A, b)).max() < 1e-5
+
+    def test_cg_solve_pallas_counts_tiles(self):
+        """The wrapper's grid: 40 systems are three tiles, the last one
+        half padding; the count is over all 48."""
+        import jax.numpy as jnp
+        from predictionio_tpu.ops.solve import cg_solve_pallas
+        A, y = als_dual_systems(40, 40)
+        x, counted = cg_solve_pallas(jnp.asarray(A), jnp.asarray(y),
+                                     iters=48, system="dual",
+                                     interpret=True)
+        assert x.shape == (40, 40)
+        assert row_errors(np.asarray(x), float64_solve(A, y)).max() < 1e-5
+        run, allowed = np.asarray(counted)
+        assert allowed == 3 * 16 * 48
+        assert run % 16 == 0 and 0 < run < allowed / 2
+
+    def test_spd_solve_reports_run_and_allowed(self, monkeypatch):
+        import functools
+        import jax.numpy as jnp
+        from predictionio_tpu.ops import solve as S
+        monkeypatch.setattr(S, "cg_solve_pallas", functools.partial(
+            S.cg_solve_pallas, interpret=True))
+        A, y = als_dual_systems(20, 40)
+        x, counted = S.spd_solve(jnp.asarray(A), jnp.asarray(y),
+                                 method="cg_pallas", iters=48,
+                                 system="dual")
+        run, allowed = np.asarray(counted)
+        assert allowed == 32 * 48 and 0 < run < allowed / 2
+        _, counted = S.spd_solve(jnp.asarray(A), jnp.asarray(y),
+                                 method="cg", iters=48)
+        assert np.array_equal(np.asarray(counted), [0.0, 0.0])
 
 
 class TestBlockedCholesky:
@@ -224,7 +373,7 @@ class TestBlockedCholesky:
 
     def test_spd_solve_dispatch(self):
         A, rhs, x_true = make_spd(4, 32, 50.0, seed=6)
-        x = np.asarray(spd_solve(A, rhs, method="chol_blocked"))
+        x = np.asarray(spd_solve(A, rhs, method="chol_blocked")[0])
         rel = np.linalg.norm(x - x_true) / np.linalg.norm(x_true)
         assert rel < 1e-4
 
