@@ -4,7 +4,7 @@
 Drives the main path once, through the entry points a user would call, each
 its own OS process, at the full width of the recommendation template
 (explicit ALS, rank 200, the ML-20M vocabulary of 138,493 users x 26,744
-items; ratings generated from a seed in the shape of bench.synthetic_ml20m):
+items; ratings generated from a seed in ML-20M's shape):
 
     store populate -> pio eventserver (REST singles + /events/columnar.json)
     -> pio train -> pio deploy -> POST /queries.json -> pio status /
@@ -198,9 +198,8 @@ def device_memory(metrics_text):
 # ---------------------------------------------------------------------------
 
 def synthetic_ratings(n_users, n_items, nnz, seed):
-    """Power-law item popularity + lognormal user activity — the shape of
-    bench.synthetic_ml20m, regenerated here so the smoke needs nothing of
-    bench.py. Returns event-ordered (user, item, rating) with repeats: a
+    """Power-law item popularity + lognormal user activity, ML-20M's
+    shape. Returns event-ordered (user, item, rating) with repeats: a
     later event for the same pair overrides the earlier one (the
     template's latest-wins dedup)."""
     rng = np.random.default_rng(seed)

@@ -151,10 +151,8 @@ class ECommAlgorithmParams(Params):
     alpha: float = 1.0
     seed: Optional[int] = None
     compute_dtype: Optional[str] = None  # None = bf16 on TPU, f32 on CPU
-    # solver-call batching / whole-iteration fusion (ops/als.ALSConfig
-    # sweep_chunk / fuse_iteration; 0 = auto)
+    # solver-call batching (ops/als.ALSConfig.sweep_chunk; 0 = auto)
     sweep_chunk: int = 0
-    fuse_iteration: bool = False
 
 
 @dataclass
@@ -192,7 +190,6 @@ class ECommAlgorithm(P2LAlgorithm):
         from predictionio_tpu.ops.als import default_compute_dtype
         cfg = ALSConfig(rank=p.rank, iterations=p.num_iterations, lam=p.lam,
                         sweep_chunk=p.sweep_chunk,
-                        fuse_iteration=p.fuse_iteration,
                         implicit_prefs=True, alpha=p.alpha,
                         seed=p.seed if p.seed is not None else 0,
                         compute_dtype=p.compute_dtype

@@ -329,10 +329,8 @@ class ALSAlgorithmParams(Params):
     # custom-query variant: property keys copied onto each ItemScore in the
     # result JSON (e.g. ("creationYear",)); requires data source read_items
     return_properties: Tuple[str, ...] = ()
-    # solver-call batching / whole-iteration fusion (ops/als.ALSConfig
-    # sweep_chunk / fuse_iteration; 0 = auto)
+    # solver-call batching (ops/als.ALSConfig.sweep_chunk; 0 = auto)
     sweep_chunk: int = 0
-    fuse_iteration: bool = False
     # sharded online plane (ISSUE 12): 'model' trains, folds AND
     # serves the factor tables row-sharded over the mesh model axis
     # (ShardedTable handles end to end) — the configuration for
@@ -420,7 +418,6 @@ class ALSAlgorithm(P2LAlgorithm):
             mesh = model_mesh(len(jax.devices()))
         cfg = ALSConfig(rank=p.rank, iterations=p.num_iterations, lam=p.lam,
                         sweep_chunk=p.sweep_chunk,
-                        fuse_iteration=p.fuse_iteration,
                         seed=p.seed if p.seed is not None else 0,
                         compute_dtype=p.compute_dtype
                         or default_compute_dtype(),
@@ -428,8 +425,8 @@ class ALSAlgorithm(P2LAlgorithm):
                                          else "replicated"),
                         keep_sharded=sharded)
         # per-phase timing of the train that just ran (plan/upload/iters/
-        # fetch) — consumed by bench.py's product-path mode; the hard
-        # syncs it adds are negligible next to a real train
+        # fetch) for the train report (workflow/core_workflow.py); the
+        # hard syncs it adds are negligible next to a real train
         self.last_train_telemetry = {}
         model = als_train(pd.ratings_coo, cfg, mesh=mesh,
                           telemetry=self.last_train_telemetry)
@@ -746,7 +743,6 @@ class MeshALSAlgorithm(ALSAlgorithm):
         from predictionio_tpu.ops.als import default_compute_dtype
         cfg = ALSConfig(rank=p.rank, iterations=p.num_iterations, lam=p.lam,
                         sweep_chunk=p.sweep_chunk,
-                        fuse_iteration=p.fuse_iteration,
                         seed=p.seed if p.seed is not None else 0,
                         compute_dtype=p.compute_dtype
                         or default_compute_dtype(),

@@ -257,10 +257,8 @@ class ALSAlgorithmParams(Params):
     # add-and-return-item-properties variant: property keys copied onto
     # each ItemScore in the result JSON (missing -> null)
     return_properties: Tuple[str, ...] = ()
-    # solver-call batching / whole-iteration fusion (ops/als.ALSConfig
-    # sweep_chunk / fuse_iteration; 0 = auto)
+    # solver-call batching (ops/als.ALSConfig.sweep_chunk; 0 = auto)
     sweep_chunk: int = 0
-    fuse_iteration: bool = False
 
 
 @dataclass(kw_only=True)
@@ -354,7 +352,6 @@ class ALSAlgorithm(P2LAlgorithm):
         from predictionio_tpu.ops.als import default_compute_dtype
         cfg = ALSConfig(rank=p.rank, iterations=p.num_iterations, lam=p.lam,
                         sweep_chunk=p.sweep_chunk,
-                        fuse_iteration=p.fuse_iteration,
                         implicit_prefs=True, alpha=p.alpha,
                         seed=p.seed if p.seed is not None else 0,
                         compute_dtype=p.compute_dtype

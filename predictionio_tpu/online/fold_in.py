@@ -71,7 +71,7 @@ class FoldInConfig:
     implicit_prefs: bool = False
     alpha: float = 1.0
     lambda_scaling: str = "nratings"   # 'nratings' (ALS-WR) | 'constant'
-    solver: str = "auto"               # ops/solve.spd_solve methods
+    solver: str = "auto"               # ops/solve.resolve_solver's names
     compute_dtype: Optional[str] = None  # None = bf16 on TPU, f32 on CPU
     work_budget: int = 1 << 20
     sweep_chunk: int = 0
@@ -135,7 +135,7 @@ class FoldInStats:
     sentinel_rollback: bool = False
     # wall seconds spent in sentinel work (baseline norm + per-side row
     # checks, including the device sync each check forces — an upper
-    # bound on the tax). Feeds bench.py's guard_overhead_ms.
+    # bound on the tax).
     guard_wall_s: float = 0.0
 
 
@@ -670,8 +670,7 @@ def fold_in_coo(als: ALSModel, coo: RatingsCOO,
 
     # -- sentinel (ISSUE 5): touched rows checked after each side -----------
     sentinel = None
-    if cfg.sentinel and not solver.startswith("diag_") \
-            and guard_enabled():
+    if cfg.sentinel and guard_enabled():
         g0 = time.perf_counter()
         # O(model) baseline scan only on the FIRST tick of a model
         # lineage: every published fold carries its norm forward (the

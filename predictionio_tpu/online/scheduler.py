@@ -714,7 +714,6 @@ class DeltaTrainingScheduler:
             guard_wall_s += _time.perf_counter() - g0
             report["gateReport"] = gate_report
         # the robustness tax, first-class: sentinel + gate wall per tick
-        # (bench.py banks it as guard_overhead_ms)
         report["guardOverheadMs"] = round(guard_wall_s * 1000, 3)
         if self.gatekeeper is not None:
             TRACER.annotate(gatesPassed=gate_report["passed"])

@@ -61,9 +61,9 @@ class ALSConfig:
     # cost once solves are fast); solves still build/solve f32 normal
     # equations from the gathered rows, so per-iteration quality loss is
     # bounded by bf16 rounding of the carried factors.
-    solver: str = "auto"  # see ops/solve.py spd_solve
-    # auto = VMEM-resident CG Pallas kernel on TPU (XLA's batched cholesky
-    # runs at ~0.05% MXU there), LAPACK cholesky on CPU.
+    solver: str = "auto"  # the names ops/solve.resolve_solver takes
+    # auto = VMEM-resident CG Pallas kernel on one TPU, jnp CG on a TPU
+    # mesh, LAPACK cholesky on CPU.
     solver_iters: Optional[int] = None  # cap on the primal CG iterations
     # None = the solver default (48). The Pallas kernel stops a tile of
     # systems once they have converged (ops/solve._cg_kernel) and runs to
@@ -106,10 +106,9 @@ class ALSConfig:
     # per shard). False keeps the legacy gather-to-host behavior.
     sweep_chunk: int = 0
     # Merge this many same-shape solve batches into one scan step (one
-    # solver call over chunk*B systems). The measured solver cost is
-    # per-CALL fixed (~20-30 ms on v5e regardless of CG iteration count —
-    # docs/benchmarks.md), so fewer, larger calls amortize it; batches
-    # within a half-sweep are independent (they read only the counterpart
+    # solver call over chunk*B systems). Each solver call has a fixed
+    # cost, so fewer, larger calls amortize it; batches within a
+    # half-sweep are independent (they read only the counterpart
     # table), so merging changes no math. Bounded by the normal-matrix
     # memory per step (chunk * B * S^2 * 4B). 0 = auto: 4 on single-device
     # TPU, 1 elsewhere.
@@ -118,12 +117,7 @@ class ALSConfig:
     # bucket_lengths). At ML-20M scale nearly every ladder K is its own
     # uniquely-shaped batch, so the ladder size IS the solver-call count
     # per sweep (~125/iteration at 1.125); a coarser ratio trades padding
-    # (more gather bytes + Gram flops) for fewer calls. The ablation's
-    # ratio rows measure the tradeoff on hardware before any flip.
-    fuse_iteration: bool = False
-    # Trace both half-sweeps (and the implicit Grams) into ONE program per
-    # iteration, letting XLA overlap the item-side gather DMAs with the
-    # tail of the user-side solves and dropping a dispatch boundary.
+    # (more gather bytes + Gram flops) for fewer calls.
     sentinel: bool = True
     # Numerical sentinel (ISSUE 5, guard/sentinels.py): after every
     # iteration the factor tables are checked on-device for finiteness
@@ -196,19 +190,8 @@ def _dual_system_solve(M, y, K: int, solver: str,
     CG, which runs the whole K+8. Returns the solution and `spd_solve`'s
     count of CG iterations (run, allowed)."""
     import jax
-    import jax.numpy as jnp
 
-    from predictionio_tpu.ops.solve import no_cg_iterations, spd_solve
-    if solver == "diag_nosolve":
-        # perf diagnostic, NOT a solver (wrong math by design): skip the
-        # solve but keep M alive — the dual Gram einsum is the
-        # traffic/flops being measured. The optimization_barrier stops
-        # XLA's algebraic simplifier from folding sum-of-einsum into a
-        # cheaper contraction that never materializes the Gram. Covers
-        # every dual call site (explicit Woodbury and implicit eig-SMW).
-        M_live = jax.lax.optimization_barrier(M)
-        return (y + M_live.sum(axis=2) * jnp.float32(1e-12),
-                no_cg_iterations())
+    from predictionio_tpu.ops.solve import spd_solve
     method = "cg" if (K < 32 and solver == "cg_pallas") else solver
     if iters_cap is not None and iters_cap < 1:
         # 0 would fall into spd_solve's `iters or 48` unset-default and
@@ -246,7 +229,7 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
     import jax
     import jax.numpy as jnp
 
-    from predictionio_tpu.ops.solve import no_cg_iterations, spd_solve
+    from predictionio_tpu.ops.solve import spd_solve
 
     cd = jnp.dtype(compute_dtype)
     with jax.named_scope("pio.sweep.gather"):
@@ -258,15 +241,6 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
         n = mask.sum(axis=-1)                        # ratings per entity
         reg = (lam * jnp.maximum(n, 1.0) if nratings_reg
                else jnp.full_like(n, lam))
-
-    if solver == "diag_gather":
-        # perf diagnostic, NOT a solver (wrong math by design): gather +
-        # one light K*R einsum + scatter, i.e. the sweep minus the Gram
-        # and minus the solve. Ablation rows subtract it from
-        # diag_nosolve / full rows to locate the iteration time.
-        x = jnp.einsum("bk,bkr->br", mask.astype(cd), Vc,
-                       preferred_element_type=jnp.float32)
-        return _scatter_rows(factors_out, rows, x), no_cg_iterations()
 
     if dual_solve == "auto" and not implicit and K < rank:
         # dual/Woodbury: with M = mask-weighted factor rows [K, R],
@@ -354,17 +328,9 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
                            preferred_element_type=jnp.float32)
     with jax.named_scope("pio.sweep.gram"):
         A = A + reg[:, None, None] * eye
-    if solver == "diag_nosolve":
-        # perf diagnostic: keep A alive against algebraic simplification
-        # (see the _dual_system_solve note)
-        x = b + jax.lax.optimization_barrier(A).sum(axis=2) \
-            * jnp.float32(1e-12)
-        cg = no_cg_iterations()
-    else:
-        with jax.named_scope("pio.sweep.solve.jnp_cg" if solver == "cg"
-                             else "pio.sweep.solve.primal"):
-            x, cg = spd_solve(A, b, method=solver, iters=solver_iters,
-                              compute_dtype=compute_dtype)
+    with jax.named_scope("pio.sweep.solve.jnp_cg" if solver == "cg"
+                         else "pio.sweep.solve.primal"):
+        x, cg = spd_solve(A, b, method=solver, iters=solver_iters)
     return _scatter_rows(factors_out, rows, x), cg
 
 
@@ -400,7 +366,6 @@ def _solve_sweep_impl(factors_out, counter_factors, gram, groups, lam,
 
 _SWEEP_STATICS = ("nratings_reg", "implicit", "rank", "compute_dtype",
                   "solver", "dual_solve", "solver_iters", "dual_iters_cap")
-_ITER_STATICS = _SWEEP_STATICS + ("n_users", "n_items")
 
 #: One half-iteration in ONE dispatch: `groups` is a tuple of stacked
 #: same-shape batch groups (rows [N,B], idx/val/mask [N,B,K]); each group
@@ -411,39 +376,6 @@ _ITER_STATICS = _SWEEP_STATICS + ("n_users", "n_items")
 #: program per plan signature. Returns (table, CG iterations [2]).
 _solve_sweep = __import__("jax").jit(
     _solve_sweep_impl, static_argnames=_SWEEP_STATICS, donate_argnums=(0,))
-
-
-def _solve_iteration_impl(U, V, user_groups, item_groups, lam, alpha, *,
-                          nratings_reg: bool, implicit: bool, rank: int,
-                          compute_dtype: str, solver: str,
-                          dual_solve: str = "auto",
-                          solver_iters: Optional[int] = None,
-                          dual_iters_cap: Optional[int] = None,
-                          n_users: int = 0, n_items: int = 0):
-    gram_of = _gram_eig_impl if dual_solve == "auto" else _gram_impl
-    gram_v = gram_of(V, n_items) if implicit else None
-    U, cg_u = _solve_sweep_impl(
-        U, V, gram_v, user_groups, lam, alpha, nratings_reg=nratings_reg,
-        implicit=implicit, rank=rank, compute_dtype=compute_dtype,
-        solver=solver, dual_solve=dual_solve, solver_iters=solver_iters,
-        dual_iters_cap=dual_iters_cap)
-    gram_u = gram_of(U, n_users) if implicit else None
-    V, cg_v = _solve_sweep_impl(
-        V, U, gram_u, item_groups, lam, alpha, nratings_reg=nratings_reg,
-        implicit=implicit, rank=rank, compute_dtype=compute_dtype,
-        solver=solver, dual_solve=dual_solve, solver_iters=solver_iters,
-        dual_iters_cap=dual_iters_cap)
-    return U, V, cg_u + cg_v
-
-
-#: One FULL iteration (user sweep then item sweep, plus the implicit
-#: Grams) traced as a single program: the half-sweeps are data-dependent
-#: (the item sweep reads the just-updated U), but fusing them lets XLA
-#: prefetch the item side's gather DMAs behind the tail of the user
-#: side's solves and drops a host dispatch boundary per iteration.
-_solve_iteration = __import__("jax").jit(
-    _solve_iteration_impl, static_argnames=_ITER_STATICS,
-    donate_argnums=(0, 1))
 
 
 def _live_gram(factors, n_live: Optional[int]):
@@ -668,16 +600,16 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
     cg_iters_budget: the CG iterations the last iteration's Pallas solves
     ran and were allowed, summed over their systems (0 and 0 under a
     solver that is not cg_pallas)."""
+    import dataclasses
     import time as _time
 
     import jax
+
+    from predictionio_tpu.ops.solve import resolve_solver
     mesh = mesh or current_mesh()
     t0 = _time.perf_counter()
-    if cfg.solver == "auto":
-        import dataclasses
-        from predictionio_tpu.ops.solve import resolve_solver
-        cfg = dataclasses.replace(
-            cfg, solver=resolve_solver(cfg.solver, mesh.n_devices))
+    cfg = dataclasses.replace(
+        cfg, solver=resolve_solver(cfg.solver, mesh.n_devices))
     dp = mesh.data_parallelism
     user_plan = plan_for_users(ratings, work_budget=cfg.work_budget,
                                batch_multiple=dp,
@@ -736,9 +668,7 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
     # a checkpointed last-good iteration (HBM copies, never host fetch)
     sentinel = None
     last_good = None
-    # diag_* pseudo-solvers are perf diagnostics with wrong math by
-    # design — their outputs are not factor tables worth guarding
-    if cfg.sentinel and not cfg.solver.startswith("diag_"):
+    if cfg.sentinel:
         from predictionio_tpu.guard.sentinels import (SweepSentinel,
                                                       device_copy,
                                                       guard_enabled)
@@ -772,36 +702,17 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
             float(np.asarray(jax.device_get(V[:1, :1]))[0, 0])
             telemetry["first_iter_s"] = _time.perf_counter() - t0
 
-    from predictionio_tpu.obs import costmon
     _cg_iters_log.clear()          # an earlier train's, or a fold tick's
-    if cfg.fuse_iteration:
-        for it in range(cfg.iterations):
-            with costmon.executable(costmon.ALS_SWEEP,
-                                    defer_to_outer=True):
-                U, V, cg = _solve_iteration(
-                    U, V, user_batches, item_batches, lam_dev, alpha_dev,
-                    nratings_reg=(cfg.lambda_scaling == "nratings"),
-                    implicit=cfg.implicit_prefs, rank=cfg.rank,
-                    compute_dtype=cfg.compute_dtype, solver=cfg.solver,
-                    dual_solve=cfg.dual_solve,
-                    solver_iters=cfg.solver_iters,
-                    dual_iters_cap=cfg.dual_iters_cap,
-                    n_users=ratings.n_users, n_items=ratings.n_items)
-            _cg_iters_log.append([cg])
-            if not _checked(it):
-                break
-            _first_iteration_done(it)
-    else:
-        for it in range(cfg.iterations):
-            gram_v = _side_gram(cfg, V, ratings.n_items, "item")
-            U = _run_side(user_batches, U, V, cfg, gram_v, lam_dev,
-                          alpha_dev, side="user")
-            gram_u = _side_gram(cfg, U, ratings.n_users, "user")
-            V = _run_side(item_batches, V, U, cfg, gram_u, lam_dev,
-                          alpha_dev, side="item")
-            if not _checked(it):
-                break
-            _first_iteration_done(it)
+    for it in range(cfg.iterations):
+        gram_v = _side_gram(cfg, V, ratings.n_items, "item")
+        U = _run_side(user_batches, U, V, cfg, gram_v, lam_dev,
+                      alpha_dev, side="user")
+        gram_u = _side_gram(cfg, U, ratings.n_users, "user")
+        V = _run_side(item_batches, V, U, cfg, gram_u, lam_dev,
+                      alpha_dev, side="item")
+        if not _checked(it):
+            break
+        _first_iteration_done(it)
     if telemetry is not None:
         # hard sync again: the loop above only enqueues device work
         float(np.asarray(jax.device_get(V[:1, :1]))[0, 0])

@@ -6,7 +6,7 @@ property of the actual program, not an assumption. Used by
 ``__graft_entry__.dryrun_multichip`` (the in-env weak-scaling proxy: no
 multi-chip hardware is reachable here, but the compiled program's
 collective bytes + the chip's published ICI bandwidth bound the scaling
-loss) and available to operators via ``bench.py --mesh-sweep``.
+loss).
 
 Role in the reference stack: the Spark UI's shuffle read/write metrics —
 the thing an MLlib operator watches to see communication cost

@@ -320,12 +320,11 @@ class TestMeshEquivalence:
 
 
 @pytest.mark.parametrize("implicit", [False, True])
-def test_sweep_chunk_and_fused_iteration_match_baseline(implicit):
+def test_sweep_chunk_matches_baseline(implicit):
     """sweep_chunk merges independent solve batches into larger scan
-    steps and fuse_iteration traces both half-sweeps into one program —
-    neither changes any math, so factors must match the default path to
-    float tolerance (explicit exactly: same ops, same order within each
-    system)."""
+    steps, which changes no math, so factors must match the default path
+    to float tolerance (explicit exactly: same ops, same order within
+    each system)."""
     rng = np.random.default_rng(13)
     n_u, n_i, nnz = 500, 150, 7000
     ui = rng.integers(0, n_u, nnz)
@@ -335,47 +334,18 @@ def test_sweep_chunk_and_fused_iteration_match_baseline(implicit):
     kw = dict(rank=8, iterations=3, lam=0.05, seed=2, work_budget=512,
               implicit_prefs=implicit)
     base = als_train(r, ALSConfig(**kw))
-    for variant in (ALSConfig(sweep_chunk=3, **kw),
-                    ALSConfig(fuse_iteration=True, **kw),
-                    ALSConfig(sweep_chunk=2, fuse_iteration=True, **kw)):
-        m = als_train(r, variant)
-        np.testing.assert_allclose(m.user_factors, base.user_factors,
-                                   rtol=2e-4, atol=2e-5)
-        np.testing.assert_allclose(m.item_factors, base.item_factors,
-                                   rtol=2e-4, atol=2e-5)
-
-
-def test_diag_solvers_run_and_are_finite():
-    """The ablation's stage-split diagnostics (solver='diag_gather' /
-    'diag_nosolve') are wrong-math perf probes: they must trace through
-    the production sweep machinery (dual and primal branches, chunked
-    scan) and produce finite factor tables, never NaN/inf — that is all
-    the ablation needs from them (bench.py solver_ablation)."""
-    rng = np.random.default_rng(17)
-    n_u, n_i, nnz = 400, 120, 6000
-    ui = rng.integers(0, n_u, nnz)
-    ii = rng.integers(0, n_i, nnz)
-    vv = rng.uniform(1, 5, nnz).astype(np.float32)
-    r = RatingsCOO(ui, ii, vv, n_u, n_i)
-    for solver in ("diag_gather", "diag_nosolve"):
-        # rank above and below the bucket Ks exercises both the dual
-        # (K < rank) and primal branches; implicit covers the eig-SMW
-        # dual call site too
-        for rank, implicit in ((4, False), (16, False), (16, True)):
-            m = als_train(r, ALSConfig(rank=rank, iterations=1, lam=0.05,
-                                       seed=2, work_budget=512,
-                                       sweep_chunk=2, solver=solver,
-                                       implicit_prefs=implicit))
-            assert np.isfinite(m.user_factors).all()
-            assert np.isfinite(m.item_factors).all()
+    m = als_train(r, ALSConfig(sweep_chunk=3, **kw))
+    np.testing.assert_allclose(m.user_factors, base.user_factors,
+                               rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(m.item_factors, base.item_factors,
+                               rtol=2e-4, atol=2e-5)
 
 
 def test_bucket_ratio_coarse_matches_default():
     """bucket_ratio only changes the padded segment-length ladder —
     masked padding positions contribute exact zeros, so a coarse ladder
     must train to the same factors as the default within float
-    reassociation tolerance (the ablation's ratio rows measure the
-    speed/padding tradeoff; this pins that the math is unchanged)."""
+    reassociation tolerance."""
     rng = np.random.default_rng(29)
     n_u, n_i, nnz = 500, 150, 8000
     ui = rng.integers(0, n_u, nnz)

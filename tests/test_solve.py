@@ -1,13 +1,12 @@
-"""Schulz-iteration SPD solver vs LAPACK — the TPU hot-loop replacement
-for batched cholesky (ops/solve.py).
+"""The batched SPD solvers (ops/solve.py): CG, jnp and Pallas, against
+LAPACK cholesky and float64, alone and inside als_train.
 """
 
 import numpy as np
 import pytest
 
 from predictionio_tpu.ops.solve import (cg_solve, cholesky_solve,
-                                        resolve_solver, schulz_solve,
-                                        spd_solve)
+                                        resolve_solver, spd_solve)
 
 
 def make_spd(b, r, cond, seed=0):
@@ -69,52 +68,6 @@ def row_errors(x, want):
     return np.linalg.norm(x - want, axis=1) / np.linalg.norm(want, axis=1)
 
 
-class TestSchulzSolve:
-    @pytest.mark.parametrize("cond", [10.0, 1e3, 1e4])
-    def test_matches_truth_well_conditioned(self, cond):
-        A, rhs, x_true = make_spd(16, 32, cond)
-        x = np.asarray(schulz_solve(A, rhs, compute_dtype="float32"))
-        rel = np.linalg.norm(x - x_true) / np.linalg.norm(x_true)
-        assert rel < 1e-3, f"cond={cond}: rel error {rel}"
-
-    def test_matches_cholesky_on_als_like_systems(self):
-        """ALS normal matrices: Gram + lam*n*I (always comfortably
-        conditioned thanks to the per-entity regularizer)."""
-        rng = np.random.default_rng(1)
-        B, K, R = 8, 40, 16
-        V = rng.standard_normal((B, K, R)).astype(np.float32) / np.sqrt(R)
-        A = np.einsum("bkr,bks->brs", V, V) + \
-            0.1 * K * np.eye(R, dtype=np.float32)
-        rhs = rng.standard_normal((B, R)).astype(np.float32)
-        x_chol = np.asarray(cholesky_solve(A, rhs))
-        x_schulz = np.asarray(schulz_solve(A, rhs, compute_dtype="float32"))
-        np.testing.assert_allclose(x_schulz, x_chol, rtol=2e-3, atol=2e-4)
-
-    def test_bf16_compute_still_converges(self):
-        """Schulz is self-correcting: bf16 matmuls with f32 accumulation
-        land within bf16-appropriate tolerance."""
-        A, rhs, x_true = make_spd(8, 24, 100.0)
-        x = np.asarray(schulz_solve(A, rhs, compute_dtype="bfloat16"))
-        rel = np.linalg.norm(x - x_true) / np.linalg.norm(x_true)
-        assert rel < 3e-2
-
-    def test_spd_solve_dispatch(self):
-        A, rhs, _ = make_spd(4, 8, 10.0)
-        for method in ("cholesky", "schulz"):
-            x, _ = spd_solve(A, rhs, method=method, compute_dtype="float32")
-            np.testing.assert_allclose(
-                x, np.linalg.solve(A, rhs[..., None])[..., 0],
-                rtol=1e-3, atol=1e-4)
-        with pytest.raises(ValueError):
-            spd_solve(A, rhs, method="qr")
-
-    def test_resolve_solver(self):
-        assert resolve_solver("cholesky") == "cholesky"
-        # on the CPU test backend auto is cholesky
-        assert resolve_solver("auto", 1) == "cholesky"
-        assert resolve_solver("auto", 8) == "cholesky"
-
-
 class TestCGSolve:
     @pytest.mark.parametrize("cond,iters", [(10.0, 32), (1e3, 128),
                                             (1e4, 384)])
@@ -167,6 +120,23 @@ class TestCGSolve:
         assert rel < 1e-3
         assert 0 < ran <= k + 8
 
+    def test_spd_solve_dispatch(self):
+        A, rhs, _ = make_spd(4, 8, 10.0)
+        for method in ("cholesky", "cg"):
+            x, _ = spd_solve(A, rhs, method=method)
+            np.testing.assert_allclose(
+                x, np.linalg.solve(A, rhs[..., None])[..., 0],
+                rtol=1e-3, atol=1e-4)
+        with pytest.raises(ValueError):
+            spd_solve(A, rhs, method="qr")
+
+    def test_resolve_solver(self):
+        for name in ("cholesky", "cg", "cg_pallas"):
+            assert resolve_solver(name) == name
+        # on the CPU test backend auto is cholesky
+        assert resolve_solver("auto", 1) == "cholesky"
+        assert resolve_solver("auto", 8) == "cholesky"
+
     def test_als_with_cg_matches_cholesky(self, mesh8):
         from predictionio_tpu.ops.als import ALSConfig, als_rmse, als_train
         from predictionio_tpu.ops.ratings import RatingsCOO
@@ -185,8 +155,7 @@ class TestCGSolve:
                                    rtol=0.05, atol=0.05)
 
 
-@pytest.mark.parametrize("fuse", [False, True])
-def test_als_with_cg_pallas_matches_cholesky_and_counts(monkeypatch, fuse):
+def test_als_with_cg_pallas_matches_cholesky_and_counts(monkeypatch):
     """als_train through the Pallas CG (the interpreter here), dual K x K
     and primal systems both: the factors of the Cholesky train, and a
     telemetry that carries the last iteration's CG iterations, run under
@@ -212,8 +181,7 @@ def test_als_with_cg_pallas_matches_cholesky_and_counts(monkeypatch, fuse):
     r = RatingsCOO(ui, ii, rng.integers(1, 6, ui.size).astype(np.float32),
                    n_u, n_i)
     mesh = make_mesh(devices=jax.devices()[:1])
-    kw = dict(rank=48, iterations=2, lam=0.1, seed=2, work_budget=2048,
-              fuse_iteration=fuse)
+    kw = dict(rank=48, iterations=2, lam=0.1, seed=2, work_budget=2048)
     tel = {}
     m_cg = als_train(r, ALSConfig(solver="cg_pallas", **kw), mesh,
                      telemetry=tel)
@@ -321,79 +289,6 @@ class TestCGStopsWhenConverged:
         assert np.array_equal(np.asarray(counted), [0.0, 0.0])
 
 
-class TestBlockedCholesky:
-    """The MXU-packed panel factorization (cholesky_solve_pallas /
-    _blocked_cholesky_solve): panel trailing updates are batched matmuls,
-    substitution is 2R^2 per system — the dense-bucket candidate
-    replacing CG's VPU-bound matvecs."""
-
-    @pytest.mark.parametrize("cond", [10.0, 1e3, 1e5])
-    def test_jnp_form_matches_truth(self, cond):
-        from predictionio_tpu.ops.solve import _blocked_cholesky_solve
-        A, rhs, x_true = make_spd(8, 64, cond)
-        x = np.asarray(_blocked_cholesky_solve(A, rhs))
-        rel = np.linalg.norm(x - x_true) / np.linalg.norm(x_true)
-        # direct method: error ~ cond * eps_f32
-        assert rel < max(1e-4, cond * 5e-6)
-
-    def test_jnp_form_matches_lapack(self):
-        from predictionio_tpu.ops.solve import _blocked_cholesky_solve
-        A, rhs, _ = make_spd(16, 40, 2e3, seed=3)
-        x = np.asarray(_blocked_cholesky_solve(A, rhs))
-        ref = np.asarray(cholesky_solve(A, rhs))
-        np.testing.assert_allclose(x, ref, rtol=2e-3, atol=2e-4)
-
-    def test_rank_below_panel_width(self):
-        """K-dim dual systems can be smaller than one panel (K < 8); the
-        jnp form must pad internally, not silently return zeros."""
-        from predictionio_tpu.ops.solve import _blocked_cholesky_solve
-        A, rhs, x_true = make_spd(6, 5, 30.0, seed=8)
-        x = np.asarray(_blocked_cholesky_solve(A, rhs))
-        rel = np.linalg.norm(x - x_true) / np.linalg.norm(x_true)
-        assert rel < 1e-4
-        A, rhs, x_true = make_spd(6, 10, 30.0, seed=9)   # 10 % 8 != 0
-        x = np.asarray(_blocked_cholesky_solve(A, rhs))
-        rel = np.linalg.norm(x - x_true) / np.linalg.norm(x_true)
-        assert rel < 1e-4
-
-    def test_nondivisible_rank_pads(self):
-        from predictionio_tpu.ops.solve import cholesky_solve_pallas
-        A, rhs, x_true = make_spd(5, 27, 100.0, seed=4)  # 27 % 8 != 0
-        x = np.asarray(cholesky_solve_pallas(A, rhs, interpret=True))
-        rel = np.linalg.norm(x - x_true) / np.linalg.norm(x_true)
-        assert rel < 1e-4
-
-    def test_pallas_interpret_matches_truth(self):
-        from predictionio_tpu.ops.solve import cholesky_solve_pallas
-        A, rhs, x_true = make_spd(12, 48, 500.0, seed=5)  # pads B 12->16
-        x = np.asarray(cholesky_solve_pallas(A, rhs, tile=8,
-                                             interpret=True))
-        rel = np.linalg.norm(x - x_true) / np.linalg.norm(x_true)
-        assert rel < 1e-4
-
-    def test_spd_solve_dispatch(self):
-        A, rhs, x_true = make_spd(4, 32, 50.0, seed=6)
-        x = np.asarray(spd_solve(A, rhs, method="chol_blocked")[0])
-        rel = np.linalg.norm(x - x_true) / np.linalg.norm(x_true)
-        assert rel < 1e-4
-
-    def test_als_with_blocked_cholesky_matches_lapack_path(self, mesh8):
-        from predictionio_tpu.ops.als import ALSConfig, als_train
-        from predictionio_tpu.ops.ratings import RatingsCOO
-        rng = np.random.default_rng(9)
-        n_u, n_i, nnz = 300, 90, 4000
-        r = RatingsCOO(rng.integers(0, n_u, nnz).astype(np.int32),
-                       rng.integers(0, n_i, nnz).astype(np.int32),
-                       rng.uniform(1, 5, nnz).astype(np.float32),
-                       n_u, n_i)
-        kw = dict(rank=8, iterations=3, lam=0.05, seed=1,
-                  dual_solve="never")
-        ref = als_train(r, ALSConfig(solver="cholesky", **kw), mesh8)
-        got = als_train(r, ALSConfig(solver="chol_blocked", **kw), mesh8)
-        np.testing.assert_allclose(got.user_factors, ref.user_factors,
-                                   rtol=2e-3, atol=2e-4)
-
-
 class TestDualSolve:
     def test_dual_matches_primal(self, mesh8):
         """Woodbury/dual K<rank route produces the same factors as the
@@ -453,51 +348,47 @@ class TestBF16FactorStorage:
         assert abs(rmse32 - rmse16) < 0.02, (rmse32, rmse16)
 
 
-class TestALSWithSchulz:
-    def test_als_factors_match_across_solvers(self, mesh8):
-        """als_train(solver='schulz') ~ als_train(solver='cholesky'):
-        same fixed point, per-iteration solves within iterative tolerance."""
-        from predictionio_tpu.ops.als import ALSConfig, als_rmse, als_train
-        from predictionio_tpu.ops.ratings import RatingsCOO
-
-        rng = np.random.default_rng(3)
-        n_u, n_i, nnz = 60, 40, 600
-        ui = rng.integers(0, n_u, nnz).astype(np.int32)
-        ii = rng.integers(0, n_i, nnz).astype(np.int32)
-        vv = (1 + 4 * rng.random(nnz)).astype(np.float32)
-        r = RatingsCOO(ui, ii, vv, n_u, n_i)
-        kw = dict(rank=8, iterations=6, lam=0.1, seed=2, work_budget=512)
-        m_chol = als_train(r, ALSConfig(solver="cholesky", **kw), mesh8)
-        m_schulz = als_train(r, ALSConfig(solver="schulz", **kw), mesh8)
-        rmse_c = als_rmse(m_chol, r)
-        rmse_s = als_rmse(m_schulz, r)
-        assert abs(rmse_c - rmse_s) < 5e-3
-        np.testing.assert_allclose(m_schulz.user_factors,
-                                   m_chol.user_factors, rtol=0.05, atol=0.05)
+#: Names that selected code this repository no longer has (ISSUE 29).
+DELETED_SOLVERS = ["schulz", "schulz_pallas", "chol_pallas", "chol_blocked",
+                   "diag_gather", "diag_nosolve"]
+DELETED_FIELD, DELETED_JIT = "fuse_iteration", "_solve_iteration"
 
 
-@pytest.mark.skipif(
-    True, reason="pallas TPU kernel needs a real TPU; exercised by bench.py "
-                 "and interpret-mode smoke below when supported")
-class TestSchulzPallasTPU:
-    pass
+@pytest.mark.parametrize("name", DELETED_SOLVERS)
+def test_deleted_solver_names_are_refused(name, monkeypatch):
+    """`resolve_solver` holds the solver names: another one is a
+    ValueError from it, from `spd_solve` and from `als_train`, which
+    refuses before it plans anything, let alone traces."""
+    from predictionio_tpu.ops import als
+    from predictionio_tpu.ops.ratings import RatingsCOO
+
+    with pytest.raises(ValueError, match=name):
+        resolve_solver(name)
+    A, rhs, _ = make_spd(4, 8, 10.0)
+    with pytest.raises(ValueError, match=name):
+        spd_solve(A, rhs, method=name)
+
+    def no_plan(*a, **kw):
+        raise AssertionError("als_train planned before it refused")
+    monkeypatch.setattr(als, "plan_for_users", no_plan)
+    r = RatingsCOO(np.zeros(4, np.int32), np.arange(4, dtype=np.int32),
+                   np.ones(4, np.float32), 1, 4)
+    with pytest.raises(ValueError, match=name):
+        als.als_train(r, als.ALSConfig(rank=4, iterations=1, solver=name))
 
 
-def test_schulz_pallas_interpret_smoke():
-    """Pallas kernel math check via the interpreter (no TPU needed)."""
-    import jax
-    from jax.experimental import pallas as pl  # noqa: F401
-    from predictionio_tpu.ops import solve as S
+def test_no_fused_iteration_option_is_left():
+    """One way to run an iteration: neither ALSConfig nor an engine's
+    algorithm parameters can ask for another."""
+    import dataclasses
+    from predictionio_tpu.models import (ecommerce, recommendation,
+                                         recommendeduser, similarproduct)
+    from predictionio_tpu.ops import als
 
-    A, rhs, x_true = make_spd(4, 16, 50.0)
-    import functools
-    import jax.numpy as jnp
-    kernel = functools.partial(S._schulz_kernel, iters=18,
-                               compute_dtype="float32")
-    x = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((4, 16), jnp.float32),
-        interpret=True,
-    )(jnp.asarray(A), jnp.asarray(rhs))
-    rel = np.linalg.norm(np.asarray(x) - x_true) / np.linalg.norm(x_true)
-    assert rel < 1e-3
+    params = [als.ALSConfig, recommendation.ALSAlgorithmParams,
+              similarproduct.ALSAlgorithmParams,
+              ecommerce.ECommAlgorithmParams,
+              recommendeduser.ALSAlgorithmParams]
+    for cls in params:
+        assert DELETED_FIELD not in {f.name for f in dataclasses.fields(cls)}
+    assert not hasattr(als, DELETED_JIT)
