@@ -317,7 +317,7 @@ def solve_rows(counter_factors: np.ndarray,
     if not plan.batches:
         return np.zeros((n_rows, rank), dtype=np.float32)
     chunk = resolve_sweep_chunk(cfg.sweep_chunk, mesh.n_devices)
-    groups = _upload_plan(mesh, plan, chunk)
+    groups = _upload_plan(mesh, plan, chunk, rank)
     # +1 dummy tail row: the scatter target for batch padding (rows = -1)
     out_dev = mesh.put_replicated(
         np.zeros((n_rows + 1, rank), dtype=np.float32))
@@ -427,7 +427,7 @@ def _pad_plan_batches(plan, batch_multiple: int = 1):
 
 def _prep_side(owner_idx: np.ndarray, counter_idx: np.ndarray,
                values: np.ndarray, touched: np.ndarray,
-               cfg: FoldInConfig, mesh: MeshContext
+               cfg: FoldInConfig, mesh: MeshContext, rank: int
                ) -> Optional[_SidePrep]:
     from predictionio_tpu.compile.buckets import bucket_rows
     if touched.size == 0:
@@ -452,7 +452,7 @@ def _prep_side(owner_idx: np.ndarray, counter_idx: np.ndarray,
         return None
     plan = _pad_plan_batches(plan, batch_multiple=mesh.data_parallelism)
     chunk = resolve_sweep_chunk(cfg.sweep_chunk, mesh.n_devices)
-    groups = _upload_plan(mesh, plan, chunk)
+    groups = _upload_plan(mesh, plan, chunk, rank)
     # only scatter rows that actually had data: a touched entity whose
     # entries all vanished (e.g. deleted events) keeps its deployed row
     # rather than being zeroed
@@ -581,9 +581,9 @@ def fold_in_coo(als: ALSModel, coo: RatingsCOO,
     prep_u = prep_i = None
     if not degenerate:
         prep_u = _prep_side(coo.user_idx, coo.item_idx, coo.rating, tu,
-                            cfg, mesh)
+                            cfg, mesh, rank)
         prep_i = _prep_side(coo.item_idx, coo.user_idx, coo.rating, ti,
-                            cfg, mesh)
+                            cfg, mesh, rank)
         degenerate = prep_u is None and prep_i is None
     if degenerate:
         # no-op tick (ISSUE 5 satellite): nothing solvable — return the

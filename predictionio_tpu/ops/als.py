@@ -57,10 +57,14 @@ class ALSConfig:
     work_budget: int = 1 << 20         # B*K per solve batch
     compute_dtype: str = "float32"     # einsum dtype ('bfloat16' on TPU ok)
     factor_dtype: str = "float32"      # HBM storage dtype of factor tables
-    # 'bfloat16' halves the per-iteration gather traffic (the dominant HBM
-    # cost once solves are fast); solves still build/solve f32 normal
-    # equations from the gathered rows, so per-iteration quality loss is
-    # bounded by bf16 rounding of the carried factors.
+    # 'bfloat16' halves the tables in HBM and changes their stated
+    # precision: the carried factors are rounded to bf16 every half-sweep
+    # (the solves still build and solve f32 normal equations from the
+    # gathered rows). It does NOT speed a half-sweep's gather: at
+    # compute_dtype bfloat16 the compiled program gathers from a bf16 copy
+    # of the f32 table already (made once a program), and on a v5e the
+    # gather costs the same per row whatever a row's bytes or tiling
+    # (PERF.md section 6, PR 30: `_gather_pad_rows` is what moves it).
     solver: str = "auto"  # the names ops/solve.resolve_solver takes
     # auto = VMEM-resident CG Pallas kernel on one TPU, jnp CG on a TPU
     # mesh, LAPACK cholesky on CPU.
@@ -441,7 +445,50 @@ def resolve_sweep_chunk(chunk: int, n_devices: int = 1) -> int:
     return 4 if (jax.default_backend() == "tpu" and n_devices == 1) else 1
 
 
-def _upload_plan(mesh: MeshContext, plan: SolvePlan, chunk: int = 1):
+#: The TPU compiler tiles a gather's index vector by 1,024 and picks the
+#: gather's step from what the last tile holds: 256 rows a step where the
+#: row count leaves it between these bounds, 128 where it leaves it nearly
+#: full, empty or nearly empty (counts of 10^4 to 8*10^6 into tables of
+#: 0.9M to 4.2M rows compiled for a described v5e: 256 at 32-64 up to
+#: 768-896, the edges moving with the count; PERF.md section 6, PR 30).
+#: The steps are latency-bound, 1.4-1.5 us each whatever they hold: 11.6 ns
+#: a row at 128, 6.1 at 256, whatever a row's bytes or tiling. Seen at rank
+#: 64, 200 and 256; a table of 10 or 32 columns gathers 128 rows a step
+#: whatever the count, and at rank 10 (the table lies row-index-minor) a
+#: batch of an odd size costs the compiler minutes: 177 s for one K 8 rung
+#: of 262,160 systems against 2.4 s for 262,144.
+_GATHER_TILE, _GATHER_STEP_256, _GATHER_MIN_RANK = 1024, (128, 704), 64
+
+
+def _gather_pad_rows(b: int, k: int) -> int:
+    """How many padding systems (row -1, mask 0: what a plan pads its own
+    batches with) to append to a [b, k] batch so that its gather of
+    (b + extra) * k rows gets the compiler's 256-row step
+    (`_GATHER_STEP_256`): the fewest, at most b // 32 (3% more systems);
+    0 where the count lies there already or none that few does it (a few
+    of the longest rungs, b < 32 or k a multiple of 1,024)."""
+    lo, hi = _GATHER_STEP_256
+    for extra in range(b // 32 + 1):
+        if lo <= (b + extra) * k % _GATHER_TILE <= hi:
+            return extra
+    return 0
+
+
+def _gather_layout(mesh: MeshContext, rank: Optional[int] = None) -> str:
+    """How the uploaded batches stand for the half-sweeps' gathers, by what
+    the program can see: "rows+pad256" on a single TPU device, where
+    `_upload_plan` pads each batch group by `_gather_pad_rows` systems;
+    "rows" anywhere else: another backend's gather has no such step, a
+    mesh shards the batch dimension, which has to stay divisible, and
+    tables of a known `rank` under `_GATHER_MIN_RANK` gain nothing."""
+    one_tpu = (mesh.n_devices == 1
+               and mesh.mesh.devices.flat[0].platform == "tpu")
+    wide = rank is None or rank >= _GATHER_MIN_RANK
+    return "rows+pad256" if one_tpu and wide else "rows"
+
+
+def _upload_plan(mesh: MeshContext, plan: SolvePlan, chunk: int = 1,
+                 rank: Optional[int] = None):
     """Stack same-shape batches into [N, B(, K)] groups and upload each
     group once, sharded on the batch dim (dim 1) over the mesh data axis.
     The index/rating/mask tensors are constant across iterations, so they
@@ -454,12 +501,18 @@ def _upload_plan(mesh: MeshContext, plan: SolvePlan, chunk: int = 1):
     [N/chunk, chunk*B]): batches within a half-sweep are independent, so
     this only amortizes the solver's per-call fixed cost over more
     systems (ALSConfig.sweep_chunk); a remainder that doesn't fill a
-    chunk becomes its own group."""
+    chunk becomes its own group. On a single TPU device each group is
+    then padded by a few systems that solve nothing, so that its gather
+    runs at the compiler's faster step (`_gather_pad_rows`;
+    `_gather_layout` says when, from the mesh and the tables' `rank`
+    where the caller gives it)."""
     with TRACER.region("train.upload"):
-        return _upload_plan_now(mesh, plan, chunk)
+        return _upload_plan_now(mesh, plan, chunk, rank)
 
 
-def _upload_plan_now(mesh: MeshContext, plan: SolvePlan, chunk: int):
+def _upload_plan_now(mesh: MeshContext, plan: SolvePlan, chunk: int,
+                     rank: Optional[int]):
+    pad = _gather_layout(mesh, rank) != "rows"
     by_shape = {}
     for b in plan.batches:
         by_shape.setdefault(b.shape, []).append(b)
@@ -484,7 +537,11 @@ def _upload_plan_now(mesh: MeshContext, plan: SolvePlan, chunk: int):
                 chunks.append(tuple(x[n_full:]
                                     for x in (rows, idx, val, mask)))
         for tensors in chunks:
-            groups.append(tuple(mesh.put_stacked(x) for x in tensors))
+            b, k = tensors[1].shape[1:]
+            padded_b = b + (_gather_pad_rows(b, k) if pad else 0)
+            groups.append(tuple(
+                mesh.put_stacked(mesh.pad_to_multiple(x, 1, padded_b, fill)[0])
+                for x, fill in zip(tensors, (-1, 0, 0, 0))))
     # host->device transfer accounting (obs.jaxmon): the plan upload is
     # the largest per-train / per-fold-in host->device transfer
     from predictionio_tpu.obs import jaxmon
@@ -589,7 +646,9 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
     returned model.
 
     `telemetry`, when a dict, receives what `auto` resolved to (solver,
-    compute_dtype, sweep_chunk, n_devices) and per-phase wall times
+    compute_dtype, sweep_chunk, n_devices, and gather_layout: whether the
+    uploaded batches are padded for the gather, `_gather_layout`) and
+    per-phase wall times
     (plan_s, upload_s, iters_s, s_per_iter, fetch_s; with two or more
     iterations also iters_s = compile_s + sweeps_s, where compile_s is
     what the first iteration took beyond a steady one: trace, lowering
@@ -645,9 +704,10 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
     if telemetry is not None:
         telemetry.update(solver=cfg.solver,
                          compute_dtype=cfg.compute_dtype,
-                         sweep_chunk=chunk, n_devices=mesh.n_devices)
-    user_batches = _upload_plan(mesh, user_plan, chunk)
-    item_batches = _upload_plan(mesh, item_plan, chunk)
+                         sweep_chunk=chunk, n_devices=mesh.n_devices,
+                         gather_layout=_gather_layout(mesh, cfg.rank))
+    user_batches = _upload_plan(mesh, user_plan, chunk, cfg.rank)
+    item_batches = _upload_plan(mesh, item_plan, chunk, cfg.rank)
     # hyperparameters ride along as device-resident scalars: no per-call
     # host uploads, and sweeping lam/alpha (evaluation tuning) does not
     # recompile the sweep program

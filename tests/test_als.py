@@ -409,8 +409,10 @@ def test_train_telemetry_phases():
     phases = {"plan_s", "upload_s", "iters_s", "compile_s", "sweeps_s",
               "s_per_iter", "fetch_s"}
     assert set(tel) == phases | {"solver", "compute_dtype", "sweep_chunk",
-                                 "n_devices", "cg_iters_run",
-                                 "cg_iters_budget"}
+                                 "n_devices", "gather_layout",
+                                 "cg_iters_run", "cg_iters_budget"}
+    # the CPU's gather has no step to pad a batch for
+    assert tel["gather_layout"] == "rows"
     # no solve went through the Pallas CG, the one solver that counts
     assert (tel["cg_iters_run"], tel["cg_iters_budget"]) == (0.0, 0.0)
     assert all(tel[k] >= 0 for k in phases)
@@ -480,3 +482,138 @@ def test_dual_solve_large_k_buckets(implicit, alpha):
     scale = np.abs(m_exact.user_factors).max()
     assert np.abs(m_exact.user_factors
                   - m_dual.user_factors).max() < 2e-3 * scale
+
+
+# ---------------------------------------------------------------------------
+# PR 30: batches padded so that the TPU's gather takes its 256-row step
+# ---------------------------------------------------------------------------
+
+# (B, K) of rungs of the benchmark's two train plans as uploaded (goodreads
+# at sweep_chunk 2, taobao at 1) with the padding each gets, then what the
+# rule has to leave alone
+_RUNGS = [(262144, 8, 16), (131072, 16, 8), (87380, 24, 7), (65536, 32, 4),
+          (52428, 40, 4), (17476, 120, 2), (11914, 176, 3), (10082, 208, 2),
+          (5041, 208, 1), (1310, 1600, 1), (138, 3584, 1), (43690, 24, 6),
+          (5957, 176, 2), (95221, 8, 27), (2520, 416, 1), (32768, 64, 2),
+          (655, 1600, 0), (268, 2752, 0),  # the count lies in the window
+          (8, 10752, 0), (1, 118016, 0),   # under 32 systems: never padded
+          (4096, 1024, 0)]                 # K a multiple of the tile: no count
+
+
+@pytest.mark.parametrize("b,k,want", _RUNGS)
+def test_gather_pad_rows_puts_the_count_in_the_256_step_window(b, k, want):
+    lo, hi = als_mod._GATHER_STEP_256
+
+    def in_window(extra):
+        return lo <= (b + extra) * k % als_mod._GATHER_TILE <= hi
+
+    extra = als_mod._gather_pad_rows(b, k)
+    assert extra == want <= b // 32
+    if extra:        # the fewest: no smaller padding reaches the window
+        assert in_window(extra) and not any(map(in_window, range(extra)))
+    else:            # there already, or out of reach
+        assert in_window(0) or not any(map(in_window, range(b // 32 + 1)))
+
+
+def _padded_upload(monkeypatch, mesh, plan, chunk):
+    """`_upload_plan` as a single TPU device gets it."""
+    with monkeypatch.context() as m:
+        m.setattr(als_mod, "_gather_layout",
+                  lambda mesh, rank=None: "rows+pad256")
+        return als_mod._upload_plan(mesh, plan, chunk)
+
+
+@pytest.mark.parametrize("chunk", [1, 3])
+def test_upload_pads_groups_with_systems_that_solve_nothing(monkeypatch,
+                                                            chunk):
+    import jax
+    from predictionio_tpu.parallel.mesh import make_mesh
+    rng = np.random.default_rng(5)
+    n_u, n_i, nnz = 4000, 300, 40000
+    r = RatingsCOO(rng.integers(0, n_u, nnz), rng.integers(0, n_i, nnz),
+                   rng.uniform(1, 5, nnz).astype(np.float32), n_u, n_i)
+    plan = plan_for_users(r, work_budget=4096)
+    mesh = make_mesh(devices=jax.devices()[:1])
+    plain = als_mod._upload_plan(mesh, plan, chunk)
+    padded = _padded_upload(monkeypatch, mesh, plan, chunk)
+    assert len(plain) == len(padded)
+    grown = 0
+    for (rows, idx, val, mask), (prows, pidx, pval, pmask) in zip(plain,
+                                                                  padded):
+        n, b, k = idx.shape
+        extra = als_mod._gather_pad_rows(b, k)
+        grown += extra
+        assert pidx.shape == (n, b + extra, k) == pval.shape == pmask.shape
+        assert prows.shape == (n, b + extra)
+        for x, px in ((rows, prows), (idx, pidx), (val, pval),
+                      (mask, pmask)):
+            assert (np.asarray(px)[:, :b] == np.asarray(x)).all()
+        assert (np.asarray(prows)[:, b:] == -1).all()
+        assert not np.asarray(pmask)[:, b:].any()
+        assert not np.asarray(pidx)[:, b:].any()
+    assert grown > 0
+
+
+@pytest.mark.parametrize("solver", ["cholesky", "cg"])
+@pytest.mark.parametrize("implicit", [False, True])
+def test_padded_half_sweep_leaves_the_rows_of_the_unpadded(monkeypatch,
+                                                           implicit, solver):
+    """The padding systems scatter onto the dummy row and the batched
+    arithmetic of the others does not see them: the same rows, bit for
+    bit."""
+    import jax
+    import jax.numpy as jnp
+    from predictionio_tpu.parallel.mesh import make_mesh
+    rng = np.random.default_rng(6)
+    n_u, n_i, nnz, rank = 3000, 200, 30000, 12
+    r = RatingsCOO(rng.integers(0, n_u, nnz), rng.integers(0, n_i, nnz),
+                   rng.integers(1, 6, nnz).astype(np.float32), n_u, n_i)
+    plan = plan_for_users(r, work_budget=2048)
+    mesh = make_mesh(devices=jax.devices()[:1])
+    cfg = ALSConfig(rank=rank, implicit_prefs=implicit, solver=solver,
+                    lam=0.05)
+    items = jnp.asarray(als_mod._init_factors(n_i, rank, 1, 2))
+    gram = als_mod._side_gram(cfg, items, n_i, "item")
+    uploads = {"plain": als_mod._upload_plan(mesh, plan, 2),
+               "padded": _padded_upload(monkeypatch, mesh, plan, 2)}
+    systems = {name: sum(idx.shape[1] for _, idx, _, _ in groups)
+               for name, groups in uploads.items()}
+    assert systems["padded"] > systems["plain"]
+    out = {}
+    for name, groups in uploads.items():
+        users = jnp.asarray(als_mod._init_factors(n_u, rank, 1, 1))
+        out[name] = np.asarray(als_mod._run_side(groups, users, items, cfg,
+                                                 gram))
+    # all but the scatter's dummy row, which the padding systems write
+    assert (out["padded"][:n_u] == out["plain"][:n_u]).all()
+
+
+def test_cpu_and_mesh_uploads_are_not_padded(mesh8):
+    """The rule reads what the program can see: one TPU device pads its
+    batches for the gather's step; the CPU has no such step, and a mesh
+    shards the batch dimension, which has to stay divisible."""
+    import types
+
+    import jax
+    from predictionio_tpu.parallel.mesh import make_mesh
+    r = synthetic_ratings(seed=5)
+    tel = {}
+    als_train(r, ALSConfig(rank=4, iterations=1, factor_sharding="model"),
+              mesh8, telemetry=tel)
+    assert tel["gather_layout"] == "rows"
+    assert als_mod._gather_layout(mesh8) == "rows"
+    assert als_mod._gather_layout(
+        make_mesh(devices=jax.devices()[:1])) == "rows"
+
+    def mesh_of(platform, n):
+        devices = np.array([types.SimpleNamespace(platform=platform)
+                            for _ in range(n)], dtype=object)
+        return types.SimpleNamespace(n_devices=n, mesh=types.SimpleNamespace(
+            devices=devices.reshape(n, 1)))
+
+    assert als_mod._gather_layout(mesh_of("tpu", 1)) == "rows+pad256"
+    assert als_mod._gather_layout(mesh_of("tpu", 1), 200) == "rows+pad256"
+    # the templates' rank 10: such a table's gathers take one step always
+    assert als_mod._gather_layout(mesh_of("tpu", 1), 10) == "rows"
+    assert als_mod._gather_layout(mesh_of("tpu", 4)) == "rows"
+    assert als_mod._gather_layout(mesh_of("gpu", 1)) == "rows"
