@@ -58,6 +58,10 @@ struct Handle {
   std::mutex mu;
   std::unordered_map<std::string, IndexEntry> index;
   std::vector<std::string> order;  // insertion order of live keys
+  // entity hash -> keys ever written under it, in insertion order (the
+  // keys live in `index`, whose nodes never move): a scan that names an
+  // entity walks its bucket, not the whole log
+  std::unordered_map<uint64_t, std::vector<const std::string*>> by_entity;
   // scan state
   std::vector<const std::string*> scan_keys;
   std::vector<uint8_t> fetch_buf;
@@ -82,6 +86,61 @@ uint64_t fnv1a(const uint8_t* data, size_t len) {
     h *= 1099511628211ULL;
   }
   return h;
+}
+
+// Insert or overwrite one index entry. An overwrite keeps the key's place
+// in `order`; where it moves the key to another entity, the key joins that
+// entity's bucket too (the stale bucket's copy fails the scan's hash test).
+void upsert(Handle* h, std::string&& k, const IndexEntry& e) {
+  auto ins = h->index.emplace(std::move(k), e);
+  uint64_t old_hash = 0;
+  if (ins.second) {
+    h->order.push_back(ins.first->first);
+  } else {
+    old_hash = ins.first->second.entity_hash;
+    ins.first->second = e;
+  }
+  if (e.entity_hash && (ins.second || old_hash != e.entity_hash)) {
+    auto& bucket = h->by_entity[e.entity_hash];
+    const std::string* kp = &ins.first->first;
+    if (ins.second ||
+        std::find(bucket.begin(), bucket.end(), kp) == bucket.end())
+      bucket.push_back(kp);
+  }
+}
+
+// The pushed-down predicate walk shared by el_scan and el_scan_ts: `fn`
+// sees each live record that passes, in insertion order. 0-valued hash
+// filters mean "no filter"; a scan with an entity hash reads that entity's
+// bucket alone.
+template <typename Fn>
+void scan_matches(Handle* h, int64_t start_ts, int64_t until_ts,
+                  uint64_t entity_hash, const uint64_t* name_hashes,
+                  int32_t n_names, uint64_t target_hash, Fn fn) {
+  auto visit = [&](const std::string& k) {
+    auto it = h->index.find(k);
+    if (it == h->index.end() || it->second.deleted) return;
+    const IndexEntry& e = it->second;
+    if (start_ts != INT64_MIN && e.ts < start_ts) return;
+    if (until_ts != INT64_MIN && e.ts >= until_ts) return;
+    if (entity_hash != 0 && e.entity_hash != entity_hash) return;
+    if (target_hash != 0 && e.target_hash != target_hash) return;
+    if (n_names > 0) {
+      bool ok = false;
+      for (int32_t i = 0; i < n_names; i++) {
+        if (e.name_hash == name_hashes[i]) { ok = true; break; }
+      }
+      if (!ok) return;
+    }
+    fn(it->first, e);
+  };
+  if (entity_hash != 0) {
+    auto bucket = h->by_entity.find(entity_hash);
+    if (bucket == h->by_entity.end()) return;
+    for (const std::string* kp : bucket->second) visit(*kp);
+  } else {
+    for (const std::string& k : h->order) visit(k);
+  }
 }
 
 bool read_exact(FILE* f, void* buf, size_t n) {
@@ -126,6 +185,15 @@ class SeqReader {
   uint64_t base_ = 0;
   std::vector<uint8_t> buf_;
 };
+
+// SeqReader window for a scan that wants `total` payload bytes: a point
+// read (one entity's few records) must not pull 8 MB through the page
+// cache for each of them, so the window follows the bytes wanted, between
+// 16 KB and the bulk scan's 8 MB.
+size_t read_window(uint64_t total) {
+  return (size_t)std::min<uint64_t>(
+      8u << 20, std::max<uint64_t>(16u << 10, 4 * total));
+}
 
 }  // namespace
 
@@ -186,10 +254,9 @@ void* el_open(const char* path) {
       auto it = h->index.find(k);
       if (it != h->index.end()) it->second.deleted = true;
     } else {
-      bool existed = h->index.count(k) != 0;
-      h->index[k] = IndexEntry{off, rh.datalen, rh.ts, rh.entity_hash,
-                               rh.name_hash, rh.target_hash, false};
-      if (!existed) h->order.push_back(k);
+      upsert(h, std::move(k),
+             IndexEntry{off, rh.datalen, rh.ts, rh.entity_hash,
+                        rh.name_hash, rh.target_hash, false});
     }
   }
   if (clean_end < fsize) {
@@ -236,11 +303,9 @@ int el_append(void* vh, const uint8_t* key, int32_t keylen,
   if (keylen && fwrite(key, 1, keylen, h->f) != (size_t)keylen) return -1;
   if (datalen && fwrite(data, 1, datalen, h->f) != (size_t)datalen)
     return -1;
-  std::string k((const char*)key, keylen);
-  bool existed = h->index.count(k) != 0;
-  h->index[k] = IndexEntry{off, (uint32_t)datalen, ts, entity_hash,
-                           name_hash, target_hash, false};
-  if (!existed) h->order.push_back(k);
+  upsert(h, std::string((const char*)key, keylen),
+         IndexEntry{off, (uint32_t)datalen, ts, entity_hash, name_hash,
+                    target_hash, false});
   return 0;
 }
 
@@ -294,13 +359,10 @@ int64_t el_append_batch(void* vh, int32_t n, const uint8_t* keys,
   for (int32_t i = 0; i < n; i++) {
     std::string k((const char*)(keys + koff), (size_t)keylens[i]);
     koff += (uint64_t)keylens[i];
-    IndexEntry e{rec_off[i], (uint32_t)datalens[i], ts[i],
-                 entity_hashes[i], name_hashes[i], target_hashes[i], false};
-    auto ins = h->index.emplace(std::move(k), e);
-    if (ins.second)
-      h->order.push_back(ins.first->first);
-    else
-      ins.first->second = e;
+    upsert(h, std::move(k),
+           IndexEntry{rec_off[i], (uint32_t)datalens[i], ts[i],
+                      entity_hashes[i], name_hashes[i], target_hashes[i],
+                      false});
   }
   return n;
 }
@@ -385,23 +447,10 @@ int64_t el_scan(void* vh, int64_t start_ts, int64_t until_ts,
   Handle* h = (Handle*)vh;
   std::lock_guard<std::mutex> lock(h->mu);
   h->scan_keys.clear();
-  for (const std::string& k : h->order) {
-    auto it = h->index.find(k);
-    if (it == h->index.end() || it->second.deleted) continue;
-    const IndexEntry& e = it->second;
-    if (start_ts != INT64_MIN && e.ts < start_ts) continue;
-    if (until_ts != INT64_MIN && e.ts >= until_ts) continue;
-    if (entity_hash != 0 && e.entity_hash != entity_hash) continue;
-    if (target_hash != 0 && e.target_hash != target_hash) continue;
-    if (n_names > 0) {
-      bool ok = false;
-      for (int32_t i = 0; i < n_names; i++) {
-        if (e.name_hash == name_hashes[i]) { ok = true; break; }
-      }
-      if (!ok) continue;
-    }
-    h->scan_keys.push_back(&it->first);
-  }
+  scan_matches(h, start_ts, until_ts, entity_hash, name_hashes, n_names,
+               target_hash, [h](const std::string& k, const IndexEntry&) {
+                 h->scan_keys.push_back(&k);
+               });
   return (int64_t)h->scan_keys.size();
 }
 
@@ -417,23 +466,10 @@ int64_t el_scan_ts(void* vh, int64_t start_ts, int64_t until_ts,
   Handle* h = (Handle*)vh;
   std::lock_guard<std::mutex> lock(h->mu);
   h->plan_ts.clear();
-  for (const std::string& k : h->order) {
-    auto it = h->index.find(k);
-    if (it == h->index.end() || it->second.deleted) continue;
-    const IndexEntry& e = it->second;
-    if (start_ts != INT64_MIN && e.ts < start_ts) continue;
-    if (until_ts != INT64_MIN && e.ts >= until_ts) continue;
-    if (entity_hash != 0 && e.entity_hash != entity_hash) continue;
-    if (target_hash != 0 && e.target_hash != target_hash) continue;
-    if (n_names > 0) {
-      bool ok = false;
-      for (int32_t i = 0; i < n_names; i++) {
-        if (e.name_hash == name_hashes[i]) { ok = true; break; }
-      }
-      if (!ok) continue;
-    }
-    h->plan_ts.push_back(e.ts);
-  }
+  scan_matches(h, start_ts, until_ts, entity_hash, name_hashes, n_names,
+               target_hash, [h](const std::string&, const IndexEntry& e) {
+                 h->plan_ts.push_back(e.ts);
+               });
   return (int64_t)h->plan_ts.size();
 }
 
@@ -476,7 +512,7 @@ int64_t el_scan_fetch(void* vh) {
   h->bulk_data.reserve(total);
   h->bulk_offsets.push_back(0);
   fflush(h->f);  // SeqReader reads through the same FILE*: no stale tail
-  SeqReader rd(h->f);
+  SeqReader rd(h->f, read_window(total));
   for (const std::string* k : h->scan_keys) {
     auto it = h->index.find(*k);
     if (it == h->index.end() || it->second.deleted) continue;
@@ -602,7 +638,13 @@ int64_t el_scan_columnar(void* vh, const char* prop_name) {
   h->col_fallback.clear();
   std::vector<uint8_t> buf;
   fflush(h->f);  // SeqReader reads through the same FILE*: no stale tail
-  SeqReader rd(h->f);
+  uint64_t total = 0;
+  for (const std::string* k : h->scan_keys) {
+    auto it = h->index.find(*k);
+    if (it != h->index.end() && !it->second.deleted)
+      total += it->second.datalen;
+  }
+  SeqReader rd(h->f, read_window(total));
   for (const std::string* k : h->scan_keys) {
     auto it = h->index.find(*k);
     if (it == h->index.end() || it->second.deleted) continue;

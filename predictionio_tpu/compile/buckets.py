@@ -27,6 +27,13 @@ BATCH_FLOOR = 1
 #: program (and one deploy-time warm spec); the extra top-k positions
 #: are noise next to the scoring matmul
 K_FLOOR = 16
+#: smallest bucket of a dispatch's flat filter list (the batch's excluded
+#: and white-listed (query, item) pairs together): a query carries tens to
+#: a few hundred, so most dispatches of up to 16 fit the floor or the
+#: bucket above it
+LIST_FLOOR = 1024
+#: smallest bucket of a query's category list
+QUERY_CATEGORIES_FLOOR = 4
 #: fraction of a bucket in use at which the next bucket should be
 #: pre-compiled in the background (before growth forces it on a tick)
 PROMOTE_AT = 0.75
@@ -44,6 +51,18 @@ def bucket_rows(n: int, floor: int = ROWS_FLOOR) -> int:
 def bucket_batch(n: int, floor: int = BATCH_FLOOR) -> int:
     """Query-batch bucket covering ``n``."""
     return max(int(floor), _next_pow2(max(int(n), 1)))
+
+
+def bucket_list(n: int) -> int:
+    """Filter-list bucket covering ``n`` entries: LIST_FLOOR times a power
+    of four. List lengths are the clients' to choose, and every (batch,
+    list) pair is an executable to warm: steps of four keep that ladder
+    at three or four rungs where steps of two would double it, and a
+    padded entry costs the device one dropped scatter update."""
+    b = LIST_FLOOR
+    while b < n:
+        b *= 4
+    return b
 
 
 def bucket_rows_sharded(n: int, shards: int,
@@ -78,7 +97,8 @@ def bucket_key(dims: Dict[str, int]) -> Tuple[Tuple[str, int], ...]:
 
     Dims need not all be sizes: flag dims ride the same key — ``s``
     (shard count, sharded vs replicated layout), ``fp`` (positive-
-    score filter), and ``p`` (readback pack mode, ISSUE 19: the packed
+    score filter), ``c`` (category slots per item of the composed-mask
+    family), and ``p`` (readback pack mode, ISSUE 19: the packed
     variant's single-payload output aval is a different program). Each
     flag value owns its own warmed executables, so flipping a flag at
     runtime never invalidates the other value's buckets."""

@@ -133,6 +133,8 @@ class DataMap(Mapping[str, Any]):
 
     def get_string_list(self, name: str) -> list:
         value = self.get(name, list)
+        if set(map(type, value)) <= {str}:     # the usual case, in C
+            return list(value)
         return [_coerce(name, v, str) for v in value]
 
     def get_double_list(self, name: str) -> list:

@@ -345,6 +345,24 @@ class Events(abc.ABC):
         callers, as in the reference).
         """
 
+    def latest_event_id(self, app_id: int, entity_type: str,
+                        entity_id: str,
+                        channel_id: Optional[int] = None,
+                        event_names: Optional[Sequence[str]] = None
+                        ) -> Optional[str]:
+        """The id of the entity's newest event (by eventTime), or None:
+        what a reader that has already parsed one event asks before it
+        parses the next (the e-commerce template's `unavailableItems`
+        `$set`, a list of tens of thousands of ids, read at every
+        dispatch). This default reads the event itself; a backend whose
+        index holds times and ids answers without touching a payload."""
+        for e in self.find(app_id, channel_id=channel_id,
+                           entity_type=entity_type, entity_id=entity_id,
+                           event_names=event_names, limit=1,
+                           reversed_order=True):
+            return e.event_id
+        return None
+
     def find_columnar(self, app_id: int,
                       channel_id: Optional[int] = None,
                       property_field: Optional[str] = None,

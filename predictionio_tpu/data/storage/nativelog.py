@@ -1943,6 +1943,40 @@ class NativeLogEvents(base.Events):
             payloads.extend(chunk)
         return payloads
 
+    def latest_event_id(self, app_id, entity_type, entity_id,
+                        channel_id=None, event_names=None):
+        """From the in-memory index alone (the entity's bucket: times and
+        keys, no payload read or parsed). A 64-bit hash collision could
+        name another entity's event; the caller reads the event by this
+        id and sees whose it is."""
+        best = None
+        for hkey, h, lk in self._read_handles(app_id, channel_id,
+                                              entity_type, entity_id):
+            with lk:
+                if self._stale(hkey, h):
+                    continue
+                entity_hash, arr, n_names, _ = self._scan_hashes(
+                    entity_type, entity_id, event_names, None, None)
+                n = self.lib.el_scan_ts(h, _INT64_MIN, _INT64_MIN,
+                                        entity_hash, arr, n_names, 0)
+                if n <= 0:
+                    continue
+                ts = self.lib.el_plan_ts(h)
+                # the newest; of several in one millisecond, the last
+                # written (both scans walk in insertion order)
+                at = max(range(n), key=lambda i: (ts[i], i))
+                t_best = ts[at]
+                self.lib.el_scan(h, _INT64_MIN, _INT64_MIN, entity_hash,
+                                 arr, n_names, 0)
+                out = ctypes.POINTER(ctypes.c_uint8)()
+                klen = self.lib.el_scan_key(h, at, ctypes.byref(out))
+                if klen < 0:
+                    continue
+                key = ctypes.string_at(out, klen).decode("utf-8")
+            if best is None or t_best >= best[0]:
+                best = (t_best, key)
+        return best[1] if best else None
+
     def find(self, app_id, channel_id=None, start_time=None, until_time=None,
              entity_type=None, entity_id=None, event_names=None,
              target_entity_type=None, target_entity_id=None, limit=None,

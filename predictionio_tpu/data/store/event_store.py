@@ -16,7 +16,7 @@ from __future__ import annotations
 import datetime as _dt
 import threading
 import time
-from typing import Dict, Iterator, Optional, Sequence
+from typing import Dict, Iterator, Optional, Sequence, Tuple
 
 from predictionio_tpu.data.datamap import PropertyMap
 from predictionio_tpu.data.event import Event
@@ -81,14 +81,22 @@ class EventStore:
     def find_columnar(self, app_name: str,
                       channel_name: Optional[str] = None,
                       property_field: Optional[str] = None,
+                      timeout_ms: Optional[int] = None,
                       **filters) -> Dict[str, "object"]:
-        """Columnar bulk read (see Events.find_columnar): flat numpy arrays
+        """Columnar read (see Events.find_columnar): flat numpy arrays
         for vectorized training ingest — the PEvents-scan-to-RDD role
-        (PEvents.scala:77) without per-event Python objects."""
-        app_id, channel_id = self.resolve(app_name, channel_name)
-        return self.events.find_columnar(
-            app_id=app_id, channel_id=channel_id,
-            property_field=property_field, **filters)
+        (PEvents.scala:77) without per-event Python objects. With
+        `timeout_ms` it is a serve-time read under the point reads'
+        deadline (:meth:`find_by_entity`): what a caller that wants one
+        column of an entity's events asks for (the e-commerce engine's
+        seen items: the target ids of a user's views and buys, a thousand
+        events for a heavy user, none of which it needs as an Event)."""
+        def _query():
+            app_id, channel_id = self.resolve(app_name, channel_name)
+            return self.events.find_columnar(
+                app_id=app_id, channel_id=channel_id,
+                property_field=property_field, **filters)
+        return self._within_deadline(_query, timeout_ms)
 
     def find_columnar_chunked(self, app_name: str,
                               channel_name: Optional[str] = None,
@@ -176,7 +184,37 @@ class EventStore:
                 target_entity_type=target_entity_type,
                 target_entity_id=target_entity_id, start_time=start_time,
                 until_time=until_time, limit=limit, reversed_order=latest))
+        return self._within_deadline(_query, timeout_ms)
 
+    def latest_event(self, app_name: str, entity_type: str, entity_id: str,
+                     channel_name: Optional[str] = None,
+                     event_names: Optional[Sequence[str]] = None,
+                     known_id: Optional[str] = None,
+                     timeout_ms: Optional[int] = None
+                     ) -> Tuple[Optional[str], Optional[Event]]:
+        """``(event id, event)`` of the entity's newest event; ``(None,
+        None)`` when it has none. When the newest is still the event
+        `known_id` the answer is ``(known_id, None)`` and nothing is read
+        or parsed (the e-commerce template's `unavailableItems` list is
+        asked for at every dispatch and changes a few times an hour). Same
+        deadline as :meth:`find_by_entity`."""
+        def _query():
+            app_id, channel_id = self.resolve(app_name, channel_name)
+            eid = self.events.latest_event_id(
+                app_id, entity_type, entity_id, channel_id=channel_id,
+                event_names=event_names)
+            if eid is None or eid == known_id:
+                return eid, None
+            e = self.events.get(eid, app_id, channel_id)
+            if e is None or (e.entity_type, e.entity_id) != (entity_type,
+                                                             entity_id):
+                return None, None     # deleted since, or a hash collision
+            return eid, e
+        return self._within_deadline(_query, timeout_ms)
+
+    def _within_deadline(self, _query, timeout_ms: Optional[int]):
+        """Run `_query` in a worker thread under the point-read deadline
+        (see :meth:`find_by_entity`); inline when there is none."""
         if timeout_ms is None:
             return _query()
         # the permit wait SHARES the deadline: a healthy burst past the

@@ -348,15 +348,19 @@ class RecommendationModel:
     item_properties: Optional[List[Optional[dict]]] = None
     # derived at train time so per-query masks are vectorized, not
     # O(n_items) Python loops on the serve path
-    item_categories: Optional[List[Optional[set]]] = None
+    # ops/similarity.ItemCategories (a list of optional sets in models
+    # pickled before ISSUE 31: build_filter_mask converts those)
+    item_categories: Optional[object] = None
     item_years: Optional[np.ndarray] = None  # float32, NaN = undated
 
     @staticmethod
     def derive_filters(item_properties):
         if item_properties is None:
             return None, None
-        cats = [set(p["categories"]) if p and p.get("categories") else None
-                for p in item_properties]
+        from predictionio_tpu.ops.similarity import ItemCategories
+        cats = ItemCategories.from_sets(
+            [set(p["categories"]) if p and p.get("categories") else None
+             for p in item_properties])
         years = np.array(
             [float(p["creationYear"])
              if p and p.get("creationYear") is not None else np.nan

@@ -28,7 +28,8 @@ from predictionio_tpu.models.common import (ItemScoreResult, RatingsData,
                                             top_scores_to_result)
 from predictionio_tpu.ops.als import ALSConfig, als_train
 from predictionio_tpu.ops.ratings import RatingsCOO, dedup_ratings
-from predictionio_tpu.ops.similarity import (build_filter_mask, cosine_top_k,
+from predictionio_tpu.ops.similarity import (ItemCategories,
+                                             build_filter_mask, cosine_top_k,
                                              item_cosine_similarities,
                                              normalize_rows)
 
@@ -267,7 +268,10 @@ class ItemMetadataModel:
     (the ALSModel fields minus the factors)."""
     item_ix: EntityIdIxMap
     items: Dict[str, Item]
-    item_categories: List[Optional[set]]  # by dense index
+    # by dense index (ops/similarity.ItemCategories; models pickled
+    # before ISSUE 31 hold a list of optional sets, which
+    # build_filter_mask converts on the way in)
+    item_categories: ItemCategories
     item_years: Optional[np.ndarray] = None  # float32, NaN = undated
 
     @staticmethod
@@ -292,7 +296,8 @@ class ItemMetadataModel:
             item_categories.append(
                 set(item.categories) if item and item.categories else None)
         return dict(item_ix=item_ix, items=dict(items),
-                    item_categories=item_categories,
+                    item_categories=ItemCategories.from_sets(
+                        item_categories),
                     item_years=cls.derive_years(items, item_ix))
 
     def properties_of(self, keys: Tuple[str, ...]):

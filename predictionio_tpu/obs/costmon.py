@@ -83,6 +83,9 @@ ALS_SWEEP = "als_sweep"
 FOLD_SIDE = "fold_side"
 BATCH_PREDICT = "batch_predict"
 BATCH_PREDICT_MASKED = "batch_predict_masked"
+#: the masked top-k whose candidate mask is composed on the device from
+#: resident filter data (ops/similarity.composed_top_k_batch_begin)
+BATCH_PREDICT_COMPOSED = "batch_predict_composed"
 GATES_PROBE = "gates_probe"
 
 _label_ctx: contextvars.ContextVar = contextvars.ContextVar(

@@ -39,6 +39,7 @@ available); ``PIO_PROFILER_HZ`` tunes the rate.
 
 from __future__ import annotations
 
+import atexit
 import logging
 import os
 import sys
@@ -99,6 +100,7 @@ class SamplingProfiler:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._registered = False
+        self._stops_at_exit = False
         self._register_metrics()
 
     def _register_metrics(self):
@@ -142,6 +144,18 @@ class SamplingProfiler:
             self._thread = threading.Thread(
                 target=self._loop, daemon=True, name="pio-profiler")
             self._thread.start()
+            if not self._stops_at_exit:
+                # the sampler holds other threads' frames for a tick, so
+                # it is where their locals die when those threads have
+                # moved on: a jaxlib object freed here releases the GIL,
+                # and a daemon thread that takes the GIL back while the
+                # interpreter finalises is unwound through jaxlib's C++
+                # and aborts the process (exit 134 after a clean run;
+                # seen in one of six runs of a server with live filters,
+                # whose pool threads end at exit: ISSUE 31). atexit runs
+                # before finalisation begins: the thread is gone by then
+                atexit.register(self.stop)
+                self._stops_at_exit = True
         return True
 
     def stop(self, join_timeout_s: float = 2.0):

@@ -11,6 +11,7 @@ HBM. Filters arrive as a packed boolean mask built on host.
 from __future__ import annotations
 
 import functools
+import threading
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
@@ -38,12 +39,14 @@ def _masked_topk_impl(query_mat, item_table, allowed, k: int,
     wrappers below, so both variants rank identically)."""
     import jax
     import jax.numpy as jnp
-    scores = jnp.einsum("br,ir->bi", query_mat, item_table,
-                        preferred_element_type=jnp.float32)
-    if filter_positive:
-        allowed = allowed & (scores > 0)
-    scores = jnp.where(allowed, scores, -jnp.inf)
-    return jax.lax.top_k(scores, k)
+    with jax.named_scope("pio.serve.score"):
+        scores = jnp.einsum("br,ir->bi", query_mat, item_table,
+                            preferred_element_type=jnp.float32)
+        if filter_positive:
+            allowed = allowed & (scores > 0)
+        scores = jnp.where(allowed, scores, -jnp.inf)
+    with jax.named_scope("pio.serve.topk"):
+        return jax.lax.top_k(scores, k)
 
 
 @functools.partial(__import__("jax").jit,
@@ -262,6 +265,410 @@ def _masked_top_k_batch_sharded_begin(item_table,
     return finish
 
 
+# ---------------------------------------------------------------------------
+# Masked top-k with the candidate mask composed ON THE DEVICE
+#
+# The family above takes a [B, I] boolean mask built on the host: 4 MB a
+# query at a 4M-item catalogue, beside an answer of 60 bytes. This one
+# takes what a query really carries (a few category codes, tens to hundreds
+# of excluded or white-listed item indices) and composes the mask inside
+# the executable from filter data that lives on the device: the item ->
+# category array and the availability bitmap (ItemFilterData below).
+# ---------------------------------------------------------------------------
+
+#: padding of an item's category slots
+NO_CATEGORY = -1
+#: padding of a query's category list
+NO_QUERY_CATEGORY = -2
+#: a category name the model has never seen: the query still filters by
+#: category, and this code matches no item
+UNKNOWN_CATEGORY = -3
+#: which of a query's two lists a flat list entry belongs to (the bit plane
+#: it sets): excluded items, white-listed items
+LISTED_OUT = 0
+LISTED_WHITE = 1
+
+
+class ItemCategories:
+    """item -> categories as arrays: ``ids`` int32 [I, c_max] of category
+    codes padded with ``NO_CATEGORY`` (c_max from the data, at least 1) and
+    ``vocab`` name -> code. Built in one pass over the items that HAVE
+    categories."""
+
+    def __init__(self, ids: np.ndarray, vocab: dict):
+        self.ids = np.ascontiguousarray(ids, dtype=np.int32)
+        self.vocab = vocab
+
+    @classmethod
+    def from_pairs(cls, n_items: int, item_ix: np.ndarray,
+                   names: Sequence) -> "ItemCategories":
+        """From flat (item index, category name) pairs."""
+        item_ix = np.asarray(item_ix, dtype=np.int64)
+        uniq, codes = np.unique(np.asarray(names, dtype=str),
+                                return_inverse=True)
+        vocab = {str(n): c for c, n in enumerate(uniq.tolist())}
+        order = np.lexsort((codes, item_ix))
+        item_ix, codes = item_ix[order], codes[order]
+        keep = np.ones(item_ix.size, bool)      # a pair named twice
+        keep[1:] = (item_ix[1:] != item_ix[:-1]) | (codes[1:] != codes[:-1])
+        item_ix, codes = item_ix[keep], codes[keep]
+        start = np.flatnonzero(np.r_[True, item_ix[1:] != item_ix[:-1]]) \
+            if item_ix.size else np.zeros(0, np.int64)
+        slot = np.arange(item_ix.size) - np.repeat(
+            start, np.diff(np.r_[start, item_ix.size]))
+        ids = np.full((int(n_items), int(slot.max()) + 1
+                       if slot.size else 1), NO_CATEGORY, np.int32)
+        ids[item_ix, slot] = codes
+        return cls(ids, vocab)
+
+    @classmethod
+    def from_sets(cls, item_categories: Sequence) -> "ItemCategories":
+        """From a per-item sequence of optional name collections."""
+        if isinstance(item_categories, cls):
+            return item_categories
+        ix, names = [], []
+        for i, cats in enumerate(item_categories):
+            if cats:
+                names.extend(cats)
+                ix.extend([i] * len(cats))
+        return cls.from_pairs(len(item_categories), ix, names)
+
+    def codes_of(self, categories) -> np.ndarray:
+        """int32 codes of a query's category names (distinct)."""
+        return np.array(sorted({self.vocab.get(str(c), UNKNOWN_CATEGORY)
+                                for c in categories}), dtype=np.int32)
+
+    def matches(self, categories) -> np.ndarray:
+        """bool [I]: the item shares a category with ``categories``."""
+        want = self.codes_of(categories)
+        return np.isin(self.ids, want[want >= 0]).any(axis=1)
+
+    def __len__(self):
+        return self.ids.shape[0]
+
+
+def pack_available(n_rows: int, unavailable: np.ndarray) -> np.ndarray:
+    """The availability bitmap of ``n_rows`` items (a multiple of 32):
+    uint32 words in the layout of :func:`_bits_of` (item ``i`` is bit
+    ``i // W`` of word ``i % W``, W = n_rows / 32), set where the item may
+    be recommended."""
+    words = int(n_rows) // 32
+    un = np.asarray(unavailable, dtype=np.int64)
+    un = un[(un >= 0) & (un < n_rows)]
+    gone = np.zeros(words, np.uint32)
+    # work proportional to the list, not to the catalogue: the bitmap is
+    # rebuilt on the serving path at every new `$set`
+    np.bitwise_or.at(gone, un % words,
+                     np.uint32(1) << (un // words).astype(np.uint32))
+    return ~gone
+
+
+def _bits_of(words):
+    """bool [..., 32 * W] of uint32 words [..., W], traced: item ``i`` is bit
+    ``i // W`` of word ``i % W``, so the items of one bit position are W
+    neighbours and the whole is 32 slabs laid end to end. With the bits of
+    a word as neighbours instead (item ``i`` in word ``i // 32``) the unpack
+    is a reshape of a 32-wide minor dimension, which the TPU pads to 128
+    lanes and re-lays: 2 ms a query row at 2^22 items (PERF.md, PR 31)."""
+    import jax.numpy as jnp
+    return jnp.concatenate(
+        [((words >> jnp.uint32(j)) & jnp.uint32(1)).astype(bool)
+         for j in range(32)], axis=-1)
+
+
+def _composed_masked_topk_impl(query_mat, item_table, cat, avail_bits,
+                               n_items, q_cats, list_rows, list_cols,
+                               list_vals, has_white, k: int):
+    """Traced body of the composed-mask executables.
+
+    query_mat [B, R]; item_table [I, R]; cat int32 [I, C] (NO_CATEGORY
+    pads); avail_bits uint32 [I / 32]; n_items the live rows; q_cats int32
+    [B, Q] (NO_QUERY_CATEGORY pads; a row of pads = no category filter);
+    list_rows / list_cols / list_vals int32 [T]: the batch's flat list of
+    (query, item) pairs, LISTED_OUT or LISTED_WHITE each, every (pair,
+    value) at most once (pads: col = I, dropped); has_white bool [B]: the
+    query gave a whiteList (an empty one admits nothing). The rule, per
+    query and item, is the e-commerce template's isCandidateItem with the
+    live lists folded in: live and available and (no categories or one
+    shared) and not listed out and (no whiteList or white-listed), and
+    score > 0.
+
+    The lists are scattered into two bitmaps of the batch, uint32
+    [2, B, I / 32] in :func:`_bits_of`'s layout (an entry adds its bit to
+    its word: entries are distinct, so the sum is the union), and read back
+    inside the mask's fusion. Scattering into a [B, I] array instead costs
+    10 ms a dispatch at I = 2^22 whatever the list's length (PERF.md, PR
+    31: the array is written, re-laid and read, 64M elements), beside a
+    9 ms scan."""
+    import jax
+    import jax.numpy as jnp
+    b, i_b = query_mat.shape[0], item_table.shape[0]
+    words = i_b // 32
+    with jax.named_scope("pio.serve.mask"):
+        base = _bits_of(avail_bits) & (jnp.arange(i_b) < n_items)
+        shared = jnp.zeros((b, i_b), bool)
+        for j in range(q_cats.shape[1]):
+            for c in range(cat.shape[1]):
+                shared |= cat[None, :, c] == q_cats[:, j, None]
+        by_category = shared | (q_cats == NO_QUERY_CATEGORY).all(
+            axis=1)[:, None]
+        listed = _bits_of(jnp.zeros((2, b, words), jnp.uint32).at[
+            list_vals, list_rows,
+            jnp.where(list_cols < i_b, list_cols % words, words)].add(
+                jnp.uint32(1) << (list_cols // words).astype(jnp.uint32),
+                mode="drop"))
+        by_list = ~listed[LISTED_OUT] & (listed[LISTED_WHITE]
+                                         | ~has_white[:, None])
+        allowed = base[None, :] & by_category & by_list
+    with jax.named_scope("pio.serve.score"):
+        scores = jnp.einsum("br,ir->bi", query_mat, item_table,
+                            preferred_element_type=jnp.float32)
+        scores = jnp.where(allowed & (scores > 0), scores, -jnp.inf)
+    with jax.named_scope("pio.serve.topk"):
+        return jax.lax.top_k(scores, k)
+
+
+@functools.partial(__import__("jax").jit, static_argnames=("k",))
+def _composed_masked_topk(query_mat, item_table, cat, avail_bits, n_items,
+                          q_cats, list_rows, list_cols, list_vals,
+                          has_white, k: int):
+    """(scores [B, k], idx [B, k]) of :func:`_composed_masked_topk_impl`;
+    excluded slots carry -inf."""
+    return _composed_masked_topk_impl(
+        query_mat, item_table, cat, avail_bits, n_items, q_cats,
+        list_rows, list_cols, list_vals, has_white, k=k)
+
+
+@functools.partial(__import__("jax").jit, static_argnames=("k", "p"))
+def _composed_masked_topk_packed(query_mat, item_table, cat, avail_bits,
+                                 n_items, q_cats, list_rows, list_cols,
+                                 list_vals, has_white, k: int, p: int):
+    """:func:`_composed_masked_topk` with the readback plane's pack fused
+    on: one contiguous ids + quantized scores payload a dispatch."""
+    from predictionio_tpu.ops import readback
+    scores, idx = _composed_masked_topk_impl(
+        query_mat, item_table, cat, avail_bits, n_items, q_cats,
+        list_rows, list_cols, list_vals, has_white, k=k)
+    return readback.pack_device(scores, idx, p)
+
+
+def _aot_composed_topk_builder(b: int = 0, i: int = 0, r: int = 0,
+                               k: int = 0, c: int = 0, q: int = 0,
+                               t: int = 0, p: int = 0):
+    """(jit_fn, example avals, statics) for one composed-mask bucket:
+    batch ``b``, item rows ``i``, rank ``r``, top ``k``, ``c`` category
+    slots an item, ``q`` a query, ``t`` flat list entries, pack ``p``."""
+    import jax
+    sds = jax.ShapeDtypeStruct
+    avals = (sds((b, r), np.float32), sds((i, r), np.float32),
+             sds((i, c), np.int32), sds((i // 32,), np.uint32),
+             sds((), np.int32), sds((b, q), np.int32),
+             sds((t,), np.int32), sds((t,), np.int32), sds((t,), np.int32),
+             sds((b,), bool))
+    if p:
+        return _composed_masked_topk_packed, avals, {"k": k, "p": p}
+    return _composed_masked_topk, avals, {"k": k}
+
+
+def composed_topk_dims(n_items: int, rank: int, batch: int, k: int,
+                       c_max: int, n_query_cats: int = 0,
+                       n_listed: int = 0) -> dict:
+    """Shape-bucket dims of one composed-mask dispatch — shared by the
+    serve dispatch and the deploy/swap warm path."""
+    from predictionio_tpu.compile import buckets as B
+    from predictionio_tpu.ops import readback
+    i_b = B.bucket_rows(n_items)
+    return {"b": B.bucket_batch(batch), "i": i_b, "r": int(rank),
+            "k": min(B.bucket_batch(k, floor=B.K_FLOOR), i_b),
+            "c": int(c_max),
+            "q": B.bucket_batch(n_query_cats,
+                                floor=B.QUERY_CATEGORIES_FLOOR),
+            "t": B.bucket_list(n_listed), "p": readback.pack_flag()}
+
+
+#: list entries a query the deploy-time warm allows for: a user's seen items
+#: (the e-commerce template's usual filter, a thousand for a heavy user)
+#: beside a white or black list of a few hundred to a few thousand ids. A
+#: bucket that was not warmed compiles on the serving path at its first
+#: dispatch, seconds during which nothing is answered (at 1,024 a query,
+#: one campaign query of a heavy user froze a server for 4.7 s: PERF.md,
+#: PR 31)
+WARM_LISTED_PER_QUERY = 4096
+
+
+def composed_topk_warm_dims(n_items: int, rank: int, batch_hint: int,
+                            c_max: int) -> list:
+    """The buckets a deployment warms: every batch bucket of the
+    micro-batcher's ladder, each with the list buckets that batch can
+    fill at up to WARM_LISTED_PER_QUERY entries a query. Longer lists and
+    more than QUERY_CATEGORIES_FLOOR categories a query compile on first
+    use, in the background."""
+    from predictionio_tpu.compile import buckets as B
+    out = []
+    for e in range(B.bucket_batch(max(batch_hint, 1)).bit_length()):
+        t = B.LIST_FLOOR
+        while True:
+            out.append(composed_topk_dims(n_items, rank, 1 << e, 16,
+                                          c_max, n_listed=t))
+            if t >= (1 << e) * WARM_LISTED_PER_QUERY:
+                break
+            t = B.bucket_list(t + 1)
+    return out
+
+
+class ItemFilterData:
+    """What the composed-mask executables read beside the factor tables,
+    one object a model: the item -> category array and the availability
+    bitmap, host copies whose device copies ride utils/device_cache (the
+    category array uploaded once at its row bucket; a bitmap once per
+    ``set_unavailable``). The bitmap is replaced whole, never edited: a
+    dispatch that has read ``available_bits`` keeps the bitmap it read."""
+
+    def __init__(self, categories: ItemCategories):
+        self.categories = categories
+        self.n_items = len(categories)
+        from predictionio_tpu.compile import buckets as B
+        self._rows = B.bucket_rows(self.n_items)
+        self.available_bits = pack_available(self._rows, ())
+        #: what the bitmap was built from, as the owner names it (the
+        #: e-commerce engine: the `$set`'s event id)
+        self.unavailable_tag = None
+        self._lock = threading.Lock()
+
+    def set_unavailable(self, item_indices, tag=None) -> None:
+        with self._lock:
+            self._replace(item_indices, tag)
+
+    def sync_unavailable(self, read) -> bool:
+        """Follow a list kept elsewhere: ``read(tag)`` is given the tag the
+        bitmap was built from and answers ``(newest tag, load)``; where the
+        tags differ the bitmap is rebuilt from ``load()``'s item indices
+        (an empty list where the newest tag is None) and True comes back.
+        The compare and the rebuild are one step under this object's lock,
+        so of two callers the later read wins and none parses a list the
+        bitmap already holds."""
+        with self._lock:
+            tag, load = read(self.unavailable_tag)
+            if tag == self.unavailable_tag:
+                return False
+            self._replace(load() if tag is not None else (), tag)
+            return True
+
+    def _replace(self, item_indices, tag) -> None:
+        self.available_bits = pack_available(self._rows, item_indices)
+        self.unavailable_tag = tag
+
+
+_composed_specs_registered = False
+
+
+def register_composed_aot_specs():
+    global _composed_specs_registered
+    if _composed_specs_registered:
+        return
+    from predictionio_tpu.compile.aot import get_aot
+    from predictionio_tpu.obs import costmon
+    get_aot().register(costmon.BATCH_PREDICT_COMPOSED,
+                       _aot_composed_topk_builder)
+    _composed_specs_registered = True
+
+
+def _filter_h2d_counter():
+    from predictionio_tpu.obs.metrics import get_registry
+    return get_registry().counter(
+        "pio_filter_h2d_bytes_total",
+        "Bytes of filter data sent host->device for composed-mask "
+        "dispatches: the queries' category codes and item lists, and "
+        "each availability bitmap once")
+
+
+def composed_top_k_batch_begin(item_table: np.ndarray,
+                               query_vecs: np.ndarray,
+                               filters: ItemFilterData,
+                               query_cats: Sequence,
+                               listed: Sequence,
+                               has_white: Sequence[bool], k: int):
+    """Enqueue one composed-mask top-k over ``item_table`` for B queries
+    and return ``finish() -> (scores, idx)`` (the deferred readback).
+
+    ``query_cats[j]``: int32 category codes of query j (empty = no
+    category filter); ``listed[j]``: (item indices, LISTED_OUT or
+    LISTED_WHITE for each) of query j's lists; ``has_white[j]``: the
+    query gave a whiteList. Nothing of size [B, I] is built or sent: the
+    upload is the category codes and the flat list, a few KB."""
+    from predictionio_tpu.compile import buckets as B
+    from predictionio_tpu.compile.aot import get_aot
+    from predictionio_tpu.obs import costmon
+    from predictionio_tpu.ops import readback
+    from predictionio_tpu.utils.device_cache import (cached_put,
+                                                     cached_put_rows,
+                                                     is_cached)
+    register_composed_aot_specs()
+    n_items, rank = item_table.shape
+    n = query_vecs.shape[0]
+    dims = composed_topk_dims(
+        n_items, rank, n, k, filters.categories.ids.shape[1],
+        max((len(c) for c in query_cats), default=0),
+        sum(len(ix) for ix, _ in listed))
+    i_b = dims["i"]
+    qp = np.zeros((dims["b"], rank), np.float32)
+    qp[:n] = query_vecs
+    q_cats = np.full((dims["b"], dims["q"]), NO_QUERY_CATEGORY, np.int32)
+    white = np.zeros(dims["b"], bool)
+    white[:n] = has_white
+    rows = np.zeros(dims["t"], np.int32)
+    cols = np.full(dims["t"], i_b, np.int32)       # past the end: dropped
+    vals = np.zeros(dims["t"], np.int32)
+    at = 0
+    for j in range(n):
+        q_cats[j, :len(query_cats[j])] = query_cats[j]
+        ix, v = listed[j]
+        rows[at:at + len(ix)] = j
+        cols[at:at + len(ix)] = ix
+        vals[at:at + len(ix)] = v
+        at += len(ix)
+    cols[(cols < 0) | (cols >= n_items)] = i_b
+    # an entry named twice would add its bit twice
+    key = (rows[:at].astype(np.int64) * 2 + vals[:at]) * (i_b + 1) \
+        + cols[:at]
+    _, first = np.unique(key, return_index=True)
+    if first.size < at:
+        twice = np.ones(at, bool)
+        twice[first] = False
+        cols[:at][twice] = i_b
+    bits = filters.available_bits
+    sent = q_cats.nbytes + rows.nbytes + cols.nbytes + vals.nbytes \
+        + white.nbytes
+    if not is_cached(bits):
+        sent += bits.nbytes
+    _filter_h2d_counter().inc(sent)
+    args = (qp, cached_put_rows(item_table, i_b),
+            cached_put_rows(filters.categories.ids, i_b), cached_put(bits),
+            np.int32(n_items), q_cats, rows, cols, vals, white)
+    k_eff, p = dims["k"], dims["p"]
+    if p:
+        packed = get_aot().dispatch(
+            costmon.BATCH_PREDICT_COMPOSED, dims,
+            lambda *a: _composed_masked_topk_packed(*a, k=k_eff, p=p),
+            *args)
+        fetch = readback.begin_fetch_packed(packed, p)
+    else:
+        scores, idx = get_aot().dispatch(
+            costmon.BATCH_PREDICT_COMPOSED, dims,
+            lambda *a: _composed_masked_topk(*a, k=k_eff), *args)
+        fetch = readback.begin_fetch(scores, idx)
+    if B.should_promote(n_items, i_b):
+        get_aot().ensure(
+            costmon.BATCH_PREDICT_COMPOSED,
+            dict(dims, i=B.next_bucket(i_b),
+                 k=min(k_eff, B.next_bucket(i_b))), background=True)
+
+    def finish() -> Tuple[np.ndarray, np.ndarray]:
+        scores_h, idx_h = fetch()
+        return scores_h[:n], idx_h[:n]
+    return finish
+
+
 def unpack_top_k_rows(scores_row: np.ndarray, idx_row: np.ndarray,
                       num: int) -> Tuple[np.ndarray, np.ndarray]:
     """Per-query view of one masked_top_k_batch row: slice to the query's
@@ -299,11 +706,14 @@ def cosine_top_k(item_factors_normalized: np.ndarray,
 def build_filter_mask(n_items: int,
                       exclude: Sequence[int] = (),
                       white_list: Optional[Sequence[int]] = None,
-                      item_categories: Optional[Sequence[Optional[set]]] = None,
+                      item_categories=None,
                       categories: Optional[set] = None) -> np.ndarray:
     """Host-side candidate mask implementing isCandidateItem
     (ALSAlgorithm.scala:192+): whitelist wins, blacklist/query items
-    excluded, category intersection required when given."""
+    excluded, category intersection required when given.
+    ``item_categories`` is an :class:`ItemCategories` (one vectorised
+    membership test) or, as older pickled models hold it, a per-item
+    sequence of optional sets, converted on the way in."""
     mask = np.ones(n_items, dtype=bool)
     if white_list is not None:
         mask[:] = False
@@ -314,9 +724,8 @@ def build_filter_mask(n_items: int,
     ex = ex[(ex >= 0) & (ex < n_items)]
     mask[ex] = False
     if categories is not None and item_categories is not None:
-        cat = np.array([bool(c and (c & categories))
-                        for c in item_categories], dtype=bool)
-        mask &= cat
+        mask &= ItemCategories.from_sets(item_categories).matches(
+            categories)
     return mask
 
 

@@ -286,6 +286,17 @@ class EngineServer:
                 inflight=(config.serve_inflight
                           if single_process else 1),
                 adaptive=config.adaptive_batching)
+        # says what the process was doing whenever requests wait and no
+        # dispatch moves for 0.4 s (obs/stallwatch.py); runs from start()
+        # to stop()
+        self.stallwatch = None
+        if self.batcher is not None:
+            from predictionio_tpu.obs.stallwatch import StallWatch
+            b = self.batcher
+            self.stallwatch = StallWatch(
+                waiting=lambda: b._inflight,
+                progress=lambda: (b.n_batches, b._inflight_batches),
+                metrics=self.metrics)
         self.router = self._build_router()
 
     def _register_metrics(self):
@@ -1065,8 +1076,16 @@ class EngineServer:
         pure function of (query, deployed models): no canary split in
         progress (two model sets answer concurrently), no feedback
         loop (each query must land its predict event), no output
-        plugins (sniffers must see every prediction)."""
+        plugins (sniffers must see every prediction), and no algorithm
+        with live filters (``LIVE_FILTERS``: the e-commerce engine reads
+        the user's seen items and the unavailable list at predict time,
+        so a stored answer would be older than the filters the next
+        request must be held to; no invalidation reaches it, since the
+        events that change it are not model changes)."""
         if self.result_cache is None:
+            return False
+        if any(getattr(a, "LIVE_FILTERS", False)
+               for a in self.algorithms or ()):
             return False
         if self.canary.active:
             return False
@@ -1498,6 +1517,8 @@ class EngineServer:
         from predictionio_tpu.obs import profiler
         profiler.ensure_started()
         TRACER.watch_gc(True)
+        if self.stallwatch is not None:
+            self.stallwatch.start()
         srv = HttpServer(self.router, self.config.ip, self.config.port)
         self.server = srv
 
@@ -1537,6 +1558,8 @@ class EngineServer:
         if self.server:
             self.server.stop()
             TRACER.watch_gc(False)
+        if self.stallwatch is not None:
+            self.stallwatch.stop()
         if self.batcher is not None:
             self.batcher.stop()
         if self.coordinator is not None:

@@ -158,6 +158,14 @@ def cached_put(arr, sharding=None):
     return dev
 
 
+def is_cached(arr, sharding=None) -> bool:
+    """Whether :func:`cached_put` holds a device copy of exactly this host
+    array (a caller that counts bytes sent asks before it puts)."""
+    with _lock:
+        entry = _cache.get((id(arr), _sharding_key(sharding)))
+        return entry is not None and entry[0]() is arr
+
+
 def cached_put_padded(arr, sharding, row_multiple: int):
     """cached_put for sharded uploads whose dim-0 must divide the axis
     size: pads rows with zeros before upload, memoized on

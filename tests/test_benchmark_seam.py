@@ -227,3 +227,164 @@ def test_engine_server_as_the_serve_job_builds_it():
         assert callable(getattr(R.ALSAlgorithm, method))
     for attr in ("start", "stop"):
         assert callable(getattr(EngineServer, attr))
+
+
+# -- what jobs/http-queries-ecomm.py takes (ISSUE 31) ----------------------------
+
+ECOMM_CALLS = [
+    ("set-up", "models.ecommerce", "ECommerceModel", (),
+     {"rank": 200, "user_factors": "U", "item_factors": "V",
+      "item_factors_normalized": "Vn", "user_ix": "u", "item_ix": "i",
+      "items": {}, "item_categories": "cats"}),
+    ("set-up", "models.ecommerce", "ECommAlgorithmParams", (),
+     {"app_name": "bench", "unseen_only": True,
+      "seen_events": ("buy", "view"), "rank": 200}),
+    ("warm-up", "models.ecommerce", "Query", (), {"user": "0", "num": 10}),
+    ("set-up", "ops.similarity", "ItemCategories", ("ids", {"c0": 0}), {}),
+    ("set-up", "ops.similarity", "normalize_rows", ("V",), {}),
+    ("set-up", "data.storage.base", "App", (0, "bench"), {}),
+    ("populate", "data.columnar", "ColumnarBatch",
+     (3, "view", "user", ["0"], "item", ["1"], None, "2017-11-25T00:00:00Z"),
+     {}),
+    ("the writer", "data.event", "Event", (),
+     {"event": "$set", "entity_type": "constraint",
+      "entity_id": "unavailableItems", "properties": "DataMap"}),
+]
+
+
+@pytest.mark.parametrize(
+    "module,name,args,kwargs",
+    [pytest.param(m, n, a, k, id=f"{n}-ecomm_{who.replace(' ', '_')}")
+     for who, m, n, a, k in ECOMM_CALLS])
+def test_the_ecomm_jobs_call_binds(module, name, args, kwargs):
+    binds(getattr(_mod(module), name), *args, **kwargs)
+
+
+def test_ecomm_engine_as_the_serve_job_builds_it():
+    from predictionio_tpu.serving import EngineServer
+    E = _mod("models.ecommerce")
+    assert callable(E.ECommerceEngineFactory.apply)
+    for method in ("batch_predict", "batch_predict_begin",
+                   "aot_warm_specs"):
+        assert callable(getattr(E.ECommAlgorithm, method))
+    assert E.ECommAlgorithm.LIVE_FILTERS is True
+    binds(EngineServer._cache_usable, "self")
+    # the model reads as a dataclass with the arrays the job hands it
+    fields = {f.name for f in dataclasses.fields(E.ECommerceModel)}
+    assert {"item_factors_normalized", "item_categories"} <= fields
+
+
+def test_the_store_as_the_ecomm_job_opens_it(tmp_env, monkeypatch):
+    """An app, a nativelog events DAO from the environment, the columnar
+    write and the plain insert the writer uses."""
+    from predictionio_tpu.data import Event
+    from predictionio_tpu.data.columnar import ColumnarBatch
+    from predictionio_tpu.data.datamap import DataMap
+    from predictionio_tpu.data.storage import registry
+    from predictionio_tpu.data.storage.base import App
+    monkeypatch.setenv("PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE",
+                       "NATIVELOG")
+    monkeypatch.setenv("PIO_STORAGE_SOURCES_NATIVELOG_TYPE", "nativelog")
+    monkeypatch.setenv("PIO_STORAGE_SOURCES_NATIVELOG_PATH",
+                       str(tmp_env / "eventlog"))
+    registry.clear_cache()
+    app_id = registry.Storage.get_meta_data_apps().insert(App(0, "bench"))
+    events = registry.Storage.get_events()
+    events.init(app_id)
+    events.insert_columnar(ColumnarBatch(
+        2, "view", "user", ["0", "0"], "item", ["1", "2"], None,
+        "2017-11-25T00:00:00.000Z"), app_id)
+    events.insert(Event(event="$set", entity_type="constraint",
+                        entity_id="unavailableItems",
+                        properties=DataMap({"items": ["1"]})), app_id)
+    from predictionio_tpu.data.store import LEventStore
+    seen = LEventStore.find_columnar(
+        "bench", entity_type="user", entity_id="0",
+        event_names=["buy", "view"], target_entity_type="item",
+        timeout_ms=200)
+    assert sorted(seen["target_entity_id"].tolist()) == ["1", "2"]
+    event_id, latest = LEventStore.latest_event(
+        "bench", "constraint", "unavailableItems", event_names=["$set"])
+    assert latest.event_id == event_id
+    assert latest.properties.get_string_list("items") == ["1"]
+    assert LEventStore.latest_event(
+        "bench", "constraint", "unavailableItems", event_names=["$set"],
+        known_id=event_id) == (event_id, None)
+
+
+#: the counters the job reads as differences over the window, and the spans
+#: it sums from the tracer's batch_predict traces
+ECOMM_COUNTERS = ["pio_filter_h2d_bytes_total",
+                  "pio_filter_seen_timeouts_total",
+                  "pio_filter_constraint_reloads_total",
+                  "pio_filter_constraint_failures_total"]
+
+
+def test_the_filter_counters_and_spans_the_ecomm_job_reads(tmp_env):
+    import numpy as np
+    from predictionio_tpu.data.bimap import BiMap, EntityIdIxMap
+    from predictionio_tpu.data.storage import registry
+    from predictionio_tpu.data.storage.base import App
+    from predictionio_tpu.obs import TRACER
+    from predictionio_tpu.obs.metrics import get_registry
+    E, S = _mod("models.ecommerce"), _mod("ops.similarity")
+    app_id = registry.Storage.get_meta_data_apps().insert(App(0, "bench"))
+    registry.Storage.get_events().init(app_id)
+    ids = EntityIdIxMap(BiMap({str(i): i for i in range(8)}))
+    V = np.abs(np.random.default_rng(0).standard_normal((8, 4))).astype(
+        np.float32)
+    model = E.ECommerceModel(
+        rank=4, user_factors=V, item_factors=V,
+        item_factors_normalized=S.normalize_rows(V), user_ix=ids,
+        item_ix=ids, items={}, item_categories=S.ItemCategories(
+            np.zeros((8, 1), np.int32), {"c0": 0}))
+    algo = E.ECommAlgorithm(E.ECommAlgorithmParams(app_name="bench",
+                                                   rank=4))
+    with TRACER.trace("batch_predict"):
+        algo.batch_predict(model, [(0, E.Query(user="1", num=3))])
+    names = set()
+
+    def walk(span):
+        names.add(span["name"])
+        for child in span.get("children", ()):
+            walk(child)
+
+    walk(TRACER.snapshot(limit=1, kind="batch_predict")[0]["root"])
+    assert {"filter.seen_read", "filter.constraint_read",
+            "filter.lists"} <= names
+    assert get_registry().get(ECOMM_COUNTERS[0]).value > 0
+    # every dispatch of a window, not the ring's last 128: the stages'
+    # histogram, read as differences (sum, count, bucket_counts /
+    # percentile_since)
+    hist = get_registry().get("pio_filter_seconds")
+    for stage in ("seen_read", "constraint_read", "lists"):
+        child = hist.labels(stage=stage)
+        assert child.count >= 1 and child.sum > 0
+        assert child.percentile_since([0] * len(child.bucket_counts()),
+                                      95) > 0
+    # the others are registered when their event first happens (a read
+    # past its deadline, a `$set` found); the job reads an absent counter
+    # as 0, and tests/test_ecomm_filtered_reference.py makes both happen
+    for name in ECOMM_COUNTERS[1:]:
+        counter = get_registry().get(name)
+        assert counter is None or counter.value >= 0
+
+
+def test_the_stall_watch_names_the_ecomm_job_reads():
+    """jobs/http-queries-ecomm.py: `server.stallwatch` (None without a
+    batcher), its two totals, its worst tick and its reports' keys."""
+    from predictionio_tpu.obs.stallwatch import StallWatch
+    from predictionio_tpu.serving import EngineServer, ServerConfig
+    R = _mod("models.recommendation")
+    server = EngineServer(ServerConfig(ip="127.0.0.1", port=0,
+                                       micro_batch=16),
+                          engine=R.RecommendationEngineFactory.apply())
+    watch = server.stallwatch
+    assert isinstance(watch, StallWatch)
+    assert (watch.n_stalls, watch.stall_s, watch.max_tick_late_s) == (0, 0.0,
+                                                                      0.0)
+    assert watch.reports() == []
+    report = watch._report(0.0, 1.0, 0.5, 0.0, 0.0, {})
+    assert {"at", "since", "stacks", "tick_late_s", "process_cpu_s",
+            "machine_s", "waiting"} <= set(report)
+    assert watch.reports()[0]["at"] == 1.0 and watch.n_stalls == 1

@@ -76,9 +76,32 @@ class TestChanneledServeTime:
             Event(event="$set", entity_type="constraint",
                   entity_id="unavailableItems",
                   properties=DataMap({"items": ["i1"]})), app_id, chan_id)
-        algo = E.ECommAlgorithm(E.ECommAlgorithmParams(
-            app_name="chapp", channel_name="mobile"))
-        assert algo._unavailable_items() == ["i1"]
-        algo_default = E.ECommAlgorithm(E.ECommAlgorithmParams(
-            app_name="chapp"))
-        assert algo_default._unavailable_items() == []
+        import numpy as np
+        from predictionio_tpu.data.bimap import EntityIdIxMap
+        from predictionio_tpu.ops.similarity import (ItemCategories,
+                                                     pack_available)
+
+        def synced_bits(**params):
+            """The availability bitmap after one sync from the store."""
+            ix = EntityIdIxMap.build(["i0", "i1", "i2"])
+            model = E.ECommerceModel(
+                rank=2, user_factors=np.zeros((1, 2), np.float32),
+                item_factors=np.zeros((3, 2), np.float32),
+                item_factors_normalized=np.zeros((3, 2), np.float32),
+                user_ix=EntityIdIxMap.build(["u0"]), item_ix=ix, items={},
+                item_categories=ItemCategories.from_sets([None] * 3))
+            algo = E.ECommAlgorithm(E.ECommAlgorithmParams(
+                app_name="chapp", **params))
+            filters = model.filter_data()
+            algo._sync_unavailable(model, filters)
+            return filters, ix
+
+        filters, ix = synced_bits(channel_name="mobile")
+        assert filters.unavailable_tag is not None
+        np.testing.assert_array_equal(
+            filters.available_bits,
+            pack_available(filters._rows, [ix["i1"]]))
+        filters, _ = synced_bits()      # the default channel holds no $set
+        assert filters.unavailable_tag is None
+        np.testing.assert_array_equal(
+            filters.available_bits, pack_available(filters._rows, ()))

@@ -183,3 +183,40 @@ def test_taobao_gram_program_reads_the_table_where_it_lies(sds, table):
     m = compiled.memory_analysis()
     assert m.temp_size_in_bytes < 64 << 20
     assert m.argument_size_in_bytes < (n + 1) * rank * 4 * 1.001
+
+
+# -- PR 31: the composed-mask executable at the served taobao buckets ---------
+
+def _compose(sds, b, t, i_rows=I_ROWS, rank=200):
+    import jax.numpy as jnp
+    from predictionio_tpu.ops import similarity as S
+    i32 = jnp.int32
+    return S._composed_masked_topk_packed.lower(
+        sds((b, rank), jnp.float32), sds((i_rows, rank), jnp.float32),
+        sds((i_rows, 1), i32), sds((i_rows // 32,), jnp.uint32),
+        sds((), i32), sds((b, 4), i32), sds((t,), i32), sds((t,), i32),
+        sds((t,), i32), sds((b,), bool), k=K, p=1).compile()
+
+
+@pytest.mark.parametrize("b,t", [(1, 1024), (16, 1024), (16, 16384)])
+def test_composed_mask_executable_fits_beside_both_item_tables(sds, b, t):
+    """The served e-commerce configuration holds two item tables at the
+    2^22 bucket (6.71 GB); one dispatch's arguments and temporaries have to
+    fit beside the other table in the 15.75 GB the compiler allows."""
+    m = _compose(sds, b, t).memory_analysis()
+    table = I_ROWS * 200 * 4
+    assert m.argument_size_in_bytes >= table
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes + table
+            < 15.75e9)
+
+
+def test_composed_mask_scatters_into_bitmaps_not_a_batch_by_items_array(sds):
+    """The lists land in [2, b, I/32] words. A scatter into a [b, I] array
+    cost 10 ms a dispatch at I = 2^22 whatever the list's length (my chip
+    run, PR 31): the compiled program may hold no scatter whose operand has
+    b * I elements."""
+    text = _compose(sds, 16, 4096).as_text()
+    scatters = [line for line in text.splitlines() if " scatter(" in line]
+    assert scatters
+    big = str(16 * I_ROWS)
+    assert not [line[:160] for line in scatters if big in line]

@@ -265,6 +265,50 @@ def test_the_serve_kernel_names_its_stages():
         "pio.serve.pack"}
 
 
+def test_the_composed_mask_kernel_names_its_stages():
+    """The masked executables carry the other serve kernel's scope names
+    (benchmark/scoped.py reduces both alike), with `pio.serve.mask` for the
+    mask composed on the device (ISSUE 31)."""
+    import jax.numpy as jnp
+    from predictionio_tpu.ops import similarity as S
+    i32 = np.int32
+    text = S._composed_masked_topk_packed.trace(
+        jnp.ones((2, 8)), jnp.ones((64, 8)), np.zeros((64, 1), i32),
+        np.zeros(2, np.uint32), i32(60), np.full((2, 4), -2, i32),
+        np.zeros(16, i32), np.full(16, 64, i32), np.zeros(16, i32),
+        np.zeros(2, bool), k=4, p=1).lower().as_text(debug_info=True)
+    assert set(re.findall(r"pio\.[a-z_.]+", text)) == {
+        "pio.serve.mask", "pio.serve.score", "pio.serve.topk",
+        "pio.serve.pack"}
+    text = S._batched_masked_topk_packed.trace(
+        jnp.ones((2, 8)), jnp.ones((64, 8)), np.ones((2, 64), bool),
+        k=4, filter_positive=True, p=1).lower().as_text(debug_info=True)
+    assert set(re.findall(r"pio\.[a-z_.]+", text)) == {
+        "pio.serve.score", "pio.serve.topk", "pio.serve.pack"}
+
+
+@pytest.mark.parametrize("name", ["filter.seen_read",
+                                  "filter.constraint_read", "filter.lists"])
+def test_the_filter_spans_are_regions_of_the_one_tracer(name, monkeypatch):
+    """Host side of the live filters: `pio.filter.*` regions entered by
+    models/ecommerce.py through obs/trace.py's TRACER, so they lie on the
+    profiler's clock with every other span."""
+    import inspect
+    from predictionio_tpu.models import ecommerce as E
+    from predictionio_tpu.obs import TRACER
+    assert E.TRACER is TRACER
+    stage = name.split(".", 1)[1]
+    assert f'_filter_stage("{stage}")' in inspect.getsource(E.ECommAlgorithm)
+    # the one way in: a region of that name, and the stage's histogram
+    from predictionio_tpu.obs.metrics import get_registry
+    with TRACER.trace("probe") as root:
+        with E._filter_stage(stage) as span:
+            assert span.name == name
+    assert root is not None
+    hist = get_registry().get("pio_filter_seconds")
+    assert hist.labels(stage=stage).count >= 1
+
+
 def test_scopes_leave_the_answers_as_they_were():
     """named_scope is metadata: the packed serve kernel ranks as the
     exact-size reference does."""
