@@ -9,11 +9,12 @@ the subsystem that amortizes it away:
   cache, managed: ``$JAX_COMPILATION_CACHE_DIR`` when set, else the
   fixed ``<checkout>/.xla_cache``, plus the ``pio cache
   {status,clear}`` surface.
-- :mod:`predictionio_tpu.compile.buckets` — the shape-bucket ladder:
-  next-pow2-style buckets for vocabulary rows, touched-row counts and
-  query batch sizes, so growth INSIDE a bucket never changes a traced
-  shape (zero recompiles) and bucket promotion is a single, predictable
-  compile that can run before the shape is needed.
+- :mod:`predictionio_tpu.compile.buckets` — the shape-bucket ladders:
+  eighth-of-an-octave rungs for the rows of resident factor tables,
+  power-of-two buckets for touched-row counts and query batch sizes,
+  so growth INSIDE a bucket never changes a traced shape (zero
+  recompiles) and bucket promotion is a single, predictable compile
+  that can run before the shape is needed.
 - :mod:`predictionio_tpu.compile.aot` — the AOT executable registry:
   hot executables (``batch_predict``, the fold-in solves, the ALS
   sweep, the gate probe) are ``jit(...).lower(...).compile()``-ed at
@@ -25,8 +26,9 @@ the subsystem that amortizes it away:
 disables the persistent cache. Both fall back to plain jit dispatch.
 """
 
-from predictionio_tpu.compile.buckets import (bucket_batch, bucket_rows,
-                                              bucket_key, occupancy,
+from predictionio_tpu.compile.buckets import (bucket_batch, bucket_key,
+                                              bucket_rows,
+                                              bucket_table_rows,
                                               PROMOTE_AT)
 from predictionio_tpu.compile.cache import (cache_status, clear_cache,
                                             enable_persistent_cache,
@@ -37,8 +39,7 @@ from predictionio_tpu.compile.aot import (AOTRegistry, aot_enabled,
 
 __all__ = [
     "AOTRegistry", "aot_enabled", "bucket_batch", "bucket_key",
-    "bucket_rows", "cache_status", "clear_cache",
-    "enable_persistent_cache", "get_aot", "occupancy",
-    "persistent_cache_enabled", "PROMOTE_AT", "shared_jit",
-    "warm_models",
+    "bucket_rows", "bucket_table_rows", "cache_status", "clear_cache",
+    "enable_persistent_cache", "get_aot", "persistent_cache_enabled",
+    "PROMOTE_AT", "shared_jit", "warm_models",
 ]

@@ -42,7 +42,9 @@ import threading
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
-from predictionio_tpu.compile.buckets import bucket_key, bucket_label
+from predictionio_tpu.compile.buckets import (bucket_key, bucket_label,
+                                              bucket_table_rows_sharded,
+                                              should_promote_table)
 from predictionio_tpu.obs.costmon import device_timed
 
 logger = logging.getLogger(__name__)
@@ -363,6 +365,17 @@ def get_aot() -> AOTRegistry:
                 import atexit
                 atexit.register(_drop_executables_at_exit)
     return _registry
+
+
+def precompile_next_rung(label: str, dims: Dict[str, int], dim: str,
+                         n: int) -> None:
+    """Bucket promotion: a resident table of ``n`` live rows nearing
+    its rung ``dims[dim]`` pre-compiles ``label``'s executable at the
+    next rung in the background, BEFORE growth needs it (a sharded
+    layout's ``s`` dim keeps the rung on a multiple of the shards)."""
+    if should_promote_table(n, dims[dim]):
+        nxt = bucket_table_rows_sharded(dims[dim] + 1, dims.get("s", 1))
+        get_aot().ensure(label, dict(dims, **{dim: nxt}), background=True)
 
 
 def shared_jit(key: str, impl: Callable, **jit_kwargs):

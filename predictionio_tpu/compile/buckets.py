@@ -1,16 +1,31 @@
-"""Shape-bucket ladder: the sizes the compile plane compiles for.
+"""Shape-bucket ladders: the sizes the compile plane compiles for.
 
 Every traced program's cost model keys on shapes; every shape that
-changes is a recompile. The ladder quantizes the three dims that
-actually move in production — vocabulary rows (users/items grow with
-traffic), touched-row counts (fold ticks), and query batch sizes — to
-next-power-of-two buckets with a floor, so:
+changes is a recompile. The dims that actually move in production are
+quantized to ladders with a floor, so growth INSIDE a bucket changes no
+traced shape (zero recompiles) and the program count per executable
+stays logarithmic in the largest size. Three ladders, because the dims
+pay differently for padding:
 
-- growth INSIDE a bucket changes no traced shape (zero recompiles);
-- a promotion (bucket -> 2x) is one predictable compile per
-  executable, cheap enough to run in the background before the shape
-  is needed (``occupancy`` past ``PROMOTE_AT`` is the trigger);
-- the program count per executable is bounded by log2(max size).
+- **resident factor tables** (vocabulary rows: users, items, and what
+  rides beside them row for row) take ``bucket_table_rows``: eight
+  rungs an octave from ``TABLE_FINE_FROM`` rows up, powers of two
+  below. Every query scans the item table whole and every padded row
+  is HBM held for as long as the model is served, so a power of two's
+  up-to-50% padding is paid per dispatch; an eighth step wastes at most
+  12.5% and still divides by pow2 shard counts and the TPU's tilings.
+  A promotion (``next_table_bucket``) is then one predictable compile
+  per executable per <= 12.5% of growth, run in the background once
+  the headroom left is a quarter of that step
+  (``should_promote_table``); below the threshold a scan costs nothing
+  and tiny models keep sharing one program.
+- **touched-row counts of a fold tick and staged upload columns** take
+  ``bucket_rows``, powers of two: they are not scanned per query, they
+  swing tick to tick, and the recompiles a coarse ladder saves are what
+  it is for.
+- **query batches, k, filter lists, category lists** take
+  ``bucket_batch`` / ``bucket_list``: chosen by clients, every value an
+  executable to warm.
 
 Pure host math — no jax imports, safe everywhere.
 """
@@ -21,6 +36,9 @@ from typing import Dict, Tuple
 
 #: smallest vocabulary-row bucket: tiny models all share one program
 ROWS_FLOOR = 64
+#: resident tables of more rows than this sit on eighth steps; up to it
+#: on powers of two (2^16 rows are 52 MB at rank 200: 64 us of a scan)
+TABLE_FINE_FROM = 1 << 16
 #: smallest batch bucket (a single query is its own class)
 BATCH_FLOOR = 1
 #: smallest top-k bucket: client-chosen num in 1..16 shares one
@@ -34,8 +52,10 @@ K_FLOOR = 16
 LIST_FLOOR = 1024
 #: smallest bucket of a query's category list
 QUERY_CATEGORIES_FLOOR = 4
-#: fraction of a bucket in use at which the next bucket should be
-#: pre-compiled in the background (before growth forces it on a tick)
+#: how far through a step of its ladder a size stands when the next
+#: bucket should be pre-compiled in the background (before growth forces
+#: it on a tick): on the power-of-two ladder, where a step is the bucket
+#: itself, that is the fraction of the bucket in use
 PROMOTE_AT = 0.75
 
 
@@ -44,8 +64,39 @@ def _next_pow2(n: int) -> int:
 
 
 def bucket_rows(n: int, floor: int = ROWS_FLOOR) -> int:
-    """Row-count bucket covering ``n`` (vocab rows, touched rows)."""
+    """Power-of-two row-count bucket covering ``n`` (touched rows, plan
+    lengths, staged columns). Resident tables: ``bucket_table_rows``."""
     return max(int(floor), _next_pow2(max(int(n), 1)))
+
+
+def bucket_table_rows(n: int) -> int:
+    """Row bucket of a resident factor table of ``n`` live rows: the
+    smallest rung >= ``n`` of the form m * 2^(e-3), m in 8..15 (eight
+    rungs an octave, each a multiple of an eighth of the octave's power
+    of two), and ``bucket_rows`` up to TABLE_FINE_FROM."""
+    b = bucket_rows(n)
+    if b <= TABLE_FINE_FROM:
+        return b
+    step = b >> 4           # an eighth of the octave's lower bound, b / 2
+    return -(-int(n) // step) * step
+
+
+def next_table_bucket(bucket: int) -> int:
+    """The rung above ``bucket`` on the resident-table ladder."""
+    return bucket_table_rows(int(bucket) + 1)
+
+
+def should_promote_table(n: int, bucket: int,
+                         threshold: float = PROMOTE_AT) -> bool:
+    """True when a resident table of ``n`` rows is close enough to
+    ``bucket`` that the next rung's executables should compile now, in
+    the background: the headroom left is at most ``1 - threshold`` of
+    the step to the next rung. On a power-of-two rung the step is the
+    bucket and this is "``threshold`` of the bucket in use"; on the fine
+    rungs, where a table is always over 88.9% full, the step is what
+    growth has to cross before the next compile is needed."""
+    step = next_table_bucket(bucket) - int(bucket)
+    return int(bucket) - int(n) <= (1.0 - threshold) * step
 
 
 def bucket_batch(n: int, floor: int = BATCH_FLOOR) -> int:
@@ -65,31 +116,15 @@ def bucket_list(n: int) -> int:
     return b
 
 
-def bucket_rows_sharded(n: int, shards: int,
-                        floor: int = ROWS_FLOOR) -> int:
-    """Row bucket for a model-axis-sharded table: the pow2 bucket
+def bucket_table_rows_sharded(n: int, shards: int) -> int:
+    """Row bucket for a model-axis-sharded resident table: its rung
     rounded up to a multiple of the shard count, so every shard gets
-    an equal contiguous row slice (pow2 shard counts divide pow2
-    buckets for free; a 3-way mesh axis still gets a legal layout)."""
-    b = bucket_rows(n, floor=floor)
+    an equal contiguous row slice (pow2 shard counts up to 8 divide
+    every rung for free; a 3-way mesh axis still gets a legal layout).
+    The rung above a resident ``bucket`` is this of ``bucket + 1``."""
+    b = bucket_table_rows(n)
     s = max(int(shards), 1)
     return ((b + s - 1) // s) * s
-
-
-def occupancy(n: int, bucket: int) -> float:
-    """How full ``bucket`` is at current size ``n`` (0..1]."""
-    return float(n) / float(bucket) if bucket else 1.0
-
-
-def should_promote(n: int, bucket: int,
-                   threshold: float = PROMOTE_AT) -> bool:
-    """True when ``n`` is close enough to ``bucket`` that the next
-    bucket's executables should compile now, in the background."""
-    return occupancy(n, bucket) >= threshold
-
-
-def next_bucket(bucket: int) -> int:
-    return int(bucket) * 2
 
 
 def bucket_key(dims: Dict[str, int]) -> Tuple[Tuple[str, int], ...]:

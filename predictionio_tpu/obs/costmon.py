@@ -169,6 +169,13 @@ def install(registry=None):
             "Device bytes held by each named residency slot in "
             "utils/device_cache (per-table HBM accounting)",
             _hbm_table_samples)
+        reg.gauge_func(
+            "pio_table_rows",
+            "Rows of each resident factor table at its last upload: "
+            "what=live the model's own, what=bucket the device array's "
+            "(its rung of compile/buckets' table ladder); the difference "
+            "is padding every scan of the table reads",
+            _table_rows_samples)
         _c_dispatch_s = reg.counter(
             "pio_dispatch_seconds_total",
             "Wall time spent in device dispatch calls (the async "
@@ -239,6 +246,13 @@ def _hbm_table_samples():
     sizes = device_cache.resident_sizes()
     return [({"table": name}, float(nbytes))
             for name, nbytes in sorted(sizes.items())]
+
+
+def _table_rows_samples():
+    from predictionio_tpu.utils import device_cache
+    return [({"table": t, "what": what}, float(rows[what]))
+            for t, rows in device_cache.table_rows().items()
+            for what in ("live", "bucket")]
 
 
 @contextmanager

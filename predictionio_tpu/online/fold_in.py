@@ -595,31 +595,32 @@ def fold_in_coo(als: ALSModel, coo: RatingsCOO,
         return als, stats
 
     # -- tables onto the device (once per tick, or not at all) --------------
-    # vocab shape-buckets (ISSUE 9): device tables live at pow2 row
-    # buckets, so vocabulary growth INSIDE a bucket re-uses every traced
-    # program (and, with residency, the device arrays themselves);
-    # promotion to the next bucket is one predictable re-pad + compile
-    from predictionio_tpu.compile.buckets import (bucket_rows,
-                                                  bucket_rows_sharded)
+    # vocab shape-buckets (ISSUE 9): device tables live on the rungs of
+    # the resident-table ladder (the ones the serve dims use), so
+    # vocabulary growth INSIDE a rung re-uses every traced program (and,
+    # with residency, the device arrays themselves); promotion to the
+    # next rung is one predictable re-pad + compile
+    from predictionio_tpu.compile.buckets import (
+        bucket_table_rows, bucket_table_rows_sharded)
     U_tab = V_tab = None
     if sharded:
         stats.sharded = True
         mp = mesh.model_parallelism
         U_tab, V_tab = als.user_factors, als.item_factors
-        n_users_b = max(bucket_rows_sharded(n_users, mp),
+        n_users_b = max(bucket_table_rows_sharded(n_users, mp),
                         U_tab.padded_rows)
-        n_items_b = max(bucket_rows_sharded(n_items, mp),
+        n_items_b = max(bucket_table_rows_sharded(n_items, mp),
                         V_tab.padded_rows)
         # bucket promotion: the one O(table) host reshuffle + upload,
-        # paid per 2x vocabulary growth (steady-state ticks never
-        # enter these branches)
+        # paid per rung crossed (steady-state ticks never enter these
+        # branches)
         if n_users_b > U_tab.padded_rows:
             U_tab = U_tab.grown(als.n_users, n_users_b)
         if n_items_b > V_tab.padded_rows:
             V_tab = V_tab.grown(als.n_items, n_items_b)
     else:
-        n_users_b = bucket_rows(n_users)
-        n_items_b = bucket_rows(n_items)
+        n_users_b = bucket_table_rows(n_users)
+        n_items_b = bucket_table_rows(n_items)
     payload = device_cache.get_resident(
         resident_key, (als.user_factors, als.item_factors),
         sharding=layout_token) if resident_key else None

@@ -1055,12 +1055,12 @@ def batch_predict_dims(model: "ALSModel", batch: int, k: int) -> dict:
     if is_sharded(model.item_factors):
         V = model.item_factors
         i_b = max(V.padded_rows,
-                  B.bucket_rows_sharded(model.n_items, V.n_shards))
+                  B.bucket_table_rows_sharded(model.n_items, V.n_shards))
         return {"i": i_b, "b": B.bucket_batch(batch),
                 "k": min(B.bucket_batch(k, floor=B.K_FLOOR), i_b),
                 "r": model.rank, "s": V.n_shards, "p": p}
-    i_b = B.bucket_rows(model.n_items)
-    return {"u": B.bucket_rows(model.n_users), "i": i_b,
+    i_b = B.bucket_table_rows(model.n_items)
+    return {"u": B.bucket_table_rows(model.n_users), "i": i_b,
             "b": B.bucket_batch(batch),
             "k": min(B.bucket_batch(k, floor=B.K_FLOOR), i_b),
             "r": model.rank, "p": p}
@@ -1089,8 +1089,8 @@ def users_topk_serve_begin(model: "ALSModel", user_ixs, k: int):
     to ids + quantized scores under ``PIO_SERVE_PACK``), so ``finish``
     only waits on an already-in-flight transfer. ``finish`` is safe
     to call from another thread; calling it is the only sync."""
-    from predictionio_tpu.compile import buckets as B
-    from predictionio_tpu.compile.aot import get_aot
+    from predictionio_tpu.compile.aot import (get_aot,
+                                              precompile_next_rung)
     from predictionio_tpu.obs import costmon
     from predictionio_tpu.ops import readback
     from predictionio_tpu.parallel.sharded_table import is_sharded
@@ -1103,8 +1103,8 @@ def users_topk_serve_begin(model: "ALSModel", user_ixs, k: int):
         return _users_topk_serve_sharded_begin(model, user_ixs, dims)
     ixs = np.zeros(dims["b"], dtype=np.int32)
     ixs[:n] = user_ixs
-    U = cached_put_rows(model.user_factors, dims["u"])
-    V = cached_put_rows(model.item_factors, dims["i"])
+    U = cached_put_rows(model.user_factors, dims["u"], table="user")
+    V = cached_put_rows(model.item_factors, dims["i"], table="item")
     k_b, p = dims["k"], dims["p"]
     if p:
         packed = get_aot().dispatch(
@@ -1120,18 +1120,8 @@ def users_topk_serve_begin(model: "ALSModel", user_ixs, k: int):
         # packing off still pays ONE d2h wall: both copies go in
         # flight now, the finish() below only waits
         fetch = readback.begin_fetch(scores, idx)
-    # bucket promotion: a vocab nearing its bucket pre-compiles the
-    # next bucket's executable in the background, BEFORE growth needs it
-    aot = get_aot()
-    if B.should_promote(model.n_items, dims["i"]):
-        aot.ensure(costmon.BATCH_PREDICT,
-                   dict(dims, i=B.next_bucket(dims["i"]),
-                        k=min(k_b, B.next_bucket(dims["i"]))),
-                   background=True)
-    if B.should_promote(model.n_users, dims["u"]):
-        aot.ensure(costmon.BATCH_PREDICT,
-                   dict(dims, u=B.next_bucket(dims["u"])),
-                   background=True)
+    precompile_next_rung(costmon.BATCH_PREDICT, dims, "i", model.n_items)
+    precompile_next_rung(costmon.BATCH_PREDICT, dims, "u", model.n_users)
 
     def finish() -> Tuple[np.ndarray, np.ndarray]:
         scores_h, idx_h = fetch()
@@ -1150,14 +1140,15 @@ def _users_topk_serve_sharded_begin(model: "ALSModel",
     buckets run zero trace / zero compile, exactly like replicated
     ones. Returns a ``finish() -> (scores, idx)`` readback callable
     (the two-phase pipelined contract of users_topk_serve_begin)."""
-    from predictionio_tpu.compile import buckets as B
-    from predictionio_tpu.compile.aot import get_aot
+    from predictionio_tpu.compile.aot import precompile_next_rung
     from predictionio_tpu.obs import costmon
     from predictionio_tpu.ops.topk import batched_sharded_top_k_begin
     from predictionio_tpu.parallel.mesh import model_mesh
     from predictionio_tpu.parallel.sharded_table import table_rows
+    from predictionio_tpu.utils.device_cache import note_table_rows
     V = model.item_factors
     mesh = model_mesh(V.n_shards)
+    note_table_rows("item", model.n_items, dims["i"])
     n = user_ixs.shape[0]
     q = np.zeros((dims["b"], model.rank), dtype=np.float32)
     q[:n] = table_rows(model.user_factors, user_ixs)
@@ -1169,12 +1160,7 @@ def _users_topk_serve_sharded_begin(model: "ALSModel",
     fetch = batched_sharded_top_k_begin(
         V.device(mesh, target_rows=dims["i"]), q, model.n_items,
         dims["k"], mesh, label=costmon.BATCH_PREDICT, dims=dims)
-    if B.should_promote(model.n_items, dims["i"]):
-        nxt = B.bucket_rows_sharded(dims["i"] + 1, V.n_shards,
-                                    floor=B.next_bucket(dims["i"]))
-        get_aot().ensure(costmon.BATCH_PREDICT,
-                         dict(dims, i=nxt, k=min(dims["k"], nxt)),
-                         background=True)
+    precompile_next_rung(costmon.BATCH_PREDICT, dims, "i", model.n_items)
 
     def finish() -> Tuple[np.ndarray, np.ndarray]:
         scores, idx = fetch()

@@ -137,7 +137,7 @@ def masked_topk_dims(n_items: int, rank: int, batch: int, k: int,
     serve dispatch and the deploy/swap warm path."""
     from predictionio_tpu.compile import buckets as B
     from predictionio_tpu.ops import readback
-    i_b = B.bucket_rows(n_items)
+    i_b = B.bucket_table_rows(n_items)
     return {"b": B.bucket_batch(batch), "i": i_b, "r": int(rank),
             "k": min(B.bucket_batch(k, floor=B.K_FLOOR), i_b),
             "fp": int(bool(filter_positive)),
@@ -175,8 +175,8 @@ def masked_top_k_batch_begin(item_table: np.ndarray,
     ``finish() -> (scores, idx)`` which performs the deferred
     device->host readback, so the completion stage can overlap the
     next window's formation."""
-    from predictionio_tpu.compile import buckets as B
-    from predictionio_tpu.compile.aot import get_aot
+    from predictionio_tpu.compile.aot import (get_aot,
+                                              precompile_next_rung)
     from predictionio_tpu.obs import costmon
     from predictionio_tpu.ops import readback
     from predictionio_tpu.parallel.sharded_table import is_sharded
@@ -195,7 +195,7 @@ def masked_top_k_batch_begin(item_table: np.ndarray,
     mp = np.zeros((dims["b"], dims["i"]), dtype=bool)
     mp[:n, :n_items] = masks
     k_eff, p = dims["k"], dims["p"]
-    item_dev = cached_put_rows(item_table, dims["i"])
+    item_dev = cached_put_rows(item_table, dims["i"], table="item")
     if p:
         packed = get_aot().dispatch(
             costmon.BATCH_PREDICT_MASKED, dims,
@@ -210,12 +210,7 @@ def masked_top_k_batch_begin(item_table: np.ndarray,
                 *a, k=k_eff, filter_positive=filter_positive),
             qp, item_dev, mp)
         fetch = readback.begin_fetch(scores, idx)
-    if B.should_promote(n_items, dims["i"]):
-        get_aot().ensure(
-            costmon.BATCH_PREDICT_MASKED,
-            dict(dims, i=B.next_bucket(dims["i"]),
-                 k=min(k_eff, B.next_bucket(dims["i"]))),
-            background=True)
+    precompile_next_rung(costmon.BATCH_PREDICT_MASKED, dims, "i", n_items)
 
     def finish() -> Tuple[np.ndarray, np.ndarray]:
         scores_h, idx_h = fetch()
@@ -239,11 +234,13 @@ def _masked_top_k_batch_sharded_begin(item_table,
     from predictionio_tpu.ops import readback
     from predictionio_tpu.ops.topk import batched_sharded_top_k_begin
     from predictionio_tpu.parallel.mesh import model_mesh
+    from predictionio_tpu.utils.device_cache import note_table_rows
     mesh = model_mesh(item_table.n_shards)
     n_items = item_table.shape[0]
     n = query_vecs.shape[0]
     i_b = max(item_table.padded_rows,
-              B.bucket_rows_sharded(n_items, item_table.n_shards))
+              B.bucket_table_rows_sharded(n_items, item_table.n_shards))
+    note_table_rows("item", n_items, i_b)
     dims = {"b": B.bucket_batch(n), "i": i_b,
             "r": int(query_vecs.shape[1]),
             "k": min(B.bucket_batch(k, floor=B.K_FLOOR), i_b),
@@ -477,7 +474,7 @@ def composed_topk_dims(n_items: int, rank: int, batch: int, k: int,
     serve dispatch and the deploy/swap warm path."""
     from predictionio_tpu.compile import buckets as B
     from predictionio_tpu.ops import readback
-    i_b = B.bucket_rows(n_items)
+    i_b = B.bucket_table_rows(n_items)
     return {"b": B.bucket_batch(batch), "i": i_b, "r": int(rank),
             "k": min(B.bucket_batch(k, floor=B.K_FLOOR), i_b),
             "c": int(c_max),
@@ -528,7 +525,7 @@ class ItemFilterData:
         self.categories = categories
         self.n_items = len(categories)
         from predictionio_tpu.compile import buckets as B
-        self._rows = B.bucket_rows(self.n_items)
+        self._rows = B.bucket_table_rows(self.n_items)
         self.available_bits = pack_available(self._rows, ())
         #: what the bitmap was built from, as the owner names it (the
         #: e-commerce engine: the `$set`'s event id)
@@ -596,8 +593,8 @@ def composed_top_k_batch_begin(item_table: np.ndarray,
     LISTED_WHITE for each) of query j's lists; ``has_white[j]``: the
     query gave a whiteList. Nothing of size [B, I] is built or sent: the
     upload is the category codes and the flat list, a few KB."""
-    from predictionio_tpu.compile import buckets as B
-    from predictionio_tpu.compile.aot import get_aot
+    from predictionio_tpu.compile.aot import (get_aot,
+                                              precompile_next_rung)
     from predictionio_tpu.obs import costmon
     from predictionio_tpu.ops import readback
     from predictionio_tpu.utils.device_cache import (cached_put,
@@ -642,7 +639,7 @@ def composed_top_k_batch_begin(item_table: np.ndarray,
     if not is_cached(bits):
         sent += bits.nbytes
     _filter_h2d_counter().inc(sent)
-    args = (qp, cached_put_rows(item_table, i_b),
+    args = (qp, cached_put_rows(item_table, i_b, table="item"),
             cached_put_rows(filters.categories.ids, i_b), cached_put(bits),
             np.int32(n_items), q_cats, rows, cols, vals, white)
     k_eff, p = dims["k"], dims["p"]
@@ -657,11 +654,8 @@ def composed_top_k_batch_begin(item_table: np.ndarray,
             costmon.BATCH_PREDICT_COMPOSED, dims,
             lambda *a: _composed_masked_topk(*a, k=k_eff), *args)
         fetch = readback.begin_fetch(scores, idx)
-    if B.should_promote(n_items, i_b):
-        get_aot().ensure(
-            costmon.BATCH_PREDICT_COMPOSED,
-            dict(dims, i=B.next_bucket(i_b),
-                 k=min(k_eff, B.next_bucket(i_b))), background=True)
+    precompile_next_rung(costmon.BATCH_PREDICT_COMPOSED, dims, "i",
+                         n_items)
 
     def finish() -> Tuple[np.ndarray, np.ndarray]:
         scores_h, idx_h = fetch()

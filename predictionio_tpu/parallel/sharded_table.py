@@ -139,11 +139,12 @@ class ShardedTable:
         zero-padded to ``padded_rows`` (default: the covering sharded
         vocab bucket). The entry path for converting a replicated model
         to the sharded layout."""
-        from predictionio_tpu.compile.buckets import bucket_rows_sharded
+        from predictionio_tpu.compile.buckets import \
+            bucket_table_rows_sharded
         arr = np.ascontiguousarray(arr, dtype=np.float32)
         n = arr.shape[0]
         target = padded_rows if padded_rows is not None \
-            else bucket_rows_sharded(max(n, 1), n_shards)
+            else bucket_table_rows_sharded(max(n, 1), n_shards)
         if target < n or target % n_shards:
             raise ValueError(
                 f"padded_rows {target} must cover {n} rows and divide "
@@ -262,8 +263,9 @@ class ShardedTable:
     def grown(self, n_rows: int, padded_rows: int) -> "ShardedTable":
         """Re-partition for a bucket promotion (``padded_rows`` grew):
         shard boundaries move, so this is the one O(table) host
-        reshuffle — paid once per 2x vocabulary growth, like the
-        compile the promotion also pays. Single-process only (a
+        reshuffle — paid once per rung of the table ladder (at most
+        12.5% of vocabulary growth from 2^16 rows up, 2x below), like
+        the compile the promotion also pays. Single-process only (a
         follower holding a subset of shards cannot re-partition
         without cross-process data movement — refuse rather than
         silently misattribute rows)."""

@@ -1352,7 +1352,11 @@ class EngineServer:
         try:
             from predictionio_tpu.compile.aot import get_aot
             from predictionio_tpu.compile.cache import cache_status
+            from predictionio_tpu.utils import device_cache
             out["aot"] = get_aot().snapshot()
+            # live rows, bucket rows and the padded share of each
+            # resident factor table: what every scan reads for nothing
+            out["tableRows"] = device_cache.table_rows()
             out["xlaCache"] = cache_status()
         except Exception:
             logger.debug("aot stats unavailable", exc_info=True)

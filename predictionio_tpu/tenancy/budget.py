@@ -81,10 +81,10 @@ def estimate_padded_bytes(models: Sequence[Any]) -> int:
         n, width = t.shape
         itemsize = np.dtype(t.dtype).itemsize
         if is_sharded(t):
-            padded = B.bucket_rows_sharded(n, t.n_shards)
+            padded = B.bucket_table_rows_sharded(n, t.n_shards)
             total += (padded // t.n_shards) * width * itemsize
         else:
-            total += B.bucket_rows(n) * width * itemsize
+            total += B.bucket_table_rows(n) * width * itemsize
     return int(total)
 
 

@@ -195,14 +195,36 @@ def cached_put_padded(arr, sharding, row_multiple: int):
     return dev
 
 
-def cached_put_rows(arr, target_rows: int, sharding=None):
+# table name -> (live rows, bucket rows) of its last bucketed upload:
+# the sample source behind ``pio_table_rows{table,what}``
+_table_rows: Dict[str, Tuple[int, int]] = {}
+
+
+def note_table_rows(table: str, live: int, bucket: int) -> None:
+    """Record what a resident table named ``table`` holds: ``live``
+    rows in a device array of ``bucket`` rows (the rest is padding that
+    every scan of the table reads)."""
+    _table_rows[table] = (int(live), int(bucket))
+
+
+def table_rows() -> "Dict[str, Dict[str, float]]":
+    """table -> {live, bucket, paddedShare}: ``/stats.json``'s
+    ``tableRows`` block and the gauge's samples."""
+    return {t: {"live": live, "bucket": bucket,
+                "paddedShare": (bucket - live) / bucket if bucket else 0.0}
+            for t, (live, bucket) in sorted(_table_rows.items())}
+
+
+def cached_put_rows(arr, target_rows: int, sharding=None,
+                    table: Optional[str] = None):
     """cached_put with dim-0 zero-padded to ``target_rows`` — the
     vocab-bucket upload of the compile plane (ISSUE 9): serving tables
     are uploaded at their shape-bucket size so vocabulary growth inside
     the bucket reuses both the resident device copy AND every compiled
     executable that reads it. Memoized on (array identity, rows,
     sharding); a smaller ``target_rows`` than the array has rows
-    uploads unpadded (callers pass a covering bucket)."""
+    uploads unpadded (callers pass a covering bucket). ``table`` names
+    the upload for ``pio_table_rows`` ("user", "item")."""
     import jax
     import numpy as np
 
@@ -227,6 +249,8 @@ def cached_put_rows(arr, target_rows: int, sharding=None):
     dev = jax.device_put(padded, sharding) if sharding is not None \
         else jax.device_put(padded)
     _record_upload(padded)
+    if table is not None:
+        note_table_rows(table, arr.shape[0], target)
     try:
         ref = weakref.ref(arr, lambda r, k=key: _evict_cache_key(k))
     except TypeError:
@@ -248,6 +272,7 @@ def clear():
         _resident.clear()
         _tenant_keys.clear()
         _tenant_slots.clear()
+        _table_rows.clear()
 
 
 # ---------------------------------------------------------------------------

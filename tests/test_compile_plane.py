@@ -49,10 +49,11 @@ class TestBucketLadder:
             assert B.bucket_rows(n) == 128
 
     def test_promotion_trigger(self):
-        bucket = B.bucket_rows(70)          # 128
-        assert not B.should_promote(70, bucket)
-        assert B.should_promote(int(bucket * B.PROMOTE_AT) + 1, bucket)
-        assert B.next_bucket(bucket) == 256
+        bucket = B.bucket_table_rows(70)    # 128
+        assert not B.should_promote_table(70, bucket)
+        assert B.should_promote_table(int(bucket * B.PROMOTE_AT) + 1,
+                                      bucket)
+        assert B.next_table_bucket(bucket) == 256
 
     def test_bucket_key_and_label_canonical(self):
         k1 = B.bucket_key({"u": 64, "b": 4})
@@ -60,6 +61,95 @@ class TestBucketLadder:
         assert k1 == k2
         from predictionio_tpu.compile.buckets import bucket_label
         assert bucket_label({"u": 64, "b": 4}) == "b4-u64"
+
+
+# ---------------------------------------------------------------------------
+# the resident-table ladder (ISSUE 32): eighth steps from 2^16 rows up
+# ---------------------------------------------------------------------------
+
+#: the benchmark's catalogues: amazonbooks users / items, taobao items /
+#: users, goodreads books / users
+CELL_COUNTS = (8026324, 2330066, 4162024, 987994, 2360650, 876145)
+LADDER_SIZES = sorted(
+    {1, 2, 63, 64, 65, 1000, 32768, 32769, 49152, 65535, 65536, 65537,
+     73728, 73729, 131071, 131072, 131073, 999999, 1 << 20, (1 << 20) + 1,
+     15 << 19, (15 << 19) + 1, (1 << 24) - 1, 1 << 24} | set(CELL_COUNTS))
+
+
+def _octave(bucket: int) -> int:
+    """The power of two at the lower end of ``bucket``'s octave:
+    (2^e, 2^(e+1)] holds the rungs 9..16 eighths of 2^e."""
+    return 1 << ((bucket - 1).bit_length() - 1)
+
+
+class TestTableLadder:
+    @pytest.mark.parametrize("n", LADDER_SIZES)
+    def test_rung_covers_and_is_a_fixed_point(self, n):
+        b = B.bucket_table_rows(n)
+        assert b >= n
+        assert B.bucket_table_rows(b) == b
+        assert B.bucket_table_rows(max(n - 1, 1)) <= b     # monotone
+
+    @pytest.mark.parametrize("n", LADDER_SIZES)
+    def test_padding_and_the_shape_of_a_rung(self, n):
+        b = B.bucket_table_rows(n)
+        if n <= B.TABLE_FINE_FROM:
+            # the old powers of two, floor 64
+            assert b == B.bucket_rows(n)
+            return
+        assert b <= B.bucket_rows(n)
+        assert (b - n) / b <= 0.125 and (b - n) / n <= 0.125
+        eighth = _octave(b) // 8
+        assert b % eighth == 0 and 9 <= b // eighth <= 16
+        for shards in (2, 4, 8):
+            assert b % shards == 0
+            assert B.bucket_table_rows_sharded(n, shards) == b
+        assert b % (8 * 128) == 0                # the (8, 128) tiling
+
+    def test_three_way_axis_still_gets_equal_slices(self):
+        assert B.bucket_table_rows_sharded(70, 3) == 129
+        assert B.bucket_table_rows_sharded(70000, 3) % 3 == 0
+
+    @pytest.mark.parametrize("n", LADDER_SIZES)
+    def test_next_rung(self, n):
+        b = B.bucket_table_rows(n)
+        nxt = B.next_table_bucket(b)
+        assert nxt > b and B.bucket_table_rows(b + 1) == nxt
+        if b < B.TABLE_FINE_FROM:
+            assert nxt == 2 * b
+        else:
+            assert nxt - b <= b // 8               # at most +12.5%
+
+    @pytest.mark.parametrize("bucket", [64 << e for e in range(10)])
+    @pytest.mark.parametrize("fill", [0.5, 0.7, 0.74, 0.75, 0.76, 0.9, 1.0])
+    def test_trigger_is_the_old_one_on_power_of_two_rungs(self, bucket,
+                                                          fill):
+        n = max(int(bucket * fill), bucket // 2 + 1)
+        assert B.bucket_table_rows(n) == bucket
+        # the rule before ISSUE 32: occupancy past PROMOTE_AT
+        assert B.should_promote_table(n, bucket) == \
+            (n / bucket >= B.PROMOTE_AT)
+
+    @pytest.mark.parametrize("n,bucket,fires", [
+        (2330066, 2359296, True),       # amazonbooks items: 29,230 left
+        (4162024, 1 << 22, True),       # taobao items: 32,280 left
+        (8026324, 1 << 23, False),      # amazonbooks users: 362,284 left
+    ])
+    def test_trigger_in_the_cells(self, n, bucket, fires):
+        assert B.bucket_table_rows(n) == bucket
+        assert B.should_promote_table(n, bucket) is fires
+
+    @pytest.mark.parametrize("n", LADDER_SIZES)
+    def test_no_table_sits_permanently_over_the_trigger(self, n):
+        """A table that has just been promoted into a rung is under it:
+        the pre-compile of the rung above waits for growth."""
+        b = B.bucket_table_rows(n)
+        lowest = 1 if b == B.ROWS_FLOOR else \
+            max(m for m in (b // 2, b - _octave(b) // 8)
+                if B.bucket_table_rows(m) < b) + 1
+        assert B.bucket_table_rows(lowest) == b
+        assert not B.should_promote_table(lowest, b)
+        assert B.should_promote_table(b, b)
 
 
 # ---------------------------------------------------------------------------
@@ -192,6 +282,181 @@ class TestServeBuckets:
         assert i[np.isfinite(s)].max() < 37
         assert not np.intersect1d(i[0][np.isfinite(s[0])],
                                   np.arange(10)).size
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 32: a table just over the fine threshold serves the same answers
+# from its eighth-step rung as from its power-of-two bucket
+# ---------------------------------------------------------------------------
+
+#: live rows over TABLE_FINE_FROM: rung 73,728, power of two 131,072
+FINE_N = 70_000
+
+
+def _both_ladders(monkeypatch, run):
+    """``run()`` with every resident table at its power-of-two bucket
+    (the ladder before ISSUE 32), then on the table ladder."""
+    monkeypatch.setenv("PIO_SERVE_PACK", "exact")
+    with monkeypatch.context() as mp:
+        mp.setattr(B, "bucket_table_rows", B.bucket_rows)
+        pow2 = run()
+    return pow2, run()
+
+
+class TestFineRungServes:
+    @pytest.mark.parametrize("ixs", [[3], [3, 50, 99]], ids=["b1", "b4"])
+    def test_users_topk_same_answers(self, monkeypatch, ixs):
+        from predictionio_tpu.ops.als import (batch_predict_dims,
+                                              users_topk_serve)
+        m = _als_model(100, FINE_N, rank=7, seed=5)
+
+        def run():
+            return (batch_predict_dims(m, len(ixs), 10)["i"],
+                    *users_topk_serve(m, ixs, 10))
+        (i0, s0, ix0), (i1, s1, ix1) = _both_ladders(monkeypatch, run)
+        assert (i0, i1) == (131072, 73728)
+        assert np.isfinite(s1).all() and ix1.max() < FINE_N
+        np.testing.assert_array_equal(ix0, ix1)
+        np.testing.assert_array_equal(s0, s1)
+
+    def test_masked_family_same_answers(self, monkeypatch):
+        from predictionio_tpu.ops.similarity import (masked_top_k_batch,
+                                                     masked_topk_dims)
+        rng = np.random.default_rng(6)
+        table = rng.standard_normal((FINE_N, 5)).astype(np.float32)
+        qv = rng.standard_normal((2, 5)).astype(np.float32)
+        masks = rng.random((2, FINE_N)) < 0.7
+
+        def run():
+            return (masked_topk_dims(FINE_N, 5, 2, 10)["i"],
+                    *masked_top_k_batch(table, qv, masks, 10))
+        (i0, s0, ix0), (i1, s1, ix1) = _both_ladders(monkeypatch, run)
+        assert (i0, i1) == (131072, 73728)
+        assert masks[0][ix1[0][np.isfinite(s1[0])]].all()
+        np.testing.assert_array_equal(ix0, ix1)
+        np.testing.assert_array_equal(s0, s1)
+
+    def test_composed_family_same_answers(self, monkeypatch):
+        from predictionio_tpu.ops import similarity as S
+        rng = np.random.default_rng(7)
+        table = rng.standard_normal((FINE_N, 5)).astype(np.float32)
+        qv = rng.standard_normal((2, 5)).astype(np.float32)
+        items = np.arange(FINE_N)
+        cats = S.ItemCategories.from_pairs(
+            FINE_N, items, np.array([f"c{c}" for c in range(7)])[items % 7])
+        gone = rng.choice(FINE_N, 300, replace=False)
+        white = rng.choice(FINE_N, 500, replace=False)
+        unavailable = rng.choice(FINE_N, 700, replace=False)
+
+        def run():
+            filters = S.ItemFilterData(cats)    # its bitmap spans the rung
+            filters.set_unavailable(unavailable)
+            scores, idx = S.composed_top_k_batch_begin(
+                table, qv, filters, [cats.codes_of(["c1", "c4"]), []],
+                [(gone, np.full(gone.size, S.LISTED_OUT)),
+                 (white, np.full(white.size, S.LISTED_WHITE))],
+                [False, True], 10)()
+            return filters.available_bits.size * 32, scores, idx
+        (i0, s0, ix0), (i1, s1, ix1) = _both_ladders(monkeypatch, run)
+        assert (i0, i1) == (131072, 73728)
+        kept = ix1[0][np.isfinite(s1[0])]
+        assert kept.size and np.isin(kept % 7, (1, 4)).all()
+        assert not np.isin(kept, np.concatenate([gone, unavailable])).any()
+        assert np.isin(ix1[1][np.isfinite(s1[1])], white).all()
+        np.testing.assert_array_equal(ix0, ix1)
+        np.testing.assert_array_equal(s0, s1)
+
+    def test_growth_inside_a_rung_is_free_and_crossing_promotes_once(self):
+        """70,000 and 71,000 items share the 73,728-row executable; at
+        72,000 (1,728 rows left of a step of 8,192) the 81,920-row one
+        compiles in the background, once; at 74,000 it is dispatched."""
+        import threading
+        from predictionio_tpu.ops.als import (batch_predict_dims,
+                                              users_topk_serve)
+
+        def compiled():
+            return [b for b in get_aot().snapshot()["bucketsCompiled"].get(
+                costmon.BATCH_PREDICT, []) if "-r9-" in b]
+
+        def settle():
+            for t in threading.enumerate():
+                if t.name.startswith("pio-aot-"):
+                    t.join()
+
+        users_topk_serve(_als_model(80, FINE_N, rank=9), [1, 2], 10)
+        settle()                 # the cold bucket's background adoption
+        assert compiled() == ["b2-i73728-k16-p1-r9-u128"]
+        hits = get_aot().snapshot()["dispatchHits"].get(
+            costmon.BATCH_PREDICT, 0)
+        before = _compile_s()
+        s, i = users_topk_serve(_als_model(80, 71_000, rank=9, seed=1),
+                                [1, 2], 10)
+        settle()
+        assert _compile_s() == before, \
+            "growth inside the rung must compile nothing"
+        assert i.max() < 71_000
+        users_topk_serve(_als_model(80, 72_000, rank=9, seed=2), [1, 2], 10)
+        settle()
+        assert compiled() == ["b2-i73728-k16-p1-r9-u128",
+                              "b2-i81920-k16-p1-r9-u128"]
+        grown = _als_model(80, 74_000, rank=9, seed=3)
+        assert batch_predict_dims(grown, 2, 10)["i"] == 81920
+        before = _compile_s()
+        s, i = users_topk_serve(grown, [1, 2], 10)
+        settle()
+        assert _compile_s() == before, \
+            "the promoted rung was compiled before growth needed it"
+        assert len(compiled()) == 2
+        assert get_aot().snapshot()["dispatchHits"][
+            costmon.BATCH_PREDICT] == hits + 3
+        assert i.max() < 74_000
+
+    def test_fold_tables_sit_on_the_serve_rung(self):
+        from predictionio_tpu.online.fold_in import (FoldInConfig,
+                                                     fold_in_coo)
+        from predictionio_tpu.ops.als import batch_predict_dims
+        from predictionio_tpu.ops.ratings import RatingsCOO
+        from predictionio_tpu.utils import device_cache
+        model = _als_model(90, FINE_N, rank=4)
+        r = np.random.default_rng(8)
+        coo = RatingsCOO(r.integers(0, 90, 400).astype(np.int32),
+                         r.integers(0, FINE_N, 400).astype(np.int32),
+                         r.integers(1, 6, 400).astype(np.float32),
+                         90, FINE_N)
+        out, _ = fold_in_coo(model, coo, np.unique(coo.user_idx[:8]),
+                             np.unique(coo.item_idx[:8]),
+                             FoldInConfig(sweeps=1),
+                             resident_key="cp-fine-rung")
+        try:
+            dims = batch_predict_dims(out, 1, 10)
+            assert (dims["u"], dims["i"]) == (128, 73728)
+            assert device_cache.resident_sizes()["cp-fine-rung"] == \
+                (dims["u"] + dims["i"]) * 4 * 4
+        finally:
+            device_cache.drop_resident("cp-fine-rung")
+
+    def test_gauges_and_stats_name_the_padded_share(self):
+        from predictionio_tpu.obs.metrics import get_registry
+        from predictionio_tpu.ops.als import users_topk_serve
+        from predictionio_tpu.utils import device_cache
+        users_topk_serve(_als_model(100, FINE_N, rank=3, seed=9), [1], 10)
+        rows = device_cache.table_rows()
+        assert rows["item"] == {"live": FINE_N, "bucket": 73728,
+                                "paddedShare": (73728 - FINE_N) / 73728}
+        assert rows["user"]["bucket"] == 128
+        got = {(l["table"], l["what"]): v for l, v in
+               get_registry().get("pio_table_rows").samples()}
+        assert got[("item", "live")] == FINE_N
+        assert got[("item", "bucket")] == 73728
+        s = _real_server(_rec_model(40, 50), canary_fraction=0.0)
+        s.handle_query_batch([{"user": "u1", "num": 3}])
+
+        class _Req:
+            params = {}
+            headers = {}
+
+        assert s._stats(_Req()).body["tableRows"]["item"] == {
+            "live": 50, "bucket": 64, "paddedShare": 14 / 64}
 
 
 # ---------------------------------------------------------------------------

@@ -63,6 +63,33 @@ def test_no_operation_over_the_whole_user_table_at_rank_200(sds, b):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
+def test_the_amazonbooks_tables_fit_at_their_rungs_and_at_the_users_next(sds):
+    """ISSUE 32: the cell's executable at its resident shapes (users 2^23,
+    items 2,359,296 on the table ladder, not 2^22), b 16, against the 15.75
+    GB the compiler allows; and the users' next rung, 9,437,184 rows, which
+    a promotion compiles beside the same item table (the power-of-two
+    ladder's 2^24 rows were 13.4 GB of user table alone and could only
+    fail). Nothing runs: bytes, not times."""
+    import jax.numpy as jnp
+    from predictionio_tpu.compile import buckets as B
+    from predictionio_tpu.ops import als
+    u_b, i_b = B.bucket_table_rows(8026324), B.bucket_table_rows(2330066)
+    assert (u_b, i_b) == (U_ROWS, 2359296)
+    assert B.next_table_bucket(u_b) == 9437184
+
+    def compiled(u_rows):
+        return als._users_topk_b_packed.lower(
+            sds((u_rows, 200), jnp.float32), sds((i_b, 200), jnp.float32),
+            sds((16,), jnp.int32), sds((), jnp.int32), k=K,
+            p=1).compile().memory_analysis()
+    m = compiled(u_b)
+    assert m.argument_size_in_bytes >= 8.59e9
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 15.75e9
+    nxt = compiled(B.next_table_bucket(u_b))
+    assert nxt.argument_size_in_bytes >= 9.43e9
+    assert nxt.argument_size_in_bytes + nxt.temp_size_in_bytes < 15.75e9
+
+
 def _gathered(monkeypatch):
     """The serve executable as it was before PR 26, its rows gathered. A
     function object of its own: JAX keeps traces by function, and a second

@@ -380,6 +380,25 @@ class TestShardedServeParity:
             np.testing.assert_allclose(s_r[row][fr][:12],
                                        s_s[row][fs][:12], atol=1e-5)
 
+    def test_sharded_route_sits_on_the_serve_rung(self, mesh8):
+        """ISSUE 32: over 2^16 rows the sharded table, the sharded serve
+        dims and the replicated serve dims agree on one eighth-step rung,
+        and both routes give the same answers from it."""
+        from predictionio_tpu.ops.als import batch_predict_dims
+        rng = np.random.default_rng(19)
+        model = ALSModel(
+            rng.standard_normal((80, 6)).astype(np.float32),
+            rng.standard_normal((70_000, 6)).astype(np.float32), 6)
+        sharded = _sharded_copy(model)
+        assert sharded.item_factors.padded_rows == 73728
+        assert batch_predict_dims(sharded, 2, 10)["i"] == 73728
+        assert batch_predict_dims(model, 2, 10)["i"] == 73728
+        assert B.bucket_table_rows_sharded(73729, N_SHARDS) == 81920
+        s_r, i_r = users_topk_serve(model, [3, 40], 10)
+        s_s, i_s = users_topk_serve(sharded, [3, 40], 10)
+        np.testing.assert_array_equal(i_r[:, :10], i_s[:, :10])
+        np.testing.assert_allclose(s_r[:, :10], s_s[:, :10], atol=1e-5)
+
     def test_masked_topk_parity(self, mesh8):
         from predictionio_tpu.ops.similarity import masked_top_k_batch
         model, _ = _train(seed=13)
