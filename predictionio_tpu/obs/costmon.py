@@ -102,6 +102,7 @@ _c_pc_misses = None
 _g_flops = None
 _g_bytes = None
 _g_cg_ratio = None
+_g_exchange = None
 _c_dispatch_s = None
 _c_device_s = None
 _c_device_syncs = None
@@ -120,7 +121,7 @@ def install(registry=None):
     global _installed, _c_seconds, _c_hits, _c_misses, _g_flops, \
         _g_bytes, _c_pc_hits, _c_pc_misses, _c_dispatch_s, \
         _c_device_s, _c_device_syncs, _g_occupancy, _g_tenant_occ, \
-        _g_cg_ratio
+        _g_cg_ratio, _g_exchange
     with _lock:
         if _installed:
             return
@@ -153,6 +154,13 @@ def install(registry=None):
             "ran over the iterations their budgets allowed (the kernel "
             "stops a tile whose systems have converged; 1 = every solve "
             "ran to its cap)")
+        _g_exchange = reg.gauge(
+            "pio_als_exchange_bytes",
+            "output bytes of the collectives one run of the last ALS "
+            "half-sweep over row-sharded tables executes, from its "
+            "compiled program (parallel/collective_stats), by side and "
+            "collective kind; absent where tables are not sharded",
+            labelnames=("side", "op"))
         _c_pc_hits = reg.counter(
             "pio_compile_pcache_hits_total",
             "persistent compilation-cache hits (an executable "
@@ -595,6 +603,17 @@ def record_cg_iterations(run: float, budget: float) -> None:
         install()
     if budget > 0:
         _g_cg_ratio.set(run / budget)
+
+
+def record_exchange_bytes(side: str, by_op: dict) -> None:
+    """Bank what a per-chip ALS half-sweep's programs exchange
+    (`ops/als.sweep_exchange`'s {collective: bytes}; its "sent" total is
+    telemetry's alone): one gauge value per collective kind."""
+    if not _installed:
+        install()
+    for op, n_bytes in by_op.items():
+        if op != "sent":
+            _g_exchange.labels(side=side, op=op).set(n_bytes)
 
 
 def analyze_jit(label: str, fn, *args, **kwargs) -> Optional[dict]:

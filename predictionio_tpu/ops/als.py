@@ -10,10 +10,27 @@ Design (ALX-style, PAPERS.md "ALX: Large Scale Matrix Factorization on
 TPUs"): instead of MLlib's factor-block shuffles, both factor tables live in
 HBM; each half-iteration sweeps bucketed [B, K] batches of entities
 (ops/ratings.build_solve_plan), gathering counterpart factors, forming the
-normal equations with batched einsums on the MXU, and solving by batched
-Cholesky. The batch dim B is sharded over the mesh `data` axis; factor
-tables are replicated (or sharded over `model` for tables larger than one
-device's HBM — GSPMD inserts the all-gathers).
+normal equations with batched einsums on the MXU, and solving them (ops/
+solve: the Pallas CG on a TPU, Cholesky on the CPU). How a half-sweep is
+divided follows from the mesh and `factor_sharding` (`_rows_sharded`):
+
+  one device, or tables replicated over a mesh (`factor_sharding`
+  "replicated"): `_solve_sweep`. The batch dim B is sharded over the mesh
+  `data` axis and GSPMD partitions the program.
+
+  tables row-sharded over the mesh `model` axis (`factor_sharding` "model",
+  for tables larger than one device's HBM): `_solve_sweep_per_chip`, one
+  `shard_map` over the half-sweep. Every chip holds a contiguous quarter of
+  each table's rows and a quarter of every batch of the plan
+  (`plan_axes`), and solves its quarter of the systems with the one-chip
+  body. Three exchanges cross the chips in a scan step, and nothing else:
+  the step's indices (all-gather), the counterpart rows each shard owns,
+  in the compute dtype (reduce-scatter over the batch), and the solved
+  float32 rows (all-gather, each shard keeping its own). No chip ever
+  holds a whole table or another chip's plan. What the compiled programs
+  exchange is read from their HLO when telemetry is asked for
+  (`sweep_exchange`, telemetry `exchange_bytes`, gauge
+  `pio_als_exchange_bytes{side, op}`).
 
 Math parity with MLlib 1.3:
   explicit  — ALS-WR: minimize sum (r - x.v)^2 + lambda * (n_u |x|^2 + ...)
@@ -32,6 +49,7 @@ from __future__ import annotations
 import collections
 import functools
 import logging
+import os
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -66,8 +84,9 @@ class ALSConfig:
     # gather costs the same per row whatever a row's bytes or tiling
     # (PERF.md section 6, PR 30: `_gather_pad_rows` is what moves it).
     solver: str = "auto"  # the names ops/solve.resolve_solver takes
-    # auto = VMEM-resident CG Pallas kernel on one TPU, jnp CG on a TPU
-    # mesh, LAPACK cholesky on CPU.
+    # auto = VMEM-resident CG Pallas kernel on one TPU and on each chip of
+    # a TPU mesh over row-sharded tables (`sweep_solver`), jnp CG on a TPU
+    # mesh whose sweep GSPMD partitions, LAPACK cholesky on CPU.
     solver_iters: Optional[int] = None  # cap on the primal CG iterations
     # None = the solver default (48). The Pallas kernel stops a tile of
     # systems once they have converged (ops/solve._cg_kernel) and runs to
@@ -97,9 +116,11 @@ class ALSConfig:
     # buckets, so this removes most of the solve work on both paths.
     factor_sharding: str = "replicated"  # 'replicated' | 'model'
     # 'model' shards factor-table rows over the mesh model axis (tables too
-    # large for one device's HBM); GSPMD inserts the all-gathers the
-    # per-batch index gathers need — the analog of MLlib's factor-block
-    # shuffles, but compiler-scheduled over ICI.
+    # large for one device's HBM) and divides every batch of the plan over
+    # the same chips: each solves its own share of the systems, and the
+    # indices, the counterpart rows and the solved rows cross the chips
+    # (`_solve_batch_per_chip`) — the analog of MLlib's factor-block
+    # shuffles, over ICI.
     keep_sharded: bool = False
     # With factor_sharding='model': return the trained tables as
     # ShardedTable handles (per-shard host slices via
@@ -128,7 +149,9 @@ class ALSConfig:
     # and norm explosion (one tiny reduction + scalar fetch per table),
     # and the last clean iteration is checkpointed as an HBM copy. A
     # breach returns the last-good model instead of NaN factors (or
-    # raises NumericalFault when no iteration completed cleanly).
+    # raises NumericalFault when no iteration completed cleanly, and
+    # always under factor_sharding='model' over several chips, where the
+    # tables are checked and no copy is kept: there is no room for one).
     # PIO_GUARD=off disables at runtime; set False to shave the
     # per-iteration copy + sync off latency-critical benches.
     sentinel_norm_cap: float = 1e4
@@ -218,28 +241,41 @@ def _scatter_rows(factors_out, rows, x):
 
 
 def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
-                 lam, alpha, *, nratings_reg: bool, implicit: bool,
-                 rank: int, compute_dtype: str, solver: str,
-                 dual_solve: str = "auto",
-                 solver_iters: Optional[int] = None,
-                 dual_iters_cap: Optional[int] = None):
+                 lam, alpha, **statics):
     """Solve one [B, K] batch of normal equations and scatter results into
     factors_out. Traced inside `_solve_sweep`'s scan body — gather ->
-    einsum -> solve -> scatter fuse into one XLA program. Explicit batches
-    with K < rank take the dual (Woodbury) K x K route; K is static per
-    batch group, so the choice costs nothing at runtime. Returns the
+    einsum -> solve -> scatter fuse into one XLA program. Returns the
     table and the batch's CG iterations (run, allowed), float32 [2]:
-    `spd_solve`'s count."""
+    `spd_solve`'s count. `statics` are `_solve_gathered`'s."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope("pio.sweep.gather"):
+        Vg = counter_factors[idx]                   # [B, K, R] gather
+        Vc = Vg.astype(jnp.dtype(statics["compute_dtype"]))
+    x, cg = _solve_gathered(Vc, gram, val, mask, lam, alpha, **statics)
+    return _scatter_rows(factors_out, rows, x), cg
+
+
+def _solve_gathered(Vc, gram, val, mask, lam, alpha, *, nratings_reg: bool,
+                    implicit: bool, rank: int, compute_dtype: str,
+                    solver: str, dual_solve: str = "auto",
+                    solver_iters: Optional[int] = None,
+                    dual_iters_cap: Optional[int] = None):
+    """The rows x [B, R] that solve one [B, K] batch of normal equations
+    built from the gathered counterpart rows `Vc` [B, K, R] (in the
+    compute dtype), and the batch's CG iterations. What is local to a chip
+    whoever gathered the rows: one device's `_solve_batch` and each chip of
+    a row-sharded mesh (`_solve_batch_per_chip`) run this same body.
+    Explicit batches with K < rank take the dual (Woodbury) K x K route; K
+    is static per batch group, so the choice costs nothing at runtime."""
     import jax
     import jax.numpy as jnp
 
     from predictionio_tpu.ops.solve import spd_solve
 
     cd = jnp.dtype(compute_dtype)
-    with jax.named_scope("pio.sweep.gather"):
-        Vg = counter_factors[idx]                   # [B, K, R] gather
-        Vc = Vg.astype(cd)
-    K = idx.shape[1]
+    K = Vc.shape[1]
     with jax.named_scope("pio.sweep.gram"):
         eye = jnp.eye(rank, dtype=jnp.float32)
         n = mask.sum(axis=-1)                        # ratings per entity
@@ -261,7 +297,7 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
         with jax.named_scope("pio.sweep.gram"):
             x = jnp.einsum("bkr,bk->br", Vm, z.astype(cd),
                            preferred_element_type=jnp.float32)
-        return _scatter_rows(factors_out, rows, x), cg
+        return x, cg
 
     if implicit:
         with jax.named_scope("pio.sweep.gram"):
@@ -319,7 +355,7 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
                 # x = B^-1 b - B^-1 V^T D^1/2 z = Q ((Q^T b - s) / denom)
                 x = jnp.einsum("bs,rs->br", bq_d - s / denom, gram_q,
                                precision=hi)
-            return _scatter_rows(factors_out, rows, x), cg
+            return x, cg
         with jax.named_scope("pio.sweep.gram"):
             A = G + jnp.einsum("bk,bkr,bks->brs", conf_minus_1.astype(cd),
                                Vc, Vc,
@@ -335,7 +371,7 @@ def _solve_batch(factors_out, counter_factors, gram, rows, idx, val, mask,
     with jax.named_scope("pio.sweep.solve.jnp_cg" if solver == "cg"
                          else "pio.sweep.solve.primal"):
         x, cg = spd_solve(A, b, method=solver, iters=solver_iters)
-    return _scatter_rows(factors_out, rows, x), cg
+    return x, cg
 
 
 def _solve_sweep_impl(factors_out, counter_factors, gram, groups, lam,
@@ -382,6 +418,155 @@ _solve_sweep = __import__("jax").jit(
     _solve_sweep_impl, static_argnames=_SWEEP_STATICS, donate_argnums=(0,))
 
 
+# -- the half-sweep over row-sharded tables: one program a chip ------------
+
+def _axis_size(mesh, axes) -> int:
+    """How many ways the mesh axes `axes` (a name, a tuple of names or
+    None) divide an array dimension."""
+    if axes is None:
+        return 1
+    names = (axes,) if isinstance(axes, str) else tuple(axes)
+    n = 1
+    for name in names:
+        n *= mesh.shape[name]
+    return n
+
+
+def sweep_shards(table, device_groups):
+    """(table shards, batch shards): how many ways a half-sweep's table
+    rows and its batch dimension ARE divided over the chips, read off the
+    arrays (telemetry's `table_shards` / `batch_shards`; nothing is
+    decided from it). 1 for anything that is not a NamedSharding on a mesh
+    (one device, a host array)."""
+    import jax
+
+    def ways(x, dim):
+        sh = getattr(x, "sharding", None)
+        if not isinstance(sh, jax.sharding.NamedSharding):
+            return 1
+        return _axis_size(sh.mesh, (tuple(sh.spec) + (None,) * 2)[dim])
+
+    return (ways(table, 0),
+            ways(device_groups[0][0], 1) if device_groups else 1)
+
+
+def _solve_batch_per_chip(f_local, counter_local, gram, rows, idx, val,
+                          mask, lam, alpha, *, table_axis: str, batch_axes,
+                          **statics):
+    """One scan step of a half-sweep as ONE chip of a row-sharded mesh runs
+    it (the ALX arrangement, PAPERS.md arXiv:2112.02194): this chip holds
+    `f_local` / `counter_local`, its contiguous quarter of each table's
+    rows, and `rows` / `idx` / `val` / `mask`, its quarter [B/n, ...] of
+    the step's systems. Three exchanges cross the chips, and nothing else:
+
+      indices  the step's counterpart indices (and the systems' row ids)
+               all-gathered over the table axis: every shard learns which
+               of its rows the step needs;
+      rows     each shard gathers the rows it owns (zeros elsewhere) for
+               all the systems of the step, in the compute dtype, and the
+               block is reduce-scattered over the batch dimension: a chip
+               receives the [B/n, K, R] rows of its own systems, each the
+               sum of one owner's row and zeros, so exact in any dtype;
+      solved   the solved [B/n, R] float32 rows all-gathered, each shard
+               keeping the rows it owns.
+
+    Between `rows` and `solved` the chip runs `_solve_gathered` on local
+    operands: the Gram, the solver routes and their Pallas kernels are the
+    one-chip ones, on B/n systems."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    cd = jnp.dtype(statics["compute_dtype"])
+    shard = lax.axis_index(table_axis)
+    with jax.named_scope("pio.sweep.exchange.indices"):
+        idx_all = lax.all_gather(idx, table_axis, axis=0, tiled=True)
+        rows_all = lax.all_gather(rows, batch_axes, axis=0, tiled=True)
+    with jax.named_scope("pio.sweep.gather"):
+        n_counter = counter_local.shape[0]
+        local = idx_all - shard * n_counter
+        mine = (local >= 0) & (local < n_counter)
+        Vg = counter_local[jnp.where(mine, local, 0)].astype(cd)
+        Vg = jnp.where(mine[..., None], Vg, jnp.zeros((), cd))
+    with jax.named_scope("pio.sweep.exchange.rows"):
+        Vc = lax.psum_scatter(Vg, table_axis, scatter_dimension=0,
+                              tiled=True)           # [B/n, K, R]
+    x, cg = _solve_gathered(Vc, gram, val, mask, lam, alpha, **statics)
+    with jax.named_scope("pio.sweep.exchange.solved"):
+        x_all = lax.all_gather(x.astype(f_local.dtype), batch_axes, axis=0,
+                               tiled=True)          # [B, R]
+    with jax.named_scope("pio.sweep.scatter"):
+        n_out = f_local.shape[0]
+        at = rows_all - shard * n_out
+        # padding systems (row -1) and other shards' rows: out of range,
+        # so dropped
+        at = jnp.where((rows_all >= 0) & (at >= 0) & (at < n_out), at, n_out)
+        return f_local.at[at].set(x_all, mode="drop"), cg
+
+
+def _solve_sweep_per_chip_impl(factors_out, counter_factors, gram, groups,
+                               lam, alpha, *, mesh, table_axis: str,
+                               batch_axes, **statics):
+    """`_solve_sweep_impl` written per chip: one `shard_map` over the whole
+    half-sweep, its scans inside, each step `_solve_batch_per_chip`. The
+    tables go in and out sharded on their rows, the batch groups sharded on
+    the batch dimension, the shared Gram and the scalars whole. The CG
+    iterations returned are the chips' summed."""
+    import jax
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from predictionio_tpu.ops.solve import no_cg_iterations
+
+    def per_chip(f_local, counter_local, gram, groups, lam, alpha):
+        # the gathers read a copy of the counterpart shard in the compute
+        # dtype, as one chip's do; made here once for every scan (left to
+        # the compiler, each scan group hoists a copy of its own: five of
+        # 2.7 GB in the item half-sweep of rec-amazon14-all-r200)
+        with jax.named_scope("pio.sweep.gather"):
+            counter_local = counter_local.astype(
+                statics["compute_dtype"])
+
+        def body(carry, batch):
+            f, cg = carry
+            rows, idx, val, mask = batch
+            f, cg_batch = _solve_batch_per_chip(
+                f, counter_local, gram, rows, idx, val, mask, lam, alpha,
+                table_axis=table_axis, batch_axes=batch_axes, **statics)
+            return (f, cg + cg_batch), None
+
+        carry = (f_local, no_cg_iterations())
+        for group in groups:
+            # one group after the other, as the table they carry says: a
+            # group of one step is no loop but straight-line code, and the
+            # compiler's scheduler starts the gathers and exchanges of
+            # dozens of them at once (the 81 groups of an item side: 19 GB)
+            carry, group = lax.optimization_barrier((carry, group))
+            carry, _ = lax.scan(body, carry, group)
+        f_local, cg = carry
+        return f_local, lax.psum(cg, batch_axes)
+
+    table = P(table_axis, None)
+    batch = tuple((P(None, batch_axes), P(None, batch_axes, None),
+                   P(None, batch_axes, None), P(None, batch_axes, None))
+                  for _ in groups)
+    whole = jax.tree_util.tree_map(lambda _: P(), gram)
+    return jax.shard_map(
+        per_chip, mesh=mesh,
+        in_specs=(table, table, whole, batch, P(), P()),
+        out_specs=(table, P()), check_vma=False)(
+            factors_out, counter_factors, gram, groups, lam, alpha)
+
+
+#: `_solve_sweep` for tables row-sharded over a mesh, the batches divided
+#: over the same chips: statics beside `_SWEEP_STATICS` are the jax mesh
+#: and the axis names (`_sweep_statics`).
+_solve_sweep_per_chip = __import__("jax").jit(
+    _solve_sweep_per_chip_impl,
+    static_argnames=_SWEEP_STATICS + ("mesh", "table_axis", "batch_axes"),
+    donate_argnums=(0,))
+
+
 def _live_gram(factors, n_live: Optional[int]):
     """Y^T Y over the table's first `n_live` rows (None: all of them). A
     training table ends in the scatter's dummy row, which is no entity:
@@ -422,17 +607,57 @@ _gram_eig = __import__("jax").jit(_gram_eig_impl,
 # Training driver
 # ---------------------------------------------------------------------------
 
+def table_rows(n: int, row_multiple: int = 1) -> int:
+    """Rows of a training table of `n` entities: at least one trailing
+    dummy row (the scatter target for padding), the total rounded up so
+    that a model-axis sharding over `row_multiple` shards divides it."""
+    return ((n + 1 + row_multiple - 1) // row_multiple) * row_multiple
+
+
+#: Rows of one random stream of `_init_factors`: a table of up to this many
+#: rows is one stream (what it always was); a larger one is a stream a
+#: block, drawn by as many threads as the host has cores (one stream fills
+#: 30M x 200 in minutes: rec-amazon14-all-r200).
+_INIT_BLOCK_ROWS = 1 << 20
+
+
 def _init_factors(n: int, rank: int, seed: int, salt: int,
                   row_multiple: int = 1) -> np.ndarray:
     # MLlib seeds factors with abs(normal)/sqrt(rank) per block; we use a
     # deterministic numpy RNG — scale keeps initial predictions O(1).
-    # At least one trailing dummy row is allocated (the scatter target for
-    # padding); total rows are rounded up so a model-axis sharding divides.
-    rows = n + 1
-    rows = ((rows + row_multiple - 1) // row_multiple) * row_multiple
-    rng = np.random.default_rng(seed * 2654435761 % (2 ** 31) + salt)
-    f = rng.standard_normal((rows, rank), dtype=np.float32)
-    return np.abs(f) / np.sqrt(rank)
+    # float32 [rows, rank]; the same whatever the host's cores.
+    rows = table_rows(n, row_multiple)
+    stream = seed * 2654435761 % (2 ** 31) + salt
+    f = np.empty((rows, rank), dtype=np.float32)
+
+    def fill(block: int) -> None:
+        part = f[block * _INIT_BLOCK_ROWS:(block + 1) * _INIT_BLOCK_ROWS]
+        rng = np.random.default_rng(stream if block == 0
+                                    else [stream, block])
+        rng.standard_normal(dtype=np.float32, out=part)
+        np.abs(part, out=part)
+        np.divide(part, np.sqrt(rank), out=part)    # in float64, as ever
+
+    blocks = range(-(-rows // _INIT_BLOCK_ROWS))
+    if len(blocks) == 1:
+        fill(0)
+    else:
+        from concurrent.futures import ThreadPoolExecutor
+        with ThreadPoolExecutor(min(len(blocks), os.cpu_count() or 1)) as ex:
+            list(ex.map(fill, blocks))
+    return f
+
+
+def sweep_solver(method: str, mesh: MeshContext,
+                 factor_sharding: str = "replicated") -> str:
+    """`ops/solve.resolve_solver` for a half-sweep on `mesh`: where the
+    sweep is written per chip (row-sharded tables, `plan_axes`) the solver
+    sees one chip's local operands, so "auto" is the one-chip answer
+    (`cg_pallas` on a TPU); a replicated mesh's sweep is GSPMD's to
+    partition, which `pallas_call` cannot take."""
+    from predictionio_tpu.ops.solve import resolve_solver
+    return resolve_solver(method, 1 if _rows_sharded(mesh, factor_sharding)
+                          else mesh.n_devices)
 
 
 def resolve_sweep_chunk(chunk: int, n_devices: int = 1) -> int:
@@ -460,37 +685,68 @@ def resolve_sweep_chunk(chunk: int, n_devices: int = 1) -> int:
 _GATHER_TILE, _GATHER_STEP_256, _GATHER_MIN_RANK = 1024, (128, 704), 64
 
 
-def _gather_pad_rows(b: int, k: int) -> int:
+def _gather_pad_rows(b: int, k: int, multiple: int = 1) -> int:
     """How many padding systems (row -1, mask 0: what a plan pads its own
     batches with) to append to a [b, k] batch so that its gather of
     (b + extra) * k rows gets the compiler's 256-row step
     (`_GATHER_STEP_256`): the fewest, at most b // 32 (3% more systems);
     0 where the count lies there already or none that few does it (a few
-    of the longest rungs, b < 32 or k a multiple of 1,024)."""
+    of the longest rungs, b < 32 or k a multiple of 1,024). Only multiples
+    of `multiple` are tried: the chips a batch is divided over."""
     lo, hi = _GATHER_STEP_256
-    for extra in range(b // 32 + 1):
+    for extra in range(0, b // 32 + 1, multiple):
         if lo <= (b + extra) * k % _GATHER_TILE <= hi:
             return extra
     return 0
 
 
-def _gather_layout(mesh: MeshContext, rank: Optional[int] = None) -> str:
+def _rows_sharded(mesh: MeshContext, factor_sharding: str) -> bool:
+    """True where `als_train` shards the tables' rows over more than one
+    chip: "model" on a mesh whose model axis is wider than 1. The
+    half-sweep is then the per-chip one."""
+    return factor_sharding == "model" and mesh.model_parallelism > 1
+
+
+def plan_axes(mesh: MeshContext, factor_sharding: str = "replicated"):
+    """The mesh axes a plan's batch dimension is divided over: the data
+    axis; and with row-sharded tables the model axis too, so that every
+    chip that holds a quarter of the rows also holds, and solves, a
+    quarter of every batch."""
+    if _rows_sharded(mesh, factor_sharding):
+        return (mesh.DATA_AXIS, mesh.MODEL_AXIS)
+    return mesh.DATA_AXIS
+
+
+def batch_shards(mesh: MeshContext, factor_sharding: str = "replicated"
+                 ) -> int:
+    """How many ways `plan_axes` divides a batch: what a plan's batch
+    sizes have to be a multiple of (`plan_for_*`'s `batch_multiple`)."""
+    return _axis_size(mesh.mesh, plan_axes(mesh, factor_sharding))
+
+
+def _gather_layout(mesh: MeshContext, rank: Optional[int] = None,
+                   factor_sharding: str = "replicated") -> str:
     """How the uploaded batches stand for the half-sweeps' gathers, by what
-    the program can see: "rows+pad256" on a single TPU device, where
-    `_upload_plan` pads each batch group by `_gather_pad_rows` systems;
-    "rows" anywhere else: another backend's gather has no such step, a
-    mesh shards the batch dimension, which has to stay divisible, and
+    the program can see: "rows+pad256" where a TPU gathers a step's rows in
+    a program of its own, which is a single TPU device and each chip of a
+    TPU mesh over row-sharded tables (`_solve_batch_per_chip`);
+    `_upload_plan` then pads each batch group by `_gather_pad_rows`
+    systems. "rows" anywhere else: another backend's gather has no such
+    step, GSPMD partitions a replicated mesh's gather as it likes, and
     tables of a known `rank` under `_GATHER_MIN_RANK` gain nothing."""
-    one_tpu = (mesh.n_devices == 1
-               and mesh.mesh.devices.flat[0].platform == "tpu")
+    tpu = mesh.mesh.devices.flat[0].platform == "tpu"
+    own_gather = mesh.n_devices == 1 or _rows_sharded(mesh, factor_sharding)
     wide = rank is None or rank >= _GATHER_MIN_RANK
-    return "rows+pad256" if one_tpu and wide else "rows"
+    return "rows+pad256" if tpu and own_gather and wide else "rows"
 
 
 def _upload_plan(mesh: MeshContext, plan: SolvePlan, chunk: int = 1,
-                 rank: Optional[int] = None):
+                 rank: Optional[int] = None,
+                 factor_sharding: str = "replicated"):
     """Stack same-shape batches into [N, B(, K)] groups and upload each
-    group once, sharded on the batch dim (dim 1) over the mesh data axis.
+    group once, sharded on the batch dim (dim 1) over the mesh data axis
+    (and the model axis under `factor_sharding` "model": `plan_axes`; the
+    plan's batches are then multiples of `batch_shards`).
     The index/rating/mask tensors are constant across iterations, so they
     stay resident in HBM for the whole train (re-uploading per sweep would
     put ~NNZ*12B on the host<->device link every iteration). Stacking is
@@ -504,15 +760,17 @@ def _upload_plan(mesh: MeshContext, plan: SolvePlan, chunk: int = 1,
     chunk becomes its own group. On a single TPU device each group is
     then padded by a few systems that solve nothing, so that its gather
     runs at the compiler's faster step (`_gather_pad_rows`;
-    `_gather_layout` says when, from the mesh and the tables' `rank`
-    where the caller gives it)."""
+    `_gather_layout` says when, from the mesh, the tables' `rank` where
+    the caller gives it, and their sharding)."""
     with TRACER.region("train.upload"):
-        return _upload_plan_now(mesh, plan, chunk, rank)
+        return _upload_plan_now(mesh, plan, chunk, rank, factor_sharding)
 
 
 def _upload_plan_now(mesh: MeshContext, plan: SolvePlan, chunk: int,
-                     rank: Optional[int]):
-    pad = _gather_layout(mesh, rank) != "rows"
+                     rank: Optional[int], factor_sharding: str):
+    pad = _gather_layout(mesh, rank, factor_sharding) != "rows"
+    axes = plan_axes(mesh, factor_sharding)
+    shards = batch_shards(mesh, factor_sharding)
     by_shape = {}
     for b in plan.batches:
         by_shape.setdefault(b.shape, []).append(b)
@@ -538,9 +796,10 @@ def _upload_plan_now(mesh: MeshContext, plan: SolvePlan, chunk: int,
                                     for x in (rows, idx, val, mask)))
         for tensors in chunks:
             b, k = tensors[1].shape[1:]
-            padded_b = b + (_gather_pad_rows(b, k) if pad else 0)
+            padded_b = b + (_gather_pad_rows(b, k, shards) if pad else 0)
             groups.append(tuple(
-                mesh.put_stacked(mesh.pad_to_multiple(x, 1, padded_b, fill)[0])
+                mesh.put_stacked(
+                    mesh.pad_to_multiple(x, 1, padded_b, fill)[0], axes)
                 for x, fill in zip(tensors, (-1, 0, 0, 0))))
     # host->device transfer accounting (obs.jaxmon): the plan upload is
     # the largest per-train / per-fold-in host->device transfer
@@ -594,14 +853,37 @@ def last_cg_iterations() -> Optional[Tuple[float, float]]:
     return float(run), float(allowed)
 
 
+def _sweep_statics(cfg: ALSConfig, mesh: Optional[MeshContext]) -> dict:
+    """The static arguments of `cfg`'s half-sweep program: `_SWEEP_STATICS`,
+    and with them the jax mesh and the axis names where the sweep is the
+    per-chip one (`_rows_sharded`, the one rule; `mesh` None: never)."""
+    statics = dict(
+        nratings_reg=(cfg.lambda_scaling == "nratings"),
+        implicit=cfg.implicit_prefs, rank=cfg.rank,
+        compute_dtype=cfg.compute_dtype, solver=cfg.solver,
+        dual_solve=cfg.dual_solve, solver_iters=cfg.solver_iters,
+        dual_iters_cap=cfg.dual_iters_cap)
+    if mesh is not None and _rows_sharded(mesh, cfg.factor_sharding):
+        statics.update(mesh=mesh.mesh, table_axis=mesh.MODEL_AXIS,
+                       batch_axes=plan_axes(mesh, cfg.factor_sharding))
+    return statics
+
+
 def _run_side(device_groups, factors, counter_factors, cfg: ALSConfig,
-              gram, lam=None, alpha=None, side: Optional[str] = None):
+              gram, lam=None, alpha=None, side: Optional[str] = None,
+              mesh: Optional[MeshContext] = None):
     """One half-iteration: solve every batch of one side, in one dispatch
     (explicit) or a few (`_sweep_programs`). `lam`/`alpha` should be
     device-resident scalars (uploaded once per train); numpy fallbacks
     keep ad-hoc callers working. `side` ("user"/"item") only labels the
     `pio.train.half_sweep` span. Returns the table; the programs' counts
-    of CG iterations stay on the device for `last_cg_iterations`."""
+    of CG iterations stay on the device for `last_cg_iterations`.
+
+    `mesh` is the mesh the caller placed the tables and `_upload_plan`ed
+    the batches on: where that divided both over the model axis
+    (`_rows_sharded`) the program is `_solve_sweep_per_chip`. Without it
+    (one device, the online fold) it is `_solve_sweep`, GSPMD's to
+    partition over whatever shardings the operands carry."""
     if lam is None:
         lam = np.float32(cfg.lam)
     if alpha is None:
@@ -610,20 +892,55 @@ def _run_side(device_groups, factors, counter_factors, cfg: ALSConfig,
     # tick keep the fold's label; bare train sweeps book as als_sweep
     from predictionio_tpu.obs import costmon
     attrs = {"side": side} if side else {}
+    statics = _sweep_statics(cfg, mesh)
     with TRACER.region("train.half_sweep", **attrs), \
             costmon.executable(costmon.ALS_SWEEP, defer_to_outer=True):
         counts = []
         for groups in _sweep_programs(device_groups, cfg.implicit_prefs):
-            factors, cg = _solve_sweep(
-                factors, counter_factors, gram, groups, lam, alpha,
-                nratings_reg=(cfg.lambda_scaling == "nratings"),
-                implicit=cfg.implicit_prefs, rank=cfg.rank,
-                compute_dtype=cfg.compute_dtype, solver=cfg.solver,
-                dual_solve=cfg.dual_solve, solver_iters=cfg.solver_iters,
-                dual_iters_cap=cfg.dual_iters_cap)
+            args = (factors, counter_factors, gram, groups, lam, alpha)
+            if "mesh" in statics:
+                factors, cg = _solve_sweep_per_chip(*args, **statics)
+            else:
+                factors, cg = _solve_sweep(*args, **statics)
             counts.append(cg)
         _cg_iters_log.append(counts)
         return factors
+
+
+def sweep_exchange(mesh: MeshContext, device_groups, factors,
+                   counter_factors, cfg: ALSConfig, gram=None) -> dict:
+    """{collective: bytes} of what one half-sweep of these operands
+    exchanges between the chips: the output bytes of every collective its
+    compiled programs run, each counted as often as its scan runs it
+    (`parallel/collective_stats.executed_collective_stats`), and under
+    "sent" what one chip sends for them by the ring model (`sent_bytes`).
+    Empty where the sweep is not the per-chip one. For telemetry, after
+    the timed path: each program is lowered and compiled once more from
+    its operands' shapes (nothing is read or donated), which the
+    persistent compile cache serves where it is on."""
+    import jax
+
+    from predictionio_tpu.parallel.collective_stats import (
+        executed_collective_stats, merged_stats, sent_bytes)
+    statics = _sweep_statics(cfg, mesh)
+    if "mesh" not in statics:
+        return {}
+
+    def shaped(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding), tree)
+
+    scalar = jax.ShapeDtypeStruct((), np.float32,
+                                  sharding=mesh.replicated())
+    stats = merged_stats(
+        executed_collective_stats(_solve_sweep_per_chip.lower(
+            *shaped((factors, counter_factors, gram, groups)), scalar,
+            scalar, **statics).compile())
+        for groups in _sweep_programs(device_groups, cfg.implicit_prefs))
+    out = {op: ent["bytes"] for op, ent in stats.items() if op != "total"}
+    out["sent"] = sent_bytes(stats, batch_shards(mesh, cfg.factor_sharding))
+    return out
 
 
 def _side_gram(cfg: ALSConfig, table, n_live: int, side: str):
@@ -664,12 +981,11 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
 
     import jax
 
-    from predictionio_tpu.ops.solve import resolve_solver
     mesh = mesh or current_mesh()
     t0 = _time.perf_counter()
     cfg = dataclasses.replace(
-        cfg, solver=resolve_solver(cfg.solver, mesh.n_devices))
-    dp = mesh.data_parallelism
+        cfg, solver=sweep_solver(cfg.solver, mesh, cfg.factor_sharding))
+    dp = batch_shards(mesh, cfg.factor_sharding)
     user_plan = plan_for_users(ratings, work_budget=cfg.work_budget,
                                batch_multiple=dp,
                                bucket_ratio=cfg.bucket_ratio)
@@ -697,17 +1013,22 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
     fdt = np.dtype(cfg.factor_dtype) if cfg.factor_dtype != "bfloat16" \
         else __import__("jax").numpy.bfloat16
     U = put_factors(_init_factors(ratings.n_users, cfg.rank, cfg.seed, 1,
-                                  row_multiple).astype(fdt))
+                                  row_multiple).astype(fdt, copy=False))
     V = put_factors(_init_factors(ratings.n_items, cfg.rank, cfg.seed, 2,
-                                  row_multiple).astype(fdt))
+                                  row_multiple).astype(fdt, copy=False))
     chunk = resolve_sweep_chunk(cfg.sweep_chunk, mesh.n_devices)
+    user_batches = _upload_plan(mesh, user_plan, chunk, cfg.rank,
+                                cfg.factor_sharding)
+    item_batches = _upload_plan(mesh, item_plan, chunk, cfg.rank,
+                                cfg.factor_sharding)
     if telemetry is not None:
+        n_table, n_batch = sweep_shards(U, user_batches)
         telemetry.update(solver=cfg.solver,
                          compute_dtype=cfg.compute_dtype,
                          sweep_chunk=chunk, n_devices=mesh.n_devices,
-                         gather_layout=_gather_layout(mesh, cfg.rank))
-    user_batches = _upload_plan(mesh, user_plan, chunk, cfg.rank)
-    item_batches = _upload_plan(mesh, item_plan, chunk, cfg.rank)
+                         table_shards=n_table, batch_shards=n_batch,
+                         gather_layout=_gather_layout(
+                             mesh, cfg.rank, cfg.factor_sharding))
     # hyperparameters ride along as device-resident scalars: no per-call
     # host uploads, and sweeping lam/alpha (evaluation tuning) does not
     # recompile the sweep program
@@ -725,9 +1046,15 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
         telemetry["upload_s"] = _time.perf_counter() - t0
         t0 = _time.perf_counter()
     # train-sweep sentinel (ISSUE 5): per-iteration finite/norm check +
-    # a checkpointed last-good iteration (HBM copies, never host fetch)
+    # a checkpointed last-good iteration (HBM copies, never host fetch).
+    # Row-sharded tables are checked and not copied: they are sharded
+    # because no chip holds them, and a second pair has no room beside a
+    # half-sweep's temporaries (rec-amazon14-all-r200: 6.1 GB of tables a
+    # chip, 6.6 of temporaries, 15.75 in all). A breach then raises at
+    # whatever iteration: nothing is published, and nothing is rolled back.
     sentinel = None
     last_good = None
+    keep_last_good = not _rows_sharded(mesh, cfg.factor_sharding)
     if cfg.sentinel:
         from predictionio_tpu.guard.sentinels import (SweepSentinel,
                                                       device_copy,
@@ -747,8 +1074,11 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
                      or sentinel.check_table(V,
                                              f"iteration {it} item table"))
             if fault is None:
-                # copies survive the next iteration's donated sweep
-                last_good = (device_copy(U), device_copy(V))
+                if keep_last_good:
+                    # copies survive the next iteration's donated sweep;
+                    # the older pair leaves first: never three pairs
+                    last_good = None
+                    last_good = (device_copy(U), device_copy(V))
                 return True
         if last_good is None:
             raise fault
@@ -766,10 +1096,10 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
     for it in range(cfg.iterations):
         gram_v = _side_gram(cfg, V, ratings.n_items, "item")
         U = _run_side(user_batches, U, V, cfg, gram_v, lam_dev,
-                      alpha_dev, side="user")
+                      alpha_dev, side="user", mesh=mesh)
         gram_u = _side_gram(cfg, U, ratings.n_users, "user")
         V = _run_side(item_batches, V, U, cfg, gram_u, lam_dev,
-                      alpha_dev, side="item")
+                      alpha_dev, side="item", mesh=mesh)
         if not _checked(it):
             break
         _first_iteration_done(it)
@@ -785,6 +1115,19 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
                                      - telemetry["compile_s"])
         telemetry["s_per_iter"] = (telemetry["iters_s"]
                                    / max(cfg.iterations, 1))
+        # what a half-sweep of each side exchanges between the chips
+        # (none where no sweep ran per chip); outside every phase's time
+        exchanged = {}
+        if cfg.iterations > 0 and _rows_sharded(mesh, cfg.factor_sharding):
+            from predictionio_tpu.obs import costmon
+            exchanged = {
+                "user": sweep_exchange(mesh, user_batches, U, V, cfg,
+                                       gram_v),
+                "item": sweep_exchange(mesh, item_batches, V, U, cfg,
+                                       gram_u)}
+            for side, by_op in exchanged.items():
+                costmon.record_exchange_bytes(side, by_op)
+        telemetry["exchange_bytes"] = exchanged
         t0 = _time.perf_counter()
     with TRACER.region("train.fetch"):
         _fetch_cg_iterations(telemetry)

@@ -11,9 +11,13 @@ precision in a bounded number of iterations. One path per platform
             for every iteration (HBM reads A exactly once); a tile stops
             when its systems have converged, under an iteration cap
             (`_cg_kernel`; PERF.md, PR 28, has the chip's timings).
-  TPU mesh  `cg_solve`: the same iteration in jnp, where pallas_call
-            cannot take GSPMD-sharded operands; also what ops/als gives
-            systems under 32 wide on one TPU.
+            Each chip of a mesh over row-sharded tables runs it too: the
+            half-sweep is written per chip there (ops/als
+            `_solve_sweep_per_chip`) and the solver sees local operands.
+  TPU mesh  `cg_solve`: the same iteration in jnp, for a sweep that GSPMD
+            partitions (replicated tables, batches over the data axis),
+            where pallas_call cannot take sharded operands; also what
+            ops/als gives systems under 32 wide on one TPU.
   CPU       `cholesky_solve`: LAPACK-style factorize-and-substitute, and
             the tests' numerical reference.
 
@@ -224,10 +228,12 @@ _SOLVERS = ("auto", "cholesky", "cg", "cg_pallas")
 def resolve_solver(method: str, n_devices: int = 1) -> str:
     """The one place that knows the solver names (`_SOLVERS`): any other
     is a ValueError here, before a plan is built or a program traced.
-    'auto' -> the platform's method: CG on TPU (Pallas single-device; the
-    jnp formulation under GSPMD meshes, where pallas_call can't consume
-    sharded operands), cholesky on CPU/GPU (LAPACK/cuSOLVER are fine
-    there)."""
+    'auto' -> the platform's method: CG on TPU (Pallas where the solver
+    sees one device's operands, `n_devices` 1: a single device, or a chip
+    of ops/als's per-chip sweep, `sweep_solver`; the jnp formulation where
+    GSPMD partitions the sweep over `n_devices`, since pallas_call can't
+    consume sharded operands), cholesky on CPU/GPU (LAPACK/cuSOLVER are
+    fine there)."""
     if method not in _SOLVERS:
         raise ValueError(f"unknown solver {method!r}: one of "
                          + " | ".join(_SOLVERS))

@@ -216,13 +216,16 @@ class MeshContext:
     def put_replicated(self, x):
         return self.put(x, self.replicated())
 
-    def put_stacked(self, x):
-        """Host array -> device array sharded on dim 1 over the data axis:
-        the layout of stacked same-shape batch groups [N, B, ...] that a
-        `lax.scan` consumes along dim 0, each slice staying data-sharded."""
+    def put_stacked(self, x, axes=None):
+        """Host array -> device array sharded on dim 1 over the data axis
+        (or over `axes`, a name or a tuple of names: ops/als.plan_axes
+        adds the model axis where the tables are row-sharded): the layout
+        of stacked same-shape batch groups [N, B, ...] that a `lax.scan`
+        consumes along dim 0, each slice staying sharded."""
         ndim = np.ndim(x)
         return self.put(
-            x, self.sharding(None, self.DATA_AXIS, *([None] * (ndim - 2))))
+            x, self.sharding(None, axes or self.DATA_AXIS,
+                             *([None] * (ndim - 2))))
 
     def put_model_sharded(self, x):
         """Rows sharded over the model axis (embedding tables)."""

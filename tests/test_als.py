@@ -410,7 +410,14 @@ def test_train_telemetry_phases():
               "s_per_iter", "fetch_s"}
     assert set(tel) == phases | {"solver", "compute_dtype", "sweep_chunk",
                                  "n_devices", "gather_layout",
+                                 "table_shards", "batch_shards",
+                                 "exchange_bytes",
                                  "cg_iters_run", "cg_iters_budget"}
+    # replicated tables, batches over the data axis: GSPMD's sweep, and
+    # no exchange of the per-chip kind to report
+    assert (tel["table_shards"], tel["batch_shards"]) == (1,
+                                                          tel["n_devices"])
+    assert tel["exchange_bytes"] == {}
     # the CPU's gather has no step to pad a batch for
     assert tel["gather_layout"] == "rows"
     # no solve went through the Pallas CG, the one solver that counts
@@ -519,7 +526,8 @@ def _padded_upload(monkeypatch, mesh, plan, chunk):
     """`_upload_plan` as a single TPU device gets it."""
     with monkeypatch.context() as m:
         m.setattr(als_mod, "_gather_layout",
-                  lambda mesh, rank=None: "rows+pad256")
+                  lambda mesh, rank=None, factor_sharding="replicated":
+                  "rows+pad256")
         return als_mod._upload_plan(mesh, plan, chunk)
 
 
@@ -617,3 +625,8 @@ def test_cpu_and_mesh_uploads_are_not_padded(mesh8):
     assert als_mod._gather_layout(mesh_of("tpu", 1), 10) == "rows"
     assert als_mod._gather_layout(mesh_of("tpu", 4)) == "rows"
     assert als_mod._gather_layout(mesh_of("gpu", 1)) == "rows"
+    # row-sharded tables: each chip gathers in a program of its own
+    sharded = mesh_of("tpu", 4)
+    sharded.model_parallelism = 4
+    assert als_mod._gather_layout(sharded, 200, "model") == "rows+pad256"
+    assert als_mod._gather_layout(sharded, 200, "replicated") == "rows"

@@ -5,8 +5,8 @@ seconds, before a chip run does. The list is read off
 
     grep -rn "predictionio_tpu" benchmark/
 
-(jobs/als-train.py, jobs/ials-train.py, jobs/http-queries.py,
-layer_metrics/cg_iters_run_pct.py, lib/account.py, scoped.py and
+(jobs/als-train.py, jobs/ials-train.py, jobs/als-train-sharded.py,
+jobs/http-queries.py, layer_metrics/cg_iters_run_pct.py, lib/account.py, scoped.py and
 tests/test_offchip_compile.py, test_cg_iters.py, test_faults.py,
 test_implicit.py), one case per name and per call shape; when the benchmark
 starts to take another name, it gets a case here. Signatures are bound, never
@@ -77,6 +77,22 @@ CALLS = [
     ("train set-up", "ops.solve", "resolve_solver", ("auto", 1), {}),
     ("test_cg_iters", "ops.solve", "cg_solve_pallas", ("A", "b"),
      {"interpret": True}),
+    # jobs/als-train-sharded.py: tables and plans placed for "model"
+    ("sharded set-up", "ops.als", "sweep_solver", ("auto", "mesh", "model"),
+     {}),
+    ("sharded set-up", "ops.als", "batch_shards", ("mesh", "model"), {}),
+    ("sharded set-up", "ops.als", "table_rows", (1, 4), {}),
+    ("sharded set-up", "ops.als", "_upload_plan",
+     ("mesh", "plan", "chunk", 200, "model"), {}),
+    ("sharded set-up", "ops.als", "_gather_layout", ("mesh", 200, "model"),
+     {}),
+    ("sharded set-up", "ops.als", "sweep_shards", ("table", "groups"), {}),
+    ("sharded set-up", "ops.als", "sweep_exchange",
+     ("mesh", "groups", "out", "counter", "cfg"), {}),
+    ("sharded window", "ops.als", "_run_side",
+     ("groups", "out", "counter", "cfg", None, "lam", "alpha"),
+     {"side": "user", "mesh": "mesh"}),
+    ("sharded set-up", "parallel.mesh", "model_mesh", (4,), {}),
     ("train set-up", "ops.ratings", "plan_for_users", ("coo",),
      {"work_budget": 1, "batch_multiple": 1, "bucket_ratio": 1.125}),
     ("train set-up", "ops.ratings", "plan_for_items", ("coo",),
@@ -123,6 +139,28 @@ def test_the_mesh_the_jobs_read():
     assert mesh.n_devices >= 1 and mesh.data_parallelism >= 1
     assert callable(mesh.replicated) and callable(mesh.put_replicated)
     binds(M.make_mesh, devices=["d"])
+
+
+def test_what_the_sharded_job_reads_of_the_program():
+    """jobs/als-train-sharded.py: `factor_sharding` on the config, the
+    model mesh's placement methods, and the shape of what `sweep_shards`
+    and `sweep_exchange` answer."""
+    import jax
+    import numpy as np
+    from predictionio_tpu.parallel import mesh as M
+    als = _als()
+    assert als.ALSConfig(factor_sharding="model").factor_sharding == "model"
+    mesh = M.make_mesh(devices=jax.devices()[:4], model_parallelism=4)
+    assert mesh.model_parallelism == 4 and mesh.n_devices == 4
+    assert callable(mesh.model_sharded) and callable(mesh.put_model_sharded)
+    table = mesh.put_model_sharded(np.zeros((8, 4), np.float32))
+    groups = ((mesh.put_stacked(np.zeros((1, 8), np.int32),
+                                als.plan_axes(mesh, "model")),),)
+    assert als.sweep_shards(table, groups) == (4, 4)
+    assert als.table_rows(20_980_000, 4) == 20_980_004
+    # nothing is exchanged where the sweep is not the per-chip one
+    assert als.sweep_exchange(mesh, groups, table, table,
+                              als.ALSConfig()) == {}
 
 
 def test_the_set_up_helpers_exist():

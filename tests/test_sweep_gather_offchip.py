@@ -97,3 +97,134 @@ def test_padded_rungs_gather_in_steps_of_256(sds, rank):
     # a handful of rows more a batch: the program holds what the parent's did
     assert compiled.memory_analysis().temp_size_in_bytes \
         <= CASES[rank][1] + ROOM
+
+
+# -- the half-sweep over row-sharded tables, compiled for a v5e:2x2 (PR 33) --
+#
+# rec-amazon14-all-r200 on the mesh `model_mesh(4)` builds: both tables
+# row-sharded four ways, the batches divided over the same chips
+# (`ops/als._solve_sweep_per_chip`). The three widest rungs of each side's
+# plan (slots a step; `batch_multiple` 4), two scan steps each, beside a
+# quarter of both whole plans (4.18 GB of int32/float32 [B, K] arrays and
+# row ids: scratch shapes.py of PR 33, PERF.md section 4).
+SHARDED_USERS, SHARDED_ITEMS, CHIPS = 20_980_000, 9_350_000, 4
+WIDEST = {"user": [(21848, 48), (26216, 40), (11916, 88)],
+          # and the rung of the heaviest item, the longest gather of a row
+          "item": [(1640, 640), (2852, 368), (4372, 240), (12, 65408)]}
+QUARTER_OF_BOTH_PLANS = 4_180_357_216 // 4
+HBM = 15.75e9
+
+
+@pytest.fixture(scope="module")
+def mesh4(sds):
+    import jax
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices).reshape(1, CHIPS), ("data", "model"))
+
+    def shaped(shape, dt, *spec):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+    return mesh, shaped
+
+
+def _sharded_half_sweep(mesh4, side):
+    import jax.numpy as jnp
+    from predictionio_tpu.ops import als
+    mesh, shaped = mesh4
+    n_out, n_counter = ((SHARDED_USERS, SHARDED_ITEMS) if side == "user"
+                        else (SHARDED_ITEMS, SHARDED_USERS))
+    both = ("data", "model")
+    groups = []
+    for b, k in WIDEST[side]:
+        b += als._gather_pad_rows(b, k, CHIPS)
+        groups.append((shaped((2, b), jnp.int32, None, both),
+                       shaped((2, b, k), jnp.int32, None, both, None),
+                       shaped((2, b, k), jnp.float32, None, both, None),
+                       shaped((2, b, k), jnp.float32, None, both, None)))
+    return als._solve_sweep_per_chip.lower(
+        shaped((als.table_rows(n_out, CHIPS), 200), jnp.float32, "model",
+               None),
+        shaped((als.table_rows(n_counter, CHIPS), 200), jnp.float32,
+               "model", None),
+        None, tuple(groups), shaped((), jnp.float32), shaped((), jnp.float32),
+        nratings_reg=True, implicit=False, rank=200,
+        compute_dtype="bfloat16", solver="cg_pallas", dual_solve="auto",
+        solver_iters=None, dual_iters_cap=None, mesh=mesh,
+        table_axis="model", batch_axes=both).compile()
+
+
+@pytest.mark.parametrize("side", ["user", "item"])
+def test_sharded_half_sweep_fits_a_chip_and_holds_no_whole_table(mesh4,
+                                                                 side):
+    from predictionio_tpu.ops import als
+    from predictionio_tpu.parallel.collective_stats import \
+        executed_collective_stats
+    compiled = _sharded_half_sweep(mesh4, side)
+    text = compiled.as_text()
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + QUARTER_OF_BOTH_PLANS) <= HBM
+    n_counter = SHARDED_ITEMS if side == "user" else SHARDED_USERS
+    whole = {als.table_rows(n, CHIPS) for n in (SHARDED_USERS,
+                                                SHARDED_ITEMS)}
+    shard = als.table_rows(n_counter, CHIPS) // CHIPS
+    rows_held = {int(r) for r in re.findall(r"\[(\d{6,}),200\]", text)}
+    assert not rows_held & whole              # never a whole table's rows
+    assert shard in rows_held                 # its own quarter
+    # the counterpart shard is read through ONE copy, in the compute dtype
+    assert not re.search(rf"= f32\[{shard},200\]\S* copy\(", text)
+    assert len(re.findall(rf"= bf16\[{shard},200\]\S* (?:copy|convert)\(",
+                          text)) <= 2        # the copy, and its relayout
+    # the Pallas CG runs on the chip's own quarter of the systems
+    assert "tpu_custom_call" in text
+    # only the exchanges the algorithm needs: indices and solved rows
+    # all-gathered, the gathered block reduce-scattered (the compiler's
+    # all-reduce + slice fusion), the row ids and the CG counts summed
+    ran = executed_collective_stats(compiled)
+    assert set(ran) <= {"all-gather", "reduce-scatter", "all-reduce",
+                        "total"}
+    steps = 2
+    slots = sum((b + als._gather_pad_rows(b, k, CHIPS)) * k
+                for b, k in WIDEST[side])
+    # a quarter of each block in bfloat16, a row at its 200 columns or
+    # padded to 256 lanes (the compiler's own reduce-scatter or its fusion)
+    assert (steps * slots * 200 * 2 / CHIPS
+            <= ran["reduce-scatter"]["bytes"]
+            <= 1.02 * steps * slots * 256 * 2 / CHIPS)
+    small = sum(b + 32 for b, _k in WIDEST[side]) * 4 * steps + 64
+    assert ran.get("all-reduce", {"bytes": 0})["bytes"] <= small
+
+
+def test_the_sentinel_checks_the_sharded_tables_in_place(mesh4):
+    """`als_train` with the sentinel on (the default: `pio train`) at
+    rec-amazon14-all-r200: the per-iteration check of a row-sharded table
+    reads the shard where it lies, no temporary and no copy, so between
+    half-sweeps a chip holds its quarter of both tables and plans and
+    nothing more; and a last-good pair is not kept, because it could not
+    be: beside one, the user half-sweep would need more than the chip has
+    (when this last assertion fails there is room again, and
+    `ops/als.als_train`'s rule can go)."""
+    import jax
+    import jax.numpy as jnp
+    from predictionio_tpu.guard import sentinels
+    from predictionio_tpu.ops import als
+    _mesh, shaped = mesh4
+    at_rest = QUARTER_OF_BOTH_PLANS
+    for n in (SHARDED_USERS, SHARDED_ITEMS):
+        table = shaped((als.table_rows(n, CHIPS), 200), jnp.float32,
+                       "model", None)
+        m = jax.jit(sentinels._table_stats_impl).lower(
+            table).compile().memory_analysis()
+        assert m.temp_size_in_bytes <= 1 << 20
+        assert m.argument_size_in_bytes <= 1.001 * (
+            als.table_rows(n, CHIPS) // CHIPS) * 200 * 4
+        at_rest += m.argument_size_in_bytes
+    assert at_rest <= 0.5 * HBM                  # 7.1 GB a chip
+    tables = at_rest - QUARTER_OF_BOTH_PLANS
+    m = _sharded_half_sweep(mesh4, "user").memory_analysis()
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + QUARTER_OF_BOTH_PLANS + tables) > HBM

@@ -185,6 +185,37 @@ def test_the_pallas_solves_say_primal_or_dual_and_their_size(K, scope,
     assert scope in text and kernel in text
 
 
+_EXCHANGE = {"pio.sweep.exchange.indices", "pio.sweep.exchange.rows",
+             "pio.sweep.exchange.solved"}
+
+
+@pytest.mark.parametrize("K,solve_scope", [
+    (16, "pio.sweep.solve.primal"), (4, "pio.sweep.solve.dual")])
+def test_the_per_chip_sweep_names_its_exchanges(K, solve_scope):
+    """The half-sweep over row-sharded tables: the one-chip stages, and the
+    three exchanges that cross the chips beside them."""
+    import jax
+    import jax.numpy as jnp
+    from predictionio_tpu.ops import als
+    from predictionio_tpu.parallel.mesh import make_mesh
+    mesh = make_mesh(devices=jax.devices()[:4], model_parallelism=4)
+    rng = np.random.default_rng(0)
+    N, B, rank = 2, 8, 8
+    group = (np.arange(N * B, dtype=np.int32).reshape(N, B),
+             rng.integers(0, 50, (N, B, K)).astype(np.int32),
+             rng.uniform(1, 5, (N, B, K)).astype(np.float32),
+             np.ones((N, B, K), np.float32))
+    text = als._solve_sweep_per_chip.trace(
+        jnp.zeros((44, rank)), jnp.ones((52, rank)), None, (group,),
+        np.float32(0.01), np.float32(1.0), nratings_reg=True,
+        implicit=False, rank=rank, compute_dtype="float32",
+        solver="cholesky", dual_solve="auto", solver_iters=None,
+        dual_iters_cap=None, mesh=mesh.mesh, table_axis="model",
+        batch_axes=("data", "model")).lower().as_text(debug_info=True)
+    assert set(re.findall(r"pio\.[a-z_.]+", text)) == (
+        _EVERY_SWEEP | _EXCHANGE | {solve_scope})
+
+
 def test_the_full_grams_are_named():
     import jax
     import jax.numpy as jnp
