@@ -23,14 +23,22 @@ divided follows from the mesh and `factor_sharding` (`_rows_sharded`):
   `shard_map` over the half-sweep. Every chip holds a contiguous quarter of
   each table's rows and a quarter of every batch of the plan
   (`plan_axes`), and solves its quarter of the systems with the one-chip
-  body. Three exchanges cross the chips in a scan step, and nothing else:
-  the step's indices (all-gather), the counterpart rows each shard owns,
-  in the compute dtype (reduce-scatter over the batch), and the solved
-  float32 rows (all-gather, each shard keeping its own). No chip ever
-  holds a whole table or another chip's plan. What the compiled programs
+  body. Which chip owns the counterpart row of which slot is a fact of the
+  plan, so `_upload_plan` routes it once on the host (`_route_group`): a
+  chip holds, beside its quarter of the plan, the local numbers of the
+  rows the others need of it (`send`), and for each of its own slots where
+  the row will stand in what it receives (`place`, in `idx`'s seat: the
+  indices themselves never reach a device). Three exchanges cross the
+  chips in a scan step, and nothing else: the rated rows, each shard's
+  gathered from its own rows in the compute dtype and handed to the chips
+  that rate them by one all-to-all (real slots only; a received row is a
+  copy of its owner's), the solved float32 rows (all-gather, each shard
+  keeping its own), and their row ids with them. No chip ever holds a
+  whole table or another chip's systems. What the compiled programs
   exchange is read from their HLO when telemetry is asked for
   (`sweep_exchange`, telemetry `exchange_bytes`, gauge
-  `pio_als_exchange_bytes{side, op}`).
+  `pio_als_exchange_bytes{side, op}`), and what the routing cost and how
+  full the exchanged blocks run is beside it (`route_s`, `route_fill`).
 
 Math parity with MLlib 1.3:
   explicit  — ALS-WR: minimize sum (r - x.v)^2 + lambda * (n_u |x|^2 + ...)
@@ -117,10 +125,10 @@ class ALSConfig:
     factor_sharding: str = "replicated"  # 'replicated' | 'model'
     # 'model' shards factor-table rows over the mesh model axis (tables too
     # large for one device's HBM) and divides every batch of the plan over
-    # the same chips: each solves its own share of the systems, and the
-    # indices, the counterpart rows and the solved rows cross the chips
-    # (`_solve_batch_per_chip`) — the analog of MLlib's factor-block
-    # shuffles, over ICI.
+    # the same chips: each solves its own share of the systems; the rated
+    # counterpart rows reach it from their owners as the plan routed them
+    # and the solved rows go back to theirs (`_solve_batch_per_chip`) — the
+    # analog of MLlib's factor-block shuffles, over ICI.
     keep_sharded: bool = False
     # With factor_sharding='model': return the trained tables as
     # ShardedTable handles (per-shard host slices via
@@ -450,25 +458,32 @@ def sweep_shards(table, device_groups):
             ways(device_groups[0][0], 1) if device_groups else 1)
 
 
-def _solve_batch_per_chip(f_local, counter_local, gram, rows, idx, val,
-                          mask, lam, alpha, *, table_axis: str, batch_axes,
-                          **statics):
+def _solve_batch_per_chip(f_local, counter_local, gram, rows, place, val,
+                          mask, send, lam, alpha, *, table_axis: str,
+                          batch_axes, **statics):
     """One scan step of a half-sweep as ONE chip of a row-sharded mesh runs
     it (the ALX arrangement, PAPERS.md arXiv:2112.02194): this chip holds
     `f_local` / `counter_local`, its contiguous quarter of each table's
-    rows, and `rows` / `idx` / `val` / `mask`, its quarter [B/n, ...] of
-    the step's systems. Three exchanges cross the chips, and nothing else:
+    rows, and `rows` / `place` / `val` / `mask`, its quarter [B/n, ...] of
+    the step's systems. Which chip owns which slot's counterpart row is a
+    fact of the plan, so the host has routed it (`_route_group`, once a
+    train): `send` [n, L] holds, for each chip of the table axis, the local
+    numbers of the rows of THIS shard that that chip's systems rate, and
+    `place` [B/n, K], where `idx` sat, where each slot's row stands in what
+    this chip receives. Three exchanges cross the chips, and nothing else:
 
-      indices  the step's counterpart indices (and the systems' row ids)
-               all-gathered over the table axis: every shard learns which
-               of its rows the step needs;
-      rows     each shard gathers the rows it owns (zeros elsewhere) for
-               all the systems of the step, in the compute dtype, and the
-               block is reduce-scattered over the batch dimension: a chip
-               receives the [B/n, K, R] rows of its own systems, each the
-               sum of one owner's row and zeros, so exact in any dtype;
+      rows     each shard gathers the rows the others (and itself) need of
+               it, `counter_local[send]` [n, L, R] in the compute dtype: a
+               plain gather of owned rows, real slots only; one all-to-all
+               over the table axis hands every chip the [n, L, R] rows of
+               its own systems, each a copy of its owner's row, which
+               `place` spreads to [B/n, K, R] (a padding slot reads
+               position 0 and is masked in every route of
+               `_solve_gathered`, as a one-chip plan's padding `idx` 0 is);
       solved   the solved [B/n, R] float32 rows all-gathered, each shard
-               keeping the rows it owns.
+               keeping the rows it owns;
+      indices  the systems' row ids all-gathered with them: which solved
+               row goes where.
 
     Between `rows` and `solved` the chip runs `_solve_gathered` on local
     operands: the Gram, the solver routes and their Pallas kernels are the
@@ -477,20 +492,10 @@ def _solve_batch_per_chip(f_local, counter_local, gram, rows, idx, val,
     import jax.numpy as jnp
     from jax import lax
 
-    cd = jnp.dtype(statics["compute_dtype"])
     shard = lax.axis_index(table_axis)
     with jax.named_scope("pio.sweep.exchange.indices"):
-        idx_all = lax.all_gather(idx, table_axis, axis=0, tiled=True)
         rows_all = lax.all_gather(rows, batch_axes, axis=0, tiled=True)
-    with jax.named_scope("pio.sweep.gather"):
-        n_counter = counter_local.shape[0]
-        local = idx_all - shard * n_counter
-        mine = (local >= 0) & (local < n_counter)
-        Vg = counter_local[jnp.where(mine, local, 0)].astype(cd)
-        Vg = jnp.where(mine[..., None], Vg, jnp.zeros((), cd))
-    with jax.named_scope("pio.sweep.exchange.rows"):
-        Vc = lax.psum_scatter(Vg, table_axis, scatter_dimension=0,
-                              tiled=True)           # [B/n, K, R]
+    Vc = _routed_rows(counter_local, send[0], place, table_axis)
     x, cg = _solve_gathered(Vc, gram, val, mask, lam, alpha, **statics)
     with jax.named_scope("pio.sweep.exchange.solved"):
         x_all = lax.all_gather(x.astype(f_local.dtype), batch_axes, axis=0,
@@ -504,13 +509,28 @@ def _solve_batch_per_chip(f_local, counter_local, gram, rows, idx, val,
         return f_local.at[at].set(x_all, mode="drop"), cg
 
 
+def _routed_rows(counter_local, send, place, table_axis: str):
+    """The counterpart rows [B/n, K, R] of this chip's systems, fetched
+    from their owners as the plan routed them (`_route_group`): `send`
+    [n, L] and `place` [B/n, K] are this chip's of one scan step."""
+    import jax
+    from jax import lax
+    with jax.named_scope("pio.sweep.gather"):
+        owned = counter_local[send]                     # [n, L, R]
+    with jax.named_scope("pio.sweep.exchange.rows"):
+        got = lax.all_to_all(owned, table_axis, 0, 0)   # [n, L, R]
+    with jax.named_scope("pio.sweep.gather.place"):
+        return got.reshape(-1, got.shape[-1])[place]
+
+
 def _solve_sweep_per_chip_impl(factors_out, counter_factors, gram, groups,
                                lam, alpha, *, mesh, table_axis: str,
                                batch_axes, **statics):
     """`_solve_sweep_impl` written per chip: one `shard_map` over the whole
     half-sweep, its scans inside, each step `_solve_batch_per_chip`. The
-    tables go in and out sharded on their rows, the batch groups sharded on
-    the batch dimension, the shared Gram and the scalars whole. The CG
+    tables go in and out sharded on their rows, the batch groups (rows,
+    place, val, mask, send: `_upload_plan` where `_rows_sharded`) sharded
+    on the batch dimension, the shared Gram and the scalars whole. The CG
     iterations returned are the chips' summed."""
     import jax
     from jax import lax
@@ -529,9 +549,8 @@ def _solve_sweep_per_chip_impl(factors_out, counter_factors, gram, groups,
 
         def body(carry, batch):
             f, cg = carry
-            rows, idx, val, mask = batch
             f, cg_batch = _solve_batch_per_chip(
-                f, counter_local, gram, rows, idx, val, mask, lam, alpha,
+                f, counter_local, gram, *batch, lam, alpha,
                 table_axis=table_axis, batch_axes=batch_axes, **statics)
             return (f, cg + cg_batch), None
 
@@ -547,8 +566,11 @@ def _solve_sweep_per_chip_impl(factors_out, counter_factors, gram, groups,
         return f_local, lax.psum(cg, batch_axes)
 
     table = P(table_axis, None)
+    # rows, place, val, mask divided on the batch dimension; send on its
+    # source chip
     batch = tuple((P(None, batch_axes), P(None, batch_axes, None),
-                   P(None, batch_axes, None), P(None, batch_axes, None))
+                   P(None, batch_axes, None), P(None, batch_axes, None),
+                   P(None, batch_axes, None, None))
                   for _ in groups)
     whole = jax.tree_util.tree_map(lambda _: P(), gram)
     return jax.shard_map(
@@ -685,16 +707,15 @@ def resolve_sweep_chunk(chunk: int, n_devices: int = 1) -> int:
 _GATHER_TILE, _GATHER_STEP_256, _GATHER_MIN_RANK = 1024, (128, 704), 64
 
 
-def _gather_pad_rows(b: int, k: int, multiple: int = 1) -> int:
+def _gather_pad_rows(b: int, k: int) -> int:
     """How many padding systems (row -1, mask 0: what a plan pads its own
-    batches with) to append to a [b, k] batch so that its gather of
-    (b + extra) * k rows gets the compiler's 256-row step
-    (`_GATHER_STEP_256`): the fewest, at most b // 32 (3% more systems);
-    0 where the count lies there already or none that few does it (a few
-    of the longest rungs, b < 32 or k a multiple of 1,024). Only multiples
-    of `multiple` are tried: the chips a batch is divided over."""
+    batches with) to append to the [b, k] batch one chip gathers for, so
+    that its gather of (b + extra) * k rows gets the compiler's 256-row
+    step (`_GATHER_STEP_256`): the fewest, at most b // 32 (3% more
+    systems); 0 where the count lies there already or none that few does
+    it (a few of the longest rungs, b < 32 or k a multiple of 1,024)."""
     lo, hi = _GATHER_STEP_256
-    for extra in range(0, b // 32 + 1, multiple):
+    for extra in range(b // 32 + 1):
         if lo <= (b + extra) * k % _GATHER_TILE <= hi:
             return extra
     return 0
@@ -730,10 +751,12 @@ def _gather_layout(mesh: MeshContext, rank: Optional[int] = None,
     the program can see: "rows+pad256" where a TPU gathers a step's rows in
     a program of its own, which is a single TPU device and each chip of a
     TPU mesh over row-sharded tables (`_solve_batch_per_chip`);
-    `_upload_plan` then pads each batch group by `_gather_pad_rows`
-    systems. "rows" anywhere else: another backend's gather has no such
-    step, GSPMD partitions a replicated mesh's gather as it likes, and
-    tables of a known `rank` under `_GATHER_MIN_RANK` gain nothing."""
+    `_upload_plan` then pads each chip's [B/n, K] slots of a batch group
+    by `_gather_pad_rows` systems (and `_route_rows` rounds what the
+    owners of row-sharded tables gather). "rows" anywhere else:
+    another backend's gather has no such step, GSPMD partitions a
+    replicated mesh's gather as it likes, and tables of a known `rank`
+    under `_GATHER_MIN_RANK` gain nothing."""
     tpu = mesh.mesh.devices.flat[0].platform == "tpu"
     own_gather = mesh.n_devices == 1 or _rows_sharded(mesh, factor_sharding)
     wide = rank is None or rank >= _GATHER_MIN_RANK
@@ -761,7 +784,11 @@ def _upload_plan(mesh: MeshContext, plan: SolvePlan, chunk: int = 1,
     then padded by a few systems that solve nothing, so that its gather
     runs at the compiler's faster step (`_gather_pad_rows`;
     `_gather_layout` says when, from the mesh, the tables' `rank` where
-    the caller gives it, and their sharding)."""
+    the caller gives it, and their sharding).
+
+    Over row-sharded tables (`_rows_sharded`) a group is (rows, place,
+    val, mask, send): the plan's indices are routed here, on the host,
+    once (`_route_group`), and stay here."""
     with TRACER.region("train.upload"):
         return _upload_plan_now(mesh, plan, chunk, rank, factor_sharding)
 
@@ -770,11 +797,65 @@ def _upload_plan_now(mesh: MeshContext, plan: SolvePlan, chunk: int,
                      rank: Optional[int], factor_sharding: str):
     pad = _gather_layout(mesh, rank, factor_sharding) != "rows"
     axes = plan_axes(mesh, factor_sharding)
-    shards = batch_shards(mesh, factor_sharding)
+    table_shards = (mesh.model_parallelism
+                    if _rows_sharded(mesh, factor_sharding) else 1)
+    groups = tuple(
+        tuple(mesh.put_stacked(x, axes) for x in tensors)
+        for tensors in _host_groups(
+            plan, chunk, pad, batch_shards(mesh, factor_sharding),
+            table_shards))
+    # host->device transfer accounting (obs.jaxmon): the plan upload is
+    # the largest per-train / per-fold-in host->device transfer
+    from predictionio_tpu.obs import jaxmon
+    jaxmon.record_h2d(jaxmon.nbytes_of(
+        t for group in groups for t in group))
+    return groups
+
+
+def _host_groups(plan: SolvePlan, chunk: int, pad: bool, shards: int,
+                 table_shards: int = 1):
+    """The host arrays of each batch group `_upload_plan` uploads, in its
+    order: (rows, idx, val, mask), same-shape batches stacked, merged
+    `chunk` at a time and, where `pad`, padded for the gather; for tables
+    row-sharded `table_shards` > 1 ways (the batches divided over `shards`
+    chips) each chip gathers its own [B/shards, K] slots, so that count is
+    what is padded for, and the group is (rows, place, val, mask, send):
+    `_route_group`'s `place` in `idx`'s seat, `send` appended, and what
+    was routed goes to `_route_log`."""
+    groups = _padded_groups(plan, chunk, pad, shards)
+    if table_shards == 1:
+        yield from groups
+        return
+    import time
+    from concurrent.futures import ThreadPoolExecutor
+    if plan.n_counter is None:
+        raise ValueError(
+            "a plan uploaded for row-sharded tables has to say how many "
+            "rows its indices point into (SolvePlan.n_counter: "
+            "plan_for_users / plan_for_items do)")
+    t0 = time.perf_counter()
+    rows_a_shard = table_rows(plan.n_counter, table_shards) // table_shards
+    degrees = _rung_degrees(plan)
+    seconds, real, room = time.perf_counter() - t0, 0, 0
+    with ThreadPoolExecutor(os.cpu_count() or 1) as pool:
+        for rows, idx, val, mask in groups:
+            t0 = time.perf_counter()
+            with TRACER.region("train.route"):
+                place, send, needed, made = _route_group(
+                    rows, idx, mask, degrees[idx.shape[2]], rows_a_shard,
+                    table_shards, shards, pad, pool)
+            seconds += time.perf_counter() - t0
+            real, room = real + needed, room + made
+            yield rows, place, val, mask, send
+    _route_log.append((seconds, real, room))
+
+
+def _padded_groups(plan: SolvePlan, chunk: int, pad: bool, shards: int):
+    """`_host_groups`' (rows, idx, val, mask); where `pad`, each of the
+    `shards` chips' [B/shards, K] slots padded for its gather."""
     by_shape = {}
     for b in plan.batches:
         by_shape.setdefault(b.shape, []).append(b)
-    groups = []
     for shape in sorted(by_shape):
         bs = by_shape[shape]
         rows = np.stack([b.rows for b in bs])    # [N, B]
@@ -796,17 +877,151 @@ def _upload_plan_now(mesh: MeshContext, plan: SolvePlan, chunk: int,
                                     for x in (rows, idx, val, mask)))
         for tensors in chunks:
             b, k = tensors[1].shape[1:]
-            padded_b = b + (_gather_pad_rows(b, k, shards) if pad else 0)
-            groups.append(tuple(
-                mesh.put_stacked(
-                    mesh.pad_to_multiple(x, 1, padded_b, fill)[0], axes)
-                for x, fill in zip(tensors, (-1, 0, 0, 0))))
-    # host->device transfer accounting (obs.jaxmon): the plan upload is
-    # the largest per-train / per-fold-in host->device transfer
-    from predictionio_tpu.obs import jaxmon
-    jaxmon.record_h2d(jaxmon.nbytes_of(
-        t for group in groups for t in group))
-    return tuple(groups)
+            extra = shards * _gather_pad_rows(b // shards, k) if pad else 0
+            if extra:
+                tensors = tuple(
+                    np.pad(x, [(0, 0), (0, extra)] + [(0, 0)] * (x.ndim - 2),
+                           constant_values=fill)
+                    for x, fill in zip(tensors, (-1, 0, 0, 0)))
+            yield tensors
+
+
+# -- the routing of a plan over row-sharded tables, on the host ------------
+
+#: Slots one thread routes at a time (`_route_group`): a few passes of
+#: whole-array numpy over 4 MB of indices each, or one scan step's.
+_ROUTE_SLOTS = 1 << 20
+
+#: What each `_upload_plan` over row-sharded tables routed, (seconds, real
+#: rows, rows of room in `send`): `als_train` clears it before its two
+#: uploads and reads `route_s` / `route_fill` after them (`_routed`).
+_route_log: list = []
+
+
+def _routed() -> dict:
+    """Telemetry of the uploads in `_route_log`: `route_s`, the host's
+    seconds routing them (a part of `upload_s`), and `route_fill`, the real
+    rows over the rows of room the chips exchange for them (padding in `L`:
+    an uneven spread of owners, and the room that keeps `L` off the seed).
+    Empty where nothing was routed."""
+    if not _route_log:
+        return {}
+    seconds, real, room = (sum(x) for x in zip(*_route_log))
+    return {"route_s": seconds, "route_fill": real / max(room, 1)}
+
+
+def _rung_degrees(plan: SolvePlan) -> dict:
+    """{K: ratings a system of rung K has}, over every batch of the plan:
+    from the plan's degrees alone, whatever ids carry them."""
+    systems, slots = collections.Counter(), collections.Counter()
+    for b in plan.batches:
+        systems[b.shape[1]] += int(np.count_nonzero(b.rows >= 0))
+        slots[b.shape[1]] += int(np.count_nonzero(b.mask))
+    return {k: slots[k] / max(systems[k], 1) for k in systems}
+
+
+def _route_rows(expected: float, observed: int, table_shards: int,
+                pad: bool) -> int:
+    """`L`, the rows a chip sends each chip in a scan step of one batch
+    group: room for `expected`, the rows a pair of chips exchanges where
+    owners are spread evenly (`_route_group`: from the plan's degrees
+    alone, so the same for every pairing of the same degree sequences, and
+    with it the compiled program and its place in the compile cache), a
+    sixteenth and eight standard deviations of a fair draw over it; over
+    `observed`, the most any pair of the group really exchanges, by steps
+    of an eighth (a catalogue whose popular rows crowd one shard pays in
+    `L` and in a program of its own, and stays exact). A multiple of 16 (a
+    bfloat16 tile's sublanes), and where `pad` such that the owners' gather
+    of `table_shards` * L rows gets the compiler's 256-row step
+    (`_GATHER_STEP_256`)."""
+    want = int(np.ceil(expected * 17 / 16 + 8 * np.sqrt(expected))) + 16
+    while want < observed:
+        want = -(-want * 9 // 8)
+    lo, hi = _GATHER_STEP_256
+    L = -(-want // 16) * 16
+    while pad and not lo <= table_shards * L % _GATHER_TILE <= hi:
+        L += 16
+    return L
+
+
+def _route_steps(idx, mask, owner, counts, rows_a_shard: int):
+    """First pass over some scan steps of a group, `idx` / `mask` /
+    `owner` [n, blocks, S] (a chip's block of a step in a row) and `counts`
+    [n, blocks, table_shards]: into `owner` (int8) goes the shard whose
+    range a real slot's row lies in, -1 for padding; into `counts` how many
+    slots of the block each shard owns."""
+    q = idx // rows_a_shard
+    q += 1
+    q *= mask != 0              # in place: `np.where` costs a third more
+    q -= 1
+    np.copyto(owner, q, casting="unsafe")
+    for s in range(counts.shape[-1]):
+        counts[:, :, s] = np.count_nonzero(owner == s, axis=-1)
+
+
+def _place_steps(idx, owner, counts, place, send, rows_a_shard: int):
+    """Second pass, `L` known (`send` [n, data, table_shards, table_shards,
+    L], zeros: by sending chip (data, shard) and receiving index on the
+    table axis; `place` zeros): `send[t, d, s, m]` becomes the local numbers
+    of the rows shard s sends the chip of block (d, m) in step t, in slot
+    order (what is left zero, row 0, is sent and never placed), and
+    `place` of a real slot s * L + its place in that run. Sorts nothing
+    and holds the interpreter for no whole-array pass (numpy's running sum
+    does: the threads of `_route_group` would take turns)."""
+    L = send.shape[-1]
+    idx, owner, place = idx.ravel(), owner.ravel(), place.ravel()
+    real = np.flatnonzero(owner >= 0)
+    owner = owner[real]
+    for s in range(counts.shape[-1]):
+        at = real[owner == s]           # in (step, block, slot) order
+        count = counts[:, :, s]
+        first = np.cumsum(count) - count.ravel()
+        among = np.arange(at.size) - np.repeat(first, count.ravel())
+        place[at] = among + s * L
+        send[:, :, s][np.arange(L) < count.reshape(
+            send.shape[:2] + (-1, 1))] = idx[at] - s * rows_a_shard
+
+
+def _route_group(rows, idx, mask, degree: float, rows_a_shard: int,
+                 table_shards: int, blocks: int, pad: bool, pool=None):
+    """The static routing of one padded batch group over row-sharded
+    tables, on the host: whole-array passes, no loop over systems, no sort;
+    `pool`'s threads take some scan steps each (numpy releases the
+    interpreter). `rows` [N, B], `idx` / `mask` [N, B, K], the B systems of
+    a step dealt to `blocks` chips in order, chip (d, m) of the data and
+    table axes holding block d * table_shards + m; `degree` the rung's
+    `_rung_degrees`. Returns
+
+      place  [N, B, K] int32, `idx`'s seat on the device: where the slot's
+             row stands among the [table_shards * L] rows its chip
+             receives in that step (owner * L + its place among the
+             block's slots of that owner; 0 for a padding slot);
+      send   [N, blocks, table_shards, L] int32: `send[t, c, m]` the local
+             row numbers chip c gathers from its shard in step t for the
+             chip m of its table axis, zeros (row 0: sent, never placed)
+             beyond what m needs;
+      real, room  the rows really needed, and the N * blocks *
+             table_shards * L of room (`route_fill`)."""
+    n, b, k = idx.shape
+    steps = max(_ROUTE_SLOTS // (b * k), 1)
+    cuts = [slice(lo, lo + steps) for lo in range(0, n, steps)]
+    each = pool.map if pool is not None else map
+    idx, mask = (x.reshape(n, blocks, -1) for x in (idx, mask))
+    owner = np.empty(idx.shape, np.int8)
+    counts = np.empty((n, blocks, table_shards), np.int64)
+    list(each(lambda c: _route_steps(idx[c], mask[c], owner[c], counts[c],
+                                     rows_a_shard), cuts))
+    systems = int((rows.reshape(n, blocks, -1) >= 0).sum(axis=-1).max())
+    L = _route_rows(systems * degree / table_shards, int(counts.max()),
+                    table_shards, pad)
+    place = np.zeros(idx.shape, np.int32)
+    send = np.zeros((n, blocks // table_shards, table_shards, table_shards,
+                     L), np.int32)
+    list(each(lambda c: _place_steps(idx[c], owner[c], counts[c], place[c],
+                                     send[c], rows_a_shard), cuts))
+    return (place.reshape(n, b, k),
+            send.reshape(n, blocks, table_shards, L), int(counts.sum()),
+            n * blocks * table_shards * L)
 
 
 #: An implicit half-sweep of more batch groups than this is dispatched as
@@ -1017,12 +1232,14 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
     V = put_factors(_init_factors(ratings.n_items, cfg.rank, cfg.seed, 2,
                                   row_multiple).astype(fdt, copy=False))
     chunk = resolve_sweep_chunk(cfg.sweep_chunk, mesh.n_devices)
+    _route_log.clear()
     user_batches = _upload_plan(mesh, user_plan, chunk, cfg.rank,
                                 cfg.factor_sharding)
     item_batches = _upload_plan(mesh, item_plan, chunk, cfg.rank,
                                 cfg.factor_sharding)
     if telemetry is not None:
         n_table, n_batch = sweep_shards(U, user_batches)
+        telemetry.update(_routed())
         telemetry.update(solver=cfg.solver,
                          compute_dtype=cfg.compute_dtype,
                          sweep_chunk=chunk, n_devices=mesh.n_devices,

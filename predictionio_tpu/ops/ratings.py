@@ -17,7 +17,7 @@ construction of the similarproduct template
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -104,6 +104,10 @@ class SolvePlan:
     batches: Sequence[SolveBatch]
     n_entities: int
     nnz: int
+    # entities of the counterpart side, whose table `idx` points into: what
+    # tells ops/als._upload_plan which shard of a row-sharded table owns a
+    # slot's row. None from a caller that did not say (the online fold).
+    n_counter: Optional[int] = None
 
     @property
     def kernel_shapes(self):
@@ -162,7 +166,8 @@ def build_solve_plan(group_idx: np.ndarray, counter_idx: np.ndarray,
                      values: np.ndarray, n_groups: int,
                      work_budget: int = 1 << 20, min_k: int = 8,
                      batch_multiple: int = 1,
-                     bucket_ratio: float = 1.125) -> SolvePlan:
+                     bucket_ratio: float = 1.125,
+                     n_counter: Optional[int] = None) -> SolvePlan:
     """Group COO entries by `group_idx`, bucket groups by padded segment
     length K (geometric ladder, bucket_lengths), and emit [B, K] batches
     with B ~= work_budget/K rounded up to `batch_multiple` (the mesh
@@ -185,7 +190,8 @@ def build_solve_plan(group_idx: np.ndarray, counter_idx: np.ndarray,
 
     present = np.nonzero(counts)[0]
     if present.size == 0:
-        return SolvePlan(batches=(), n_entities=n_groups, nnz=0)
+        return SolvePlan(batches=(), n_entities=n_groups, nnz=0,
+                         n_counter=n_counter)
     sizes = bucket_lengths(int(counts[present].max()), min_k,
                            ratio=bucket_ratio)
     ks = sizes[np.searchsorted(sizes, counts[present], side="left")]
@@ -242,16 +248,17 @@ def build_solve_plan(group_idx: np.ndarray, counter_idx: np.ndarray,
             val[row_of, pos] = v_sorted[src]
             mask[row_of, pos] = 1.0
             batches.append(SolveBatch(rows, idx, val, mask))
-    return SolvePlan(batches=tuple(batches), n_entities=n_groups, nnz=nnz)
+    return SolvePlan(batches=tuple(batches), n_entities=n_groups, nnz=nnz,
+                     n_counter=n_counter)
 
 
 def plan_for_users(r: RatingsCOO, **kw) -> SolvePlan:
     with TRACER.region("train.plan", side="user"):
         return build_solve_plan(r.user_idx, r.item_idx, r.rating,
-                                r.n_users, **kw)
+                                r.n_users, n_counter=r.n_items, **kw)
 
 
 def plan_for_items(r: RatingsCOO, **kw) -> SolvePlan:
     with TRACER.region("train.plan", side="item"):
         return build_solve_plan(r.item_idx, r.user_idx, r.rating,
-                                r.n_items, **kw)
+                                r.n_items, n_counter=r.n_users, **kw)

@@ -1,11 +1,14 @@
 """ALS training over row-sharded factor tables (`factor_sharding="model"`)
 on a forced CPU mesh at small sizes, seeded ratings: the half-sweep written
-per chip (ops/als._solve_sweep_per_chip: indices all-gathered, own rows
-gathered, the block reduce-scattered over the batch, the one-chip solve on
-B/n systems, solved rows all-gathered and kept by their owner).
+per chip (ops/als._solve_sweep_per_chip: each shard gathers the rows the
+plan's routing says the others need of it, one all-to-all hands every chip
+the rows of its own systems, each a copy of its owner's row, placed by the
+plan; the one-chip solve on B/n systems; solved rows all-gathered and kept
+by their owner).
 
 Every case runs explicit and implicit training on mesh shapes 1x4 and 1x2
-(data x model):
+(data x model); `layout` and `rows` also on 2x2, where a data axis wider
+than 1 divides the batches further and the rows cross within a data row:
 
   reference   one half-sweep's rows against the configuration's plain
               reference (benchmark/references/als-explicit.py and
@@ -13,11 +16,14 @@ Every case runs explicit and implicit training on mesh shapes 1x4 and 1x2
               the program imported) solving the same systems;
   layout      the same ratings and seed give the same rows on one device
               and on the mesh, within float32 rounding;
+  rows        the counterpart rows a chip's systems receive are copies of
+              their owners' rows: `table[idx]` bit for bit, in bfloat16;
   shards      after one half-sweep rows of every shard are written, and
               only rows that have ratings;
   program     each device's compiled half-sweep holds Gram and solve
-              operands of leading dimension B/n, and no operand or
-              temporary with a whole table's rows;
+              operands of leading dimension B/n, no operand or temporary
+              with a whole table's rows or a whole step's slots, an
+              all-to-all and no reduce-scatter or gathered indices;
   exchange    `telemetry["exchange_bytes"]` is what the compiled programs'
               collectives say (parallel/collective_stats), and so is the
               gauge `pio_als_exchange_bytes`;
@@ -39,6 +45,7 @@ LAM, ALPHA = 0.1, 1.0
 KINDS = {"explicit": False, "implicit": True}
 MESHES = {"1x4": (1, 4), "1x2": (1, 2)}
 CASES = [(k, m) for k in KINDS for m in MESHES]
+WIDE = dict(MESHES, **{"2x2": (2, 2)})
 
 
 def _load(name):
@@ -71,7 +78,7 @@ def ratings():
 def _mesh(name):
     import jax
     from predictionio_tpu.parallel.mesh import make_mesh
-    dp, mp = MESHES[name]
+    dp, mp = WIDE[name]
     return make_mesh(devices=jax.devices()[:dp * mp], model_parallelism=mp)
 
 
@@ -93,7 +100,7 @@ def trained(ratings):
         out[kind, "one"] = (als.als_train(
             ratings, _cfg(implicit),
             make_mesh(devices=jax.devices()[:1])), None)
-        for name in MESHES:
+        for name in WIDE:
             tel = {}
             model = als.als_train(
                 ratings, _cfg(implicit, factor_sharding="model"),
@@ -102,17 +109,64 @@ def trained(ratings):
     return out
 
 
-@pytest.mark.parametrize("kind,mesh_name", CASES)
+@pytest.mark.parametrize("kind,mesh_name",
+                         [(k, m) for k in KINDS for m in WIDE])
 def test_same_rows_on_one_device_and_on_the_mesh(trained, kind, mesh_name):
     one = trained[kind, "one"][0]
     many, tel = trained[kind, mesh_name]
-    n = MESHES[mesh_name][1]
-    assert (tel["table_shards"], tel["batch_shards"]) == (n, n)
-    assert tel["n_devices"] == n
+    dp, n = WIDE[mesh_name]
+    assert (tel["table_shards"], tel["batch_shards"]) == (n, dp * n)
+    assert tel["n_devices"] == dp * n
     np.testing.assert_allclose(many.user_factors, one.user_factors,
                                rtol=0, atol=2e-5)
     np.testing.assert_allclose(many.item_factors, one.item_factors,
                                rtol=0, atol=2e-5)
+    # the routing engaged, and says what it cost and how full it ran
+    assert tel["route_s"] > 0 and 0 < tel["route_fill"] <= 1
+    assert "route_s" not in (trained[kind, "one"][1] or {})
+
+
+@pytest.mark.parametrize("mesh_name", WIDE)
+def test_received_rows_are_copies_of_their_owners(ratings, mesh_name):
+    """The plan as `_upload_plan` routes and places it, and the fetch a scan
+    step makes with it (`_routed_rows`: the owners' gather, the all-to-all,
+    the placement), on a bfloat16 table: wherever the mask is 1 the row a
+    system's slot receives is `table[idx]`, to the last bit."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+    from predictionio_tpu.ops import als
+    from predictionio_tpu.ops.ratings import plan_for_users
+    mesh = _mesh(mesh_name)
+    mp = mesh.model_parallelism
+    axes = als.plan_axes(mesh, "model")
+    chips = als.batch_shards(mesh, "model")
+    plan = plan_for_users(ratings, work_budget=512, batch_multiple=chips)
+    table = np.asarray(jnp.asarray(
+        als._init_factors(N_ITEMS, RANK, 9, 2, mp), jnp.bfloat16))
+    V = mesh.put_model_sharded(table)
+
+    @jax.jit
+    def fetch(V, place, send):
+        def per_chip(v, place, send):
+            return jax.lax.map(lambda step: als._routed_rows(
+                v, step[1][0], step[0], "model"), (place, send))
+        return jax.shard_map(
+            per_chip, mesh=mesh.mesh,
+            in_specs=(P("model", None), P(None, axes, None),
+                      P(None, axes, None, None)),
+            out_specs=P(None, axes, None, None), check_vma=False)(
+                V, place, send)
+
+    plain = list(als._host_groups(plan, 1, False, chips))
+    groups = als._upload_plan(mesh, plan, 1, RANK, "model")
+    assert len(groups) == len(plain) > 3
+    for (_rows, idx, _val, mask), group in zip(plain, groups):
+        _r, place, _v, _m, send = group
+        got = np.asarray(fetch(V, place, send))
+        live = mask.astype(bool)
+        assert got.dtype == table.dtype
+        assert (got[live] == table[idx][live]).all()
 
 
 def _half_sweep(ratings, implicit, mesh):
@@ -200,14 +254,18 @@ def test_each_chip_solves_its_share_and_holds_no_whole_table(
     mesh = _mesh(mesh_name)
     B, K = 32, 24                                  # K >= RANK: primal
     rng = np.random.default_rng(7)
-    group = (rng.permutation(N_USERS)[:B].astype(np.int32)[None],
-             rng.integers(0, N_ITEMS, (1, B, K)).astype(np.int32),
-             rng.integers(1, 6, (1, B, K)).astype(np.float32),
-             np.ones((1, B, K), np.float32))
-    groups = (tuple(mesh.put_stacked(x, als.plan_axes(mesh, "model"))
-                    for x in group),)
     rows_u = als.table_rows(N_USERS, n)
     rows_v = als.table_rows(N_ITEMS, n)
+    rows = rng.permutation(N_USERS)[:B].astype(np.int32)[None]
+    idx = rng.integers(0, N_ITEMS, (1, B, K)).astype(np.int32)
+    mask = np.ones((1, B, K), np.float32)
+    place, send, _real, _room = als._route_group(
+        rows, idx, mask, K, rows_v // n, n, n, False)
+    L = send.shape[-1]
+    group = (rows, place, rng.integers(1, 6, (1, B, K)).astype(np.float32),
+             mask, send)
+    groups = (tuple(mesh.put_stacked(x, als.plan_axes(mesh, "model"))
+                    for x in group),)
     U = mesh.put_model_sharded(als._init_factors(N_USERS, RANK, 1, 1, n))
     V = mesh.put_model_sharded(als._init_factors(N_ITEMS, RANK, 1, 2, n))
     cfg = _cfg(implicit, factor_sharding="model", solver="cholesky")
@@ -218,8 +276,19 @@ def test_each_chip_solves_its_share_and_holds_no_whole_table(
     # Gram and solve operands: a quarter (half) of the batch a chip
     assert f"{B // n},{RANK},{RANK}" in shapes
     assert f"{B},{RANK},{RANK}" not in shapes
-    # the gathered block crosses whole, and leaves the exchange divided
+    # the rows a chip's systems rate arrive as [n, L] from their owners
+    # and are placed as [B/n, K]; no chip holds a whole step's slots
     assert f"{B // n},{K},{RANK}" in shapes
+    assert f"{n},{L},{RANK}" in shapes
+    held = set(re.findall(r"\[([\d,]+)\]", text))
+    assert not {d for d in held
+                if d.startswith((f"{B},{K}", f"{B * K},", f"1,{B},{K}"))
+                or d == f"{B * K}"}
+    # one all-to-all of rows; nothing is reduce-scattered and no index
+    # crosses the chips (the row ids of the solved rows do: [.., B])
+    assert " all-to-all(" in text
+    assert "reduce-scatter" not in text
+    assert not re.search(r"s32\[[\d,]*\b%d\]\S* all-gather" % K, text)
     # a chip holds its shard of each table and never a whole one
     assert f"{rows_u // n},{RANK}" in shapes
     assert f"{rows_v // n},{RANK}" in shapes
@@ -266,9 +335,10 @@ def test_exchange_bytes_are_the_compiled_programs_collectives(
         assert got.pop("sent") == sent_bytes(want, n) > 0
         assert got == {op: ent["bytes"] for op, ent in want.items()
                        if op != "total"}
-        # what crosses: indices and solved rows gathered, the block
-        # reduce-scattered
-        assert got["all-gather"] > 0 and got["reduce-scatter"] > 0
+        # what crosses: row ids and solved rows gathered, the rated rows
+        # all-to-all from their owners
+        assert got["all-gather"] > 0 and got["all-to-all"] > 0
+        assert "reduce-scatter" not in got
     # the gauge holds the last train's: this module's last is implicit 1x2
     gauge = get_registry().get("pio_als_exchange_bytes")
     assert gauge is not None
@@ -320,13 +390,12 @@ def test_the_per_chip_sweep_takes_the_one_chip_solver():
 def test_gather_padding_keeps_batches_divisible():
     from predictionio_tpu.ops import als
     for b, k in ((131072, 8), (43692, 24), (5044, 208), (12, 65408)):
-        extra = als._gather_pad_rows(b, k, 4)
-        assert extra % 4 == 0 and (b + extra) % 4 == 0
+        # every chip's [b / 4, k] slots padded alike, for its own gather
+        extra = 4 * als._gather_pad_rows(b // 4, k)
+        assert (b + extra) % 4 == 0
         lo, hi = als._GATHER_STEP_256
-        assert extra == 0 or lo <= (b + extra) * k % als._GATHER_TILE <= hi
-    # one chip's padding is what it was
-    assert als._gather_pad_rows(87380, 24) == als._gather_pad_rows(87380, 24,
-                                                                   1)
+        assert extra == 0 or (lo <= (b + extra) // 4 * k % als._GATHER_TILE
+                              <= hi)
 
 
 TPU_HLO = """

@@ -104,14 +104,20 @@ def test_padded_rungs_gather_in_steps_of_256(sds, rank):
 # rec-amazon14-all-r200 on the mesh `model_mesh(4)` builds: both tables
 # row-sharded four ways, the batches divided over the same chips
 # (`ops/als._solve_sweep_per_chip`). The three widest rungs of each side's
-# plan (slots a step; `batch_multiple` 4), two scan steps each, beside a
-# quarter of both whole plans (4.18 GB of int32/float32 [B, K] arrays and
-# row ids: scratch shapes.py of PR 33, PERF.md section 4).
+# plan and the users' K 8 rung, three quarters of that side's slots
+# (systems and slots a step, `batch_multiple` 4; and L, the rows a chip
+# sends each chip in a step, as `_route_group` sets it for the plan at the
+# published counts, the same on two seeds: a builder's host run, PR 35), two
+# scan steps each, beside a quarter of both whole plans (4.18 GB of
+# int32/float32 [B, K] arrays and row ids and 0.74 GB of `send`: scratch
+# shapes.py of PR 33, PERF.md section 4).
 SHARDED_USERS, SHARDED_ITEMS, CHIPS = 20_980_000, 9_350_000, 4
-WIDEST = {"user": [(21848, 48), (26216, 40), (11916, 88)],
+WIDEST = {"user": [(21848, 48, 66080), (26216, 40, 64800),
+                   (11916, 88, 65056), (131072, 8, 20512)],
           # and the rung of the heaviest item, the longest gather of a row
-          "item": [(1640, 640), (2852, 368), (4372, 240), (12, 65408)]}
-QUARTER_OF_BOTH_PLANS = 4_180_357_216 // 4
+          "item": [(1640, 640, 66224), (2852, 368, 67104),
+                   (4372, 240, 66944), (12, 65408, 49440)]}
+QUARTER_OF_BOTH_PLANS = (4_180_357_216 + 739_518_464) // 4
 HBM = 15.75e9
 
 
@@ -139,12 +145,14 @@ def _sharded_half_sweep(mesh4, side):
                         else (SHARDED_ITEMS, SHARDED_USERS))
     both = ("data", "model")
     groups = []
-    for b, k in WIDEST[side]:
-        b += als._gather_pad_rows(b, k, CHIPS)
+    for b, k, L in WIDEST[side]:
+        b += CHIPS * als._gather_pad_rows(b // CHIPS, k)
         groups.append((shaped((2, b), jnp.int32, None, both),
                        shaped((2, b, k), jnp.int32, None, both, None),
                        shaped((2, b, k), jnp.float32, None, both, None),
-                       shaped((2, b, k), jnp.float32, None, both, None)))
+                       shaped((2, b, k), jnp.float32, None, both, None),
+                       shaped((2, CHIPS, CHIPS, L), jnp.int32, None, both,
+                              None, None)))
     return als._solve_sweep_per_chip.lower(
         shaped((als.table_rows(n_out, CHIPS), 200), jnp.float32, "model",
                None),
@@ -157,6 +165,27 @@ def _sharded_half_sweep(mesh4, side):
         table_axis="model", batch_axes=both).compile()
 
 
+def _routed_gathers(compiled):
+    """{scope: [(rows, rows a step)]} of the per-chip half-sweep's two
+    gathers a rung: the owners' (`pio.sweep.gather`) and the placement of
+    what arrived (`pio.sweep.gather.place`)."""
+    found = {"pio.sweep.gather": [], "pio.sweep.gather.place": []}
+    for line in compiled.as_text().splitlines():
+        scope = re.search(r"/(pio\.sweep\.gather[a-z.]*)/gather", line)
+        if "kind=kCustom" in line and scope:
+            rows = re.search(r"= bf16\[(\d+),200\]", line)
+            step = re.search(r'"integer_config":\{"integer":"(\d+)"\}', line)
+            found[scope.group(1)].append((int(rows.group(1)),
+                                          int(step.group(1))))
+    return found
+
+
+# argument_size_in_bytes + temp_size_in_bytes of PR 35's parent (80a925c)
+# for these rungs of each side: the [B, K, 256] block every chip gathered
+# of a whole step's slots is gone (off-chip compiles, PR 35)
+PARENT_PEAK = {"user": 14_005_321_216, "item": 12_073_402_368}
+
+
 @pytest.mark.parametrize("side", ["user", "item"])
 def test_sharded_half_sweep_fits_a_chip_and_holds_no_whole_table(mesh4,
                                                                  side):
@@ -166,8 +195,9 @@ def test_sharded_half_sweep_fits_a_chip_and_holds_no_whole_table(mesh4,
     compiled = _sharded_half_sweep(mesh4, side)
     text = compiled.as_text()
     m = compiled.memory_analysis()
-    assert (m.argument_size_in_bytes + m.temp_size_in_bytes
-            + QUARTER_OF_BOTH_PLANS) <= HBM
+    peak = m.argument_size_in_bytes + m.temp_size_in_bytes
+    assert peak + QUARTER_OF_BOTH_PLANS <= HBM
+    assert peak < PARENT_PEAK[side]
     n_counter = SHARDED_ITEMS if side == "user" else SHARDED_USERS
     whole = {als.table_rows(n, CHIPS) for n in (SHARDED_USERS,
                                                 SHARDED_ITEMS)}
@@ -181,22 +211,34 @@ def test_sharded_half_sweep_fits_a_chip_and_holds_no_whole_table(mesh4,
                           text)) <= 2        # the copy, and its relayout
     # the Pallas CG runs on the chip's own quarter of the systems
     assert "tpu_custom_call" in text
-    # only the exchanges the algorithm needs: indices and solved rows
-    # all-gathered, the gathered block reduce-scattered (the compiler's
-    # all-reduce + slice fusion), the row ids and the CG counts summed
+    # both gathers of a step at the compiler's 256-row step: the owners'
+    # of CHIPS * L rows, and the placement of a chip's own [B/n, K] slots,
+    # which where the rows that arrived are under ~80 MB (the users' K 8
+    # rung, the heaviest items') gets a form of its own, step "0", that
+    # the chip runs faster still (3.7 ns a row over the mix: PERF.md
+    # section 6, PR 35); no chip gathers a whole step's B * K slots
+    padded = [(b + CHIPS * als._gather_pad_rows(b // CHIPS, k), k, L)
+              for b, k, L in WIDEST[side]]
+    found = _routed_gathers(compiled)
+    assert found["pio.sweep.gather"] == [(CHIPS * L, 256)
+                                         for _b, _k, L in padded]
+    assert found["pio.sweep.gather.place"] == [
+        (b // CHIPS * k, 256 if CHIPS * L * 400 > 80e6 else 0)
+        for b, k, L in padded]
+    # only the exchanges the algorithm needs: the rated rows all-to-all
+    # from their owners, the solved rows all-gathered, the row ids and the
+    # CG counts summed; nothing is reduce-scattered, no index crosses
     ran = executed_collective_stats(compiled)
-    assert set(ran) <= {"all-gather", "reduce-scatter", "all-reduce",
-                        "total"}
+    assert set(ran) == {"all-to-all", "all-gather", "all-reduce", "total"}
     steps = 2
-    slots = sum((b + als._gather_pad_rows(b, k, CHIPS)) * k
-                for b, k in WIDEST[side])
-    # a quarter of each block in bfloat16, a row at its 200 columns or
-    # padded to 256 lanes (the compiler's own reduce-scatter or its fusion)
-    assert (steps * slots * 200 * 2 / CHIPS
-            <= ran["reduce-scatter"]["bytes"]
-            <= 1.02 * steps * slots * 256 * 2 / CHIPS)
-    small = sum(b + 32 for b, _k in WIDEST[side]) * 4 * steps + 64
-    assert ran.get("all-reduce", {"bytes": 0})["bytes"] <= small
+    # every chip receives CHIPS * L bfloat16 rows a step, at their 200
+    # columns (the compiler does not pad them to 256 lanes)
+    assert ran["all-to-all"]["bytes"] == steps * sum(
+        CHIPS * L * 200 * 2 for _b, _k, L in padded)
+    assert ran["all-gather"]["bytes"] == steps * sum(
+        b * 200 * 4 for b, _k, _L in padded)
+    small = sum(b + 32 for b, _k, _L in padded) * 4 * steps + 64
+    assert ran["all-reduce"]["bytes"] <= small
 
 
 def test_the_sentinel_checks_the_sharded_tables_in_place(mesh4):

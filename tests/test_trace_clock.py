@@ -186,14 +186,15 @@ def test_the_pallas_solves_say_primal_or_dual_and_their_size(K, scope,
 
 
 _EXCHANGE = {"pio.sweep.exchange.indices", "pio.sweep.exchange.rows",
-             "pio.sweep.exchange.solved"}
+             "pio.sweep.exchange.solved", "pio.sweep.gather.place"}
 
 
 @pytest.mark.parametrize("K,solve_scope", [
     (16, "pio.sweep.solve.primal"), (4, "pio.sweep.solve.dual")])
 def test_the_per_chip_sweep_names_its_exchanges(K, solve_scope):
-    """The half-sweep over row-sharded tables: the one-chip stages, and the
-    three exchanges that cross the chips beside them."""
+    """The half-sweep over row-sharded tables: the one-chip stages, the
+    three exchanges that cross the chips beside them, and the placing of
+    the rows a chip received (`pio.sweep.gather` is the owners' gather)."""
     import jax
     import jax.numpy as jnp
     from predictionio_tpu.ops import als
@@ -201,10 +202,13 @@ def test_the_per_chip_sweep_names_its_exchanges(K, solve_scope):
     mesh = make_mesh(devices=jax.devices()[:4], model_parallelism=4)
     rng = np.random.default_rng(0)
     N, B, rank = 2, 8, 8
-    group = (np.arange(N * B, dtype=np.int32).reshape(N, B),
-             rng.integers(0, 50, (N, B, K)).astype(np.int32),
-             rng.uniform(1, 5, (N, B, K)).astype(np.float32),
-             np.ones((N, B, K), np.float32))
+    rows = np.arange(N * B, dtype=np.int32).reshape(N, B)
+    mask = np.ones((N, B, K), np.float32)
+    place, send, _real, _room = als._route_group(
+        rows, rng.integers(0, 50, (N, B, K)).astype(np.int32), mask, K,
+        52 // 4, 4, 4, False)
+    group = (rows, place, rng.uniform(1, 5, (N, B, K)).astype(np.float32),
+             mask, send)
     text = als._solve_sweep_per_chip.trace(
         jnp.zeros((44, rank)), jnp.ones((52, rank)), None, (group,),
         np.float32(0.01), np.float32(1.0), nratings_reg=True,
