@@ -565,9 +565,14 @@ def run(args, phases, procs, work, env, cache_dir):
     for name, k in (("read", "read"), ("prepare", "prepare")):
         phases.add(f"  train: {name}", report["stages"][k], "train report")
     for name, k in (("plan", "plan_s"), ("upload", "upload_s"),
-                    ("compile", "compile_s"), ("sweeps", "sweeps_s"),
-                    ("fetch", "fetch_s")):
+                    ("iterations", "iters_s"), ("fetch", "fetch_s")):
         phases.add(f"  train: {name}", algo[k], "train report")
+    # the set-up account (ISSUE 37): what the train's executables took to
+    # trace, lower, compile or load from the cache
+    setup = algo["setup"]["total"]
+    for stage in ("trace", "lower", "compile", "cache_load"):
+        phases.add(f"  train: {stage} (setup account)",
+                   setup[f"{stage}_s"], "train report")
     print(f"[chip_smoke] train process reports platform="
           f"{report['platform']} device_kind={report['device_kind']} "
           f"n={report['device_count']} solver={algo['solver']} "

@@ -24,8 +24,10 @@ JAX005 lint rules recognize as compile-plane-routed.
 
 Instrumentation (obs registry):
 
-- ``pio_aot_compile_seconds_total{executable,bucket}`` — AOT compile
-  wall per bucket (the deploy-time cost the cache amortizes);
+- a compile here runs under ``costmon.executable(label, bucket=...)``, so
+  its trace, lowering, compile or cache-load seconds land in
+  ``pio_compile_seconds_total{executable,stage}`` and its record in the
+  set-up account with the bucket (obs/costmon);
 - ``pio_aot_dispatch_hits_total{executable}`` /
   ``..._misses_total`` / ``..._fallbacks_total`` — warmed vs unwarmed
   vs aval-mismatch dispatches;
@@ -76,15 +78,9 @@ class AOTRegistry:
         self._failed: set = set()
         self._threads: set = set()
         self._jits: Dict[str, Any] = {}
-        self.compile_seconds = 0.0
-        self.compile_count = 0
         if registry is None:
             from predictionio_tpu.obs import get_registry
             registry = get_registry()
-        self._c_compile_s = registry.counter(
-            "pio_aot_compile_seconds_total",
-            "AOT lower+compile wall time by executable and shape "
-            "bucket", labelnames=("executable", "bucket"))
         self._c_hits = registry.counter(
             "pio_aot_dispatch_hits_total",
             "dispatches answered by a held AOT executable (no trace, "
@@ -162,19 +158,13 @@ class AOTRegistry:
         try:
             builder = self._builders[label]
             fn, args, statics = builder(**dims)
-            t0 = time.perf_counter()
             # compile attribution: the AOT warm IS this executable's
-            # compile — charge its label, and let the persistent cache
-            # answer it when a previous process already paid
-            with costmon.executable(label):
+            # compile — charge its label and bucket, and let the
+            # persistent cache answer it when a previous process paid
+            with costmon.executable(label, bucket=bucket_label(dims)):
                 compiled = fn.lower(*args, **(statics or {})).compile()
-            dt = time.perf_counter() - t0
-            self._c_compile_s.labels(
-                executable=label, bucket=bucket_label(dims)).inc(dt)
             with self._lock:
                 self._compiled[key] = compiled
-                self.compile_seconds += dt
-                self.compile_count += 1
             return compiled
         except Exception:
             with self._lock:
@@ -207,7 +197,7 @@ class AOTRegistry:
         counted per request and a 1-in-N sampled sync books true
         device seconds to ``pio_device_time_seconds_total{label}``."""
         if not aot_enabled():
-            return device_timed(label, fallback, *args)
+            return self._fallback(label, fallback, args)
         compiled = self._compiled.get((label, bucket_key(dims)))
         if compiled is not None:
             try:
@@ -224,7 +214,15 @@ class AOTRegistry:
         else:
             self._c_misses.labels(executable=label).inc()
             self.ensure(label, dims, background=True)
-        return device_timed(label, fallback, *args)
+        return self._fallback(label, fallback, args)
+
+    @staticmethod
+    def _fallback(label: str, fallback: Callable, args):
+        """The jit fallback, whose compile (a bucket not yet held) books
+        under ``label`` unless a caller's scope already names it."""
+        from predictionio_tpu.obs import costmon
+        with costmon.executable(label, defer_to_outer=True):
+            return device_timed(label, fallback, *args)
 
     # -- shared cached-jit surface ------------------------------------------
     def adopt(self, key: str, fn) -> Any:
@@ -275,7 +273,9 @@ class AOTRegistry:
     def snapshot(self) -> Dict[str, Any]:
         """Registry state for /stats.json and `pio status --telemetry`:
         executables resident, buckets compiled per label, jit handles,
-        compile seconds, dispatch hit/miss counts since start."""
+        the compile requests and seconds of the labels it has specs for
+        (from obs/costmon's family, every stage: jit fallbacks and
+        promotions included), dispatch hit/miss counts since start."""
         from predictionio_tpu.obs import costmon
 
         def _vals(counter):
@@ -293,8 +293,6 @@ class AOTRegistry:
                 "bucketsCompiled": {k: sorted(v)
                                     for k, v in sorted(by_label.items())},
                 "sharedJits": sorted(self._jits),
-                "compileCount": self.compile_count,
-                "compileSeconds": round(self.compile_seconds, 4),
                 "inflight": len(self._inflight),
                 "failedBuckets": len(self._failed),
             }
@@ -306,6 +304,14 @@ class AOTRegistry:
         out["hitRate"] = (round(sum(hits.values()) / total, 4)
                           if total else None)
         out["pcache"] = costmon.pcache_totals()
+        labels = set(self._builders)
+        requests = costmon.compile_requests()
+        out["compileCount"] = int(sum(
+            n for by in requests.values()
+            for label, n in by.items() if label in labels))
+        out["compileSeconds"] = round(sum(
+            sum(st.values()) for label, st in
+            costmon.compile_stage_seconds().items() if label in labels), 4)
         return out
 
     def clear(self) -> None:

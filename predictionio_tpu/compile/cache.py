@@ -17,10 +17,11 @@ whole product:
   the serve/fold programs this repo cares about are small and fast to
   compile on CPU but minutes on TPU; caching everything costs little
   and makes the CPU test container exercise the same code path.
-- **Counters** — ``pio_compile_pcache_hits_total{executable}`` /
-  ``..._misses_total{executable}``: jax fires cache hit/miss events on
-  the compiling thread, so obs/costmon's executable label attributes
-  them to the dispatch scope that paid (or skipped) the compile.
+- **Counters** — ``pio_compile_requests_total{executable,outcome}``
+  (``cache_loaded`` / ``compiled``) and the ``cache_load`` stage of
+  ``pio_compile_seconds_total``: jax fires the cache's events on the
+  compiling thread, so obs/costmon's executable label attributes them
+  to the dispatch scope that loaded (or compiled) the executable.
 
 ``enable_persistent_cache()`` is idempotent and safe before or after
 jax's first use — config updates apply to every later compile.

@@ -47,8 +47,8 @@ class BulkLoadStats:
     h2d_bytes: int = 0
     h2d_overlap_frac: float = 1.0
     read_mb_s: float = 0.0
-    #: XLA compiles / compile seconds observed DURING the steady
-    #: streaming phase (jaxmon counter deltas) — expected 0
+    #: compile requests / their seconds observed DURING the steady
+    #: streaming phase (obs/costmon's family deltas) — expected 0
     steady_compiles: int = 0
     steady_compile_s: float = 0.0
 
@@ -83,7 +83,7 @@ class BulkLoadExecutor:
         self.chunk_rows = chunk_rows
         self.queue_depth = queue_depth
         self.slots = slots
-        # install the jax.monitoring listeners HERE, not in run():
+        # install the jax.monitoring listener HERE, not in run():
         # registration (COST003) belongs at init, and run() is a
         # hot-path root — its per-chunk loop must stay alloc-free
         from predictionio_tpu.obs import jaxmon
@@ -96,15 +96,6 @@ class BulkLoadExecutor:
         self._m_loads = reg.counter(
             "pio_dataplane_loads_total",
             "Completed dataplane bulk-load runs")
-        # compile counters exist whether or not jaxmon is installed;
-        # resolving here keeps the steady-phase delta read off the
-        # chunk path
-        self._m_compiles = reg.counter(
-            "pio_jax_compiles_total",
-            "Backend compile events observed via jax.monitoring")
-        self._m_compile_s = reg.counter(
-            "pio_jax_compile_seconds_total",
-            "Cumulative backend compile wall time")
 
     def run(self, app_name: str, channel_name: Optional[str] = None,
             property_field: Optional[str] = None,
@@ -129,8 +120,8 @@ class BulkLoadExecutor:
             self.store, app_name, channel_name=channel_name,
             property_field=property_field, chunk_rows=self.chunk_rows,
             queue_depth=self.queue_depth, **filters)
-        compiles0 = self._m_compiles.value
-        compile_s0 = self._m_compile_s.value
+        from predictionio_tpu.obs import costmon
+        compiled0 = costmon.compile_totals()
         t_start = time.perf_counter()
         with reader:
             for chunk in reader:
@@ -151,8 +142,9 @@ class BulkLoadExecutor:
                     if cols:
                         stager.stage(cols)
         # end of steady phase: everything past here is finalize
-        steady_compiles = self._m_compiles.value - compiles0
-        steady_compile_s = self._m_compile_s.value - compile_s0
+        compiled = costmon.compile_totals()
+        steady_compiles = compiled["requests"] - compiled0["requests"]
+        steady_compile_s = compiled["seconds"] - compiled0["seconds"]
         if stager is not None:
             result.segments = stager.finish()
         st = result.stats

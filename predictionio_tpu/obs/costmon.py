@@ -1,25 +1,41 @@
-"""Per-executable compile/cost attribution (extends obs/jaxmon).
+"""Per-executable compile/cost attribution, and the set-up account.
 
-ISSUE 6 tentpole piece 3. jaxmon counts compiles process-wide; that
-tells an operator THAT a warm-up of minutes exists, not where it
-goes. This module attributes compile wall time to a stable
-**executable label** — the handful of jitted programs the system
-actually runs (``als_sweep``, ``fold_side``, ``batch_predict``,
-``gates_probe``) — which is the evidence base for the AOT/compile-
-cache ROADMAP item: the label whose seconds dominate is the one to
-AOT-lower first.
+ISSUE 6 tentpole piece 3; since ISSUE 37 the process's one compile
+listener. This module attributes compile time to a stable **executable
+label** — the handful of jitted programs the system actually runs
+(``als_sweep``, ``fold_side``, ``batch_predict``, ``gates_probe``) —
+which is the evidence base for the AOT/compile-cache ROADMAP item: the
+label whose seconds dominate is the one to AOT-lower first.
 
-Mechanics: call sites wrap their jit dispatch in ``executable(label)``.
-jax.monitoring fires compile-duration events synchronously on the
-compiling thread, so a contextvar label + a thread-local accumulator
-attribute each event to the scope that triggered it:
+Mechanics: call sites wrap their jit dispatch in ``executable(label)``
+(or a whole phase in ``phase(label)``). jax.monitoring fires its compile
+events synchronously on the compiling thread, so a contextvar label and a
+thread-local request attribute each to the scope that triggered it. One
+compile request is, in jax's order: the jaxpr trace
+(``jaxpr_trace_duration``; nested jits report their own, inside the
+outer one), the MLIR lowering (``jaxpr_to_mlir_module_duration``), the
+persistent cache's ``cache_hits`` and ``cache_retrieval_time_sec`` on a
+read or ``cache_misses`` once the compiled executable is written (no
+event with no cache), and ``backend_compile_duration``, which holds the
+read or the compile and closes the request. From them:
 
-- ``pio_compile_executable_seconds_total{executable}`` — compile wall;
+- ``pio_compile_seconds_total{executable, stage}``: ``trace``,
+  ``lower``, ``compile`` (backend seconds less the retrieval) and
+  ``cache_load`` (the retrieval);
+- ``pio_compile_requests_total{executable, outcome}``: ``compiled`` or
+  ``cache_loaded`` (a climbing count in steady state = shape churn on
+  that executable, the classic silent TPU perf bug);
+- one ``setup.compile`` record per request on ``obs.trace.TRACER``
+  (``COMPILE_FIELDS``), inside a ``pio.compile`` annotation (attribute
+  ``executable``) from the request's first event to its close;
 - ``pio_compile_cache_hits_total{executable}`` /
-  ``pio_compile_cache_misses_total{executable}`` — a scope that
-  triggered no backend compile was answered by XLA's jit cache (a
-  climbing miss count in steady state = shape churn on that
-  executable, the classic silent TPU perf bug).
+  ``pio_compile_cache_misses_total{executable}``: an ``executable()``
+  scope that triggered no backend compile was answered by XLA's jit
+  cache.
+
+``setup_account()`` summarizes the compile records with the upload
+records ``utils/device_cache`` writes: ``/stats.json``'s ``setup``,
+``als_train``'s telemetry, ``pio status --telemetry``.
 
 ``analyze_jit`` banks XLA ``cost_analysis()`` FLOPs/bytes per label
 (``pio_executable_flops{executable}`` /
@@ -87,18 +103,33 @@ BATCH_PREDICT_MASKED = "batch_predict_masked"
 #: resident filter data (ops/similarity.composed_top_k_batch_begin)
 BATCH_PREDICT_COMPOSED = "batch_predict_composed"
 GATES_PROBE = "gates_probe"
+#: the training path's set-up (ops/als): the plan upload's device work,
+#: the implicit Gram (+ eigh), the per-chip sweep's recompile for its
+#: exchange bytes, and everything else als_train compiles
+ALS_UPLOAD = "als_upload"
+ALS_GRAM = "als_gram"
+ALS_SWEEP_EXCHANGE = "als_sweep_exchange"
+ALS_TRAIN = "als_train"
 
 _label_ctx: contextvars.ContextVar = contextvars.ContextVar(
     "pio_exec_label", default=None)
+#: the shape bucket of an ``executable()`` scope (the AOT registry's)
+_bucket_ctx: contextvars.ContextVar = contextvars.ContextVar(
+    "pio_exec_bucket", default="")
+#: a phase's label (``phase()``): what a compile with no executable
+#: scope of its own is booked under; an executable scope never defers
+#: to it
+_phase_ctx: contextvars.ContextVar = contextvars.ContextVar(
+    "pio_exec_phase", default=None)
 _tls = threading.local()
+UNLABELED = "unlabeled"
 
 _lock = threading.Lock()
 _installed = False
-_c_seconds = None
+_c_stage_s = None
+_c_requests = None
 _c_hits = None
 _c_misses = None
-_c_pc_hits = None
-_c_pc_misses = None
 _g_flops = None
 _g_bytes = None
 _g_cg_ratio = None
@@ -110,28 +141,28 @@ _g_occupancy = None
 _g_tenant_occ = None
 
 
-def _is_backend_compile(name: str) -> bool:
-    # only the actual XLA compile: trace/lowering durations fire on
-    # cache hits too and would misclassify every hit as a miss
-    return "backend_compile" in name
-
-
 def install(registry=None):
     """Register the listener + gauges. Idempotent; never raises."""
-    global _installed, _c_seconds, _c_hits, _c_misses, _g_flops, \
-        _g_bytes, _c_pc_hits, _c_pc_misses, _c_dispatch_s, \
-        _c_device_s, _c_device_syncs, _g_occupancy, _g_tenant_occ, \
-        _g_cg_ratio, _g_exchange
+    global _installed, _c_stage_s, _c_requests, _c_hits, _c_misses, \
+        _g_flops, _g_bytes, _c_dispatch_s, _c_device_s, \
+        _c_device_syncs, _g_occupancy, _g_tenant_occ, _g_cg_ratio, \
+        _g_exchange
     with _lock:
         if _installed:
             return
         _installed = True
         reg = registry or get_registry()
-        _c_seconds = reg.counter(
-            "pio_compile_executable_seconds_total",
-            "XLA backend-compile wall time attributed to the "
-            "executable label whose dispatch triggered it",
-            labelnames=("executable",))
+        _c_stage_s = reg.counter(
+            "pio_compile_seconds_total",
+            "Seconds of each stage of the compile requests an executable "
+            "label's dispatches made: trace (jaxpr), lower (MLIR), "
+            "compile (XLA backend, less the persistent-cache read) and "
+            "cache_load (that read)", labelnames=("executable", "stage"))
+        _c_requests = reg.counter(
+            "pio_compile_requests_total",
+            "Compile requests by executable label and outcome: compiled "
+            "(XLA ran) or cache_loaded (the persistent cache answered)",
+            labelnames=("executable", "outcome"))
         _c_hits = reg.counter(
             "pio_compile_cache_hits_total",
             "executable() scopes answered without a backend compile "
@@ -161,17 +192,6 @@ def install(registry=None):
             "compiled program (parallel/collective_stats), by side and "
             "collective kind; absent where tables are not sharded",
             labelnames=("side", "op"))
-        _c_pc_hits = reg.counter(
-            "pio_compile_pcache_hits_total",
-            "persistent compilation-cache hits (an executable "
-            "deserialized from disk instead of compiling) by the "
-            "executable label that dispatched it",
-            labelnames=("executable",))
-        _c_pc_misses = reg.counter(
-            "pio_compile_pcache_misses_total",
-            "persistent compilation-cache misses (a fresh XLA compile "
-            "whose result was then written to the cache) by executable",
-            labelnames=("executable",))
         reg.gauge_func(
             "pio_hbm_table_bytes",
             "Device bytes held by each named residency slot in "
@@ -220,33 +240,115 @@ def install(registry=None):
         logger.debug("costmon monitoring listener unavailable: %s", e)
 
 
+# -- the compile listener (ISSUE 37) ---------------------------------
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_HIT = "/jax/compilation_cache/cache_hits"
+_MISS = "/jax/compilation_cache/cache_misses"
+STAGES = ("trace", "lower", "compile", "cache_load")
+COMPILED, CACHE_LOADED = "compiled", "cache_loaded"
+
+
+class _Request:
+    """One compile request in the making, on the thread that makes it."""
+
+    __slots__ = ("t_start", "label", "trace_s", "lower_s", "hit", "load_s",
+                 "ann")
+
+    def __init__(self, t_start: float, label: str):
+        self.t_start, self.label = t_start, label
+        self.trace_s = self.lower_s = self.load_s = 0.0
+        self.hit = None
+        self.ann = None
+
+
+def _label() -> str:
+    return _label_ctx.get() or _phase_ctx.get() or UNLABELED
+
+
+def _request(t_start: float, renew: bool = False) -> _Request:
+    """The calling thread's open request; a new one where there is none,
+    where the open one was made under another label, or where ``renew``
+    and the open one has gone past its trace (a request lowered and never
+    compiled: the jit's in-memory cache held the executable)."""
+    req = getattr(_tls, "req", None)
+    label = _label()
+    if req is not None and (req.label != label or renew and (
+            req.lower_s or req.hit is not None)):
+        _close_annotation(req)
+        req = None
+    if req is None:
+        req = _tls.req = _Request(t_start, label)
+        from predictionio_tpu.obs.trace import _annotate
+        ann = _annotate("compile", {"executable": label})
+        try:
+            ann.__enter__()
+            req.ann = ann
+        except Exception:
+            pass
+    req.t_start = min(req.t_start, t_start)
+    return req
+
+
+def _close_annotation(req: _Request) -> None:
+    if req.ann is not None:
+        ann, req.ann = req.ann, None
+        try:
+            ann.__exit__(None, None, None)
+        except Exception:
+            pass
+
+
 def _on_duration(name, secs, *a, **kw):
-    if not _is_backend_compile(name):
-        return
     try:
         secs = float(secs)
     except (TypeError, ValueError):
         return
-    _tls.compile_s = getattr(_tls, "compile_s", 0.0) + secs
-    label = _label_ctx.get() or "unlabeled"
-    _c_seconds.labels(executable=label).inc(secs)
+    if name == _TRACE:
+        # nested jits report their traces first, each inside the outer
+        # one: the outer trace is the longest of the request's
+        now = time.perf_counter()
+        req = _request(now - secs, renew=True)
+        req.trace_s = max(req.trace_s, secs)
+    elif name == _LOWER:
+        now = time.perf_counter()
+        _request(now - secs).lower_s += secs
+    elif name == _RETRIEVAL:
+        _request(time.perf_counter() - secs).load_s += secs
+    elif name == _BACKEND:
+        _close_request(secs)
 
 
 def _on_event(name, *a, **kw):
-    """Persistent compilation-cache hit/miss events (ISSUE 9): jax
-    fires them synchronously on the compiling thread, so the contextvar
-    label attributes each to the executable whose dispatch consulted
-    the disk cache."""
-    if not name.startswith("/jax/compilation_cache/cache_"):
-        return
-    label = _label_ctx.get() or "unlabeled"
+    if name == _HIT or name == _MISS:
+        _request(time.perf_counter()).hit = name == _HIT
+
+
+def _close_request(backend_s: float) -> None:
+    """``backend_compile_duration`` fired: the request is whole. Book its
+    stages and write its record."""
+    t_end = time.perf_counter()
+    req = _request(t_end - backend_s)
+    _tls.req = None
+    _close_annotation(req)
+    _tls.compile_s = getattr(_tls, "compile_s", 0.0) + backend_s
+    load_s = min(req.load_s, backend_s) if req.hit else 0.0
+    stages = (req.trace_s, req.lower_s, backend_s - load_s, load_s)
+    outcome = CACHE_LOADED if req.hit else COMPILED
+    label = req.label
     try:
-        if name.endswith("cache_hits"):
-            _c_pc_hits.labels(executable=label).inc()
-        elif name.endswith("cache_misses"):
-            _c_pc_misses.labels(executable=label).inc()
+        for stage, secs in zip(STAGES, stages):
+            _c_stage_s.labels(executable=label, stage=stage).inc(secs)
+        _c_requests.labels(executable=label, outcome=outcome).inc()
+        from predictionio_tpu.obs.trace import COMPILE, TRACER
+        TRACER.record(COMPILE, (req.t_start, t_end, label,
+                                _bucket_ctx.get(),
+                                threading.current_thread().name)
+                      + stages + (outcome,))
     except Exception:
-        pass
+        logger.debug("compile record dropped", exc_info=True)
 
 
 def _hbm_table_samples():
@@ -264,8 +366,26 @@ def _table_rows_samples():
 
 
 @contextmanager
-def executable(label: str, defer_to_outer: bool = False):
+def phase(label: str):
+    """Book every compile inside this scope that no ``executable()``
+    scope claims under ``label``: the small programs a phase such as a
+    train or a deploy runs around its executables (syncs, slices, the
+    sentinel's checks). Counts nothing itself; for set-up paths, not
+    per-dispatch ones."""
+    if not _installed:
+        install()
+    token = _phase_ctx.set(label)
+    try:
+        yield
+    finally:
+        _phase_ctx.reset(token)
+
+
+@contextmanager
+def executable(label: str, defer_to_outer: bool = False,
+               bucket: str = ""):
     """Attribute any compile triggered inside this scope to ``label``
+    (and ``bucket``, the AOT registry's shape bucket, on its records)
     and count the scope as a cache hit/miss. Cheap enough for per-
     window dispatch paths (~1-2 µs; one contextvar set/reset and two
     float reads).
@@ -282,6 +402,7 @@ def executable(label: str, defer_to_outer: bool = False):
         yield                      # the outer scope owns all accounting
         return
     token = _label_ctx.set(label)
+    b_token = _bucket_ctx.set(bucket) if bucket else None
     before = getattr(_tls, "compile_s", 0.0)
     ok = False
     try:
@@ -289,6 +410,8 @@ def executable(label: str, defer_to_outer: bool = False):
         ok = True
     finally:
         _label_ctx.reset(token)
+        if b_token is not None:
+            _bucket_ctx.reset(b_token)
         # clean exits only: a body that raises before dispatching
         # (fault injection, malformed golden query) compiled nothing —
         # counting it as a "hit" would inflate the ratio the AOT /
@@ -645,9 +768,45 @@ def _labeled_values(counter) -> Dict[str, float]:
     return out
 
 
+def compile_stage_seconds() -> Dict[str, Dict[str, float]]:
+    """{label: {stage: seconds}} from ``pio_compile_seconds_total``."""
+    out: Dict[str, Dict[str, float]] = {}
+    if _c_stage_s is None:
+        return out
+    for labels, v in _c_stage_s.samples():
+        if labels:
+            by = out.setdefault(labels["executable"], {})
+            by[labels["stage"]] = by.get(labels["stage"], 0.0) + v
+    return out
+
+
 def compile_seconds_by_executable() -> Dict[str, float]:
-    return {k: round(v, 4)
-            for k, v in _labeled_values(_c_seconds).items()}
+    """{label: backend-compile seconds}: XLA's own, with what the
+    persistent cache's reads took where it answered (the benchmark's
+    filtered serve job reads this)."""
+    return {k: round(st.get("compile", 0.0) + st.get("cache_load", 0.0), 4)
+            for k, st in compile_stage_seconds().items()}
+
+
+def compile_requests() -> Dict[str, Dict[str, float]]:
+    """{outcome: {label: requests}} from ``pio_compile_requests_total``."""
+    out: Dict[str, Dict[str, float]] = {COMPILED: {}, CACHE_LOADED: {}}
+    if _c_requests is None:
+        return out
+    for labels, v in _c_requests.samples():
+        if labels:
+            by = out.setdefault(labels["outcome"], {})
+            by[labels["executable"]] = by.get(labels["executable"], 0) + v
+    return out
+
+
+def compile_totals() -> Dict[str, float]:
+    """Process-wide compile requests and their seconds over every
+    stage: the steady-phase delta the dataplane reads."""
+    req = compile_requests()
+    return {"requests": sum(v for by in req.values() for v in by.values()),
+            "seconds": sum(v for st in compile_stage_seconds().values()
+                           for v in st.values())}
 
 
 def cache_counts() -> Dict[str, Dict[str, float]]:
@@ -656,14 +815,73 @@ def cache_counts() -> Dict[str, Dict[str, float]]:
             "misses": _labeled_values(_c_misses)}
 
 
-def pcache_counts() -> Dict[str, Dict[str, float]]:
-    """Persistent-cache {"hits": {label: n}, "misses": {label: n}}."""
-    return {"hits": _labeled_values(_c_pc_hits),
-            "misses": _labeled_values(_c_pc_misses)}
-
-
 def pcache_totals() -> Dict[str, float]:
-    """Process-wide persistent-cache hit/miss totals (all labels)."""
-    c = pcache_counts()
-    return {"hits": sum(c["hits"].values()),
-            "misses": sum(c["misses"].values())}
+    """Process-wide persistent-cache answers ("hits": requests the cache
+    loaded) and compiles ("misses": requests XLA compiled)."""
+    req = compile_requests()
+    return {"hits": sum(req[CACHE_LOADED].values()),
+            "misses": sum(req[COMPILED].values())}
+
+
+def setup_account(since: Optional[float] = None) -> Dict[str, object]:
+    """The set-up account from ``TRACER``'s ``setup.compile`` and
+    ``setup.upload`` records (those starting at or after ``since``, a
+    ``time.perf_counter()`` stamp; all that the rings hold by default):
+    per executable label its stage seconds and outcomes, per table its
+    uploads, bytes, host preparation and seconds from the upload's start
+    to its arrays being ready, and the totals. Unlabelled compiles are
+    not the program's and are left out. ``/stats.json``'s ``setup``,
+    ``als_train``'s telemetry, ``pio status --telemetry``."""
+    from predictionio_tpu.obs.trace import (COMPILE, COMPILE_FIELDS, TRACER,
+                                            UPLOAD, UPLOAD_FIELDS)
+    t0 = float("-inf") if since is None else since
+    execs: Dict[str, dict] = {}
+    for rec in TRACER.recent(COMPILE):
+        r = dict(zip(COMPILE_FIELDS, rec))
+        if r["t_start"] < t0 or r["executable"] == UNLABELED:
+            continue
+        e = execs.setdefault(r["executable"], dict(
+            {f"{st}_s": 0.0 for st in STAGES},
+            **{COMPILED: 0, CACHE_LOADED: 0}))
+        for st in STAGES:
+            e[f"{st}_s"] += r[f"{st}_s"]
+        e[r["outcome"]] += 1
+    tables: Dict[str, dict] = {}
+    spans = []
+    for rec in TRACER.recent(UPLOAD):
+        r = dict(zip(UPLOAD_FIELDS, rec))
+        if r["t_start"] < t0:
+            continue
+        t = tables.setdefault(r["table"], {
+            "uploads": 0, "bytes": 0, "host_prep_s": 0.0, "ready_s": 0.0})
+        t["uploads"] += 1
+        t["bytes"] += r["bytes"]
+        t["host_prep_s"] += r["host_prep_s"]
+        t["ready_s"] += r["t_ready"] - r["t_start"]
+        spans.append((r["t_start"], r["t_ready"]))
+    total = {f"{st}_s": sum(e[f"{st}_s"] for e in execs.values())
+             for st in STAGES}
+    total.update(
+        {o: sum(e[o] for e in execs.values())
+         for o in (COMPILED, CACHE_LOADED)},
+        upload_bytes=sum(t["bytes"] for t in tables.values()),
+        upload_ready_s=sum(t["ready_s"] for t in tables.values()),
+        upload_wall_s=_union_seconds(spans))
+    return {"executables": {k: _rounded(e) for k, e in sorted(execs.items())},
+            "tables": {k: _rounded(t) for k, t in sorted(tables.items())},
+            "total": _rounded(total)}
+
+
+def _rounded(d: dict) -> dict:
+    return {k: round(v, 4) if isinstance(v, float) else v
+            for k, v in d.items()}
+
+
+def _union_seconds(spans) -> float:
+    """Seconds covered by the union of (start, end) intervals."""
+    covered, end = 0.0, float("-inf")
+    for a, b in sorted(spans):
+        if b > end:
+            covered += b - max(a, end)
+            end = b
+    return covered

@@ -56,7 +56,7 @@ DEFAULT_WATCHED = (
     "pio_ingest_spilled_total",
     "pio_guard_gate_rejects_total",
     "pio_guard_rollbacks_total",
-    "pio_jax_compiles_total",
+    "pio_compile_requests_total",
 )
 
 

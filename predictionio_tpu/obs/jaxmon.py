@@ -1,15 +1,12 @@
-"""JAX runtime telemetry: compiles, host<->device bytes, device memory.
+"""JAX runtime telemetry: host<->device bytes, device memory.
 
 ISSUE 2 tentpole piece 3. TPU-scale systems (ALX, arxiv 2112.02194)
 make per-stage transfer accounting a first-class metric because the
 host<->device link — not the MXU — can bound fold-in and serve
-latency. Three instruments, all on the process-wide registry so both
-HTTP servers' ``/metrics`` expose them:
+latency. Two instruments, on the process-wide registry so both HTTP
+servers' ``/metrics`` expose them (compiles are obs/costmon's, whose
+listener ``install()`` puts in place: the process has one):
 
-- **compile counters** via ``jax.monitoring`` event listeners (every
-  event whose name mentions a compilation, plus cumulative backend
-  compile seconds) — a climbing compile count in steady-state serving
-  means shape churn (the classic silent TPU perf bug);
 - **transfer byte counters** incremented by the code paths that
   actually move data (``utils/device_cache.cached_put``, the ALS
   plan upload, ``utils/arrays.to_host``), so fold-in's per-tick upload
@@ -34,8 +31,6 @@ from predictionio_tpu.obs.metrics import get_registry
 
 _lock = threading.Lock()
 _installed = False
-_m_compiles = None
-_m_compile_s = None
 _m_h2d = None
 _m_d2h = None
 # per-thread upload accounting: lets a caller price ITS OWN uploads
@@ -44,21 +39,10 @@ _m_d2h = None
 _tls = threading.local()
 
 
-def _is_compile_event(name: str) -> bool:
-    return "compil" in name  # compile / compilation / compiling
-
-
 def _register_metrics(reg):
     """One-time family registration — runs once per process under
     ``install()``'s flag+lock, never per request (COST003 init-time)."""
-    global _m_compiles, _m_compile_s, _m_h2d, _m_d2h
-    _m_compiles = reg.counter(
-        "pio_jax_compiles_total",
-        "XLA compilation events observed via jax.monitoring")
-    _m_compile_s = reg.counter(
-        "pio_jax_compile_seconds_total",
-        "Cumulative compile wall time (jaxpr trace + lowering + backend "
-        "compile or persistent-cache retrieval)")
+    global _m_h2d, _m_d2h
     _m_h2d = reg.counter(
         "pio_jax_host_to_device_bytes_total",
         "Bytes uploaded host->device by instrumented paths "
@@ -70,31 +54,17 @@ def _register_metrics(reg):
 
 
 def install(registry=None):
-    """Register the JAX listeners and counters on the process registry
-    (or ``registry``). Idempotent; initializes no backend."""
+    """Register the transfer counters on the process registry (or
+    ``registry``) and the compile listener (obs/costmon). Idempotent;
+    initializes no backend."""
     global _installed
     with _lock:
         if _installed:
             return
         _installed = True
         _register_metrics(registry or get_registry())
-    from jax import monitoring
-
-    def _on_event(name, *a, **kw):
-        if _is_compile_event(name):
-            _m_compiles.inc()
-
-    def _on_duration(name, secs, *a, **kw):
-        # the three stages of a compile (jaxpr trace, lowering, backend
-        # compile — the last includes a persistent-cache retrieval).
-        # The /jax/compilation_cache/* durations are not compile time:
-        # compile_time_saved_sec is an estimate that goes negative, and
-        # cache_retrieval_time_sec is already inside backend_compile.
-        if name.startswith("/jax/core/compile/"):
-            _m_compile_s.inc(float(secs))
-
-    monitoring.register_event_listener(_on_event)
-    monitoring.register_event_duration_secs_listener(_on_duration)
+    from predictionio_tpu.obs import costmon
+    costmon.install()
 
 
 def install_device_memory_gauge(registry=None):

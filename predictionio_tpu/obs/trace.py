@@ -35,7 +35,9 @@ The serving account (ISSUE 25): beside the trace rings the tracer keeps
 two bounded rings of plain tuples, one per dispatch and one per request
 (``DISPATCH_FIELDS`` / ``REQUEST_FIELDS``), written by the batcher, the
 engine server and the HTTP layer and read with ``recent(kind, n)``.
-They outlive the server that wrote them.
+They outlive the server that wrote them. The set-up account (ISSUE 37)
+is two more: one record per compile request (``COMPILE_FIELDS``) and one
+per upload of a table or a plan (``UPLOAD_FIELDS``).
 
 Hot-path cost: ``span()`` outside any active trace returns a shared
 no-op context manager (~0.3 µs); inside a trace it is one object append,
@@ -159,7 +161,19 @@ DISPATCH_FIELDS = ("seq", "t_enqueue", "t_dequeue", "t_closed", "t_gate",
                    "bucket", "sync_s", "tenant")
 REQUEST_FIELDS = ("t_start", "t_enqueue", "t_result", "t_written",
                   "dispatch_seq", "tenant")
-_ACCOUNT_CAPACITY = {DISPATCH: 4096, REQUEST: 16384}
+# The set-up account (ISSUE 37), the same kind of ring: one record per
+# compile request, written by obs/costmon's listener on the compiling
+# thread, and one per upload at utils/device_cache.cached_put* and
+# ops/als._upload_plan, written once the uploaded arrays are ready.
+COMPILE = "setup.compile"
+UPLOAD = "setup.upload"
+COMPILE_FIELDS = ("t_start", "t_end", "executable", "bucket", "thread",
+                  "trace_s", "lower_s", "compile_s", "cache_load_s",
+                  "outcome")
+UPLOAD_FIELDS = ("t_start", "t_put", "t_ready", "table", "bytes",
+                 "host_prep_s")
+_ACCOUNT_CAPACITY = {DISPATCH: 4096, REQUEST: 16384, COMPILE: 4096,
+                     UPLOAD: 1024}
 _dispatch_seq = itertools.count(1)
 _request_note = threading.local()
 
@@ -470,13 +484,13 @@ class Tracer:
         return next(_dispatch_seq)
 
     def record(self, kind: str, rec: tuple):
-        """Append one account record (``DISPATCH_FIELDS`` or
-        ``REQUEST_FIELDS`` order) to its bounded ring."""
+        """Append one account record (in the order of its kind's
+        ``*_FIELDS``) to its bounded ring."""
         self._account[kind].append(rec)
 
     def recent(self, kind: str, n: Optional[int] = None) -> List[tuple]:
-        """The newest ``n`` records of ``DISPATCH`` or ``REQUEST``
-        (all that the ring holds by default), oldest first."""
+        """The newest ``n`` records of one kind (all that the ring holds
+        by default), oldest first."""
         recs = list(self._account[kind])
         return recs if n is None else recs[max(0, len(recs) - int(n)):]
 

@@ -765,7 +765,7 @@ def _gather_layout(mesh: MeshContext, rank: Optional[int] = None,
 
 def _upload_plan(mesh: MeshContext, plan: SolvePlan, chunk: int = 1,
                  rank: Optional[int] = None,
-                 factor_sharding: str = "replicated"):
+                 factor_sharding: str = "replicated", table: str = "plan"):
     """Stack same-shape batches into [N, B(, K)] groups and upload each
     group once, sharded on the batch dim (dim 1) over the mesh data axis
     (and the model axis under `factor_sharding` "model": `plan_axes`; the
@@ -788,28 +788,46 @@ def _upload_plan(mesh: MeshContext, plan: SolvePlan, chunk: int = 1,
 
     Over row-sharded tables (`_rows_sharded`) a group is (rows, place,
     val, mask, send): the plan's indices are routed here, on the host,
-    once (`_route_group`), and stay here."""
-    with TRACER.region("train.upload"):
-        return _upload_plan_now(mesh, plan, chunk, rank, factor_sharding)
+    once (`_route_group`), and stay here.
+
+    `table` names the upload's record in the set-up account (one for the
+    whole plan: its host preparation is the groups' stacking, padding and
+    routing)."""
+    from predictionio_tpu.obs import costmon
+    with TRACER.region("train.upload"), \
+            TRACER.region("setup.upload", table=table), \
+            costmon.executable(costmon.ALS_UPLOAD, defer_to_outer=True):
+        return _upload_plan_now(mesh, plan, chunk, rank, factor_sharding,
+                                table)
 
 
 def _upload_plan_now(mesh: MeshContext, plan: SolvePlan, chunk: int,
-                     rank: Optional[int], factor_sharding: str):
+                     rank: Optional[int], factor_sharding: str, table: str):
+    import time
+
+    from predictionio_tpu.obs import jaxmon
+    from predictionio_tpu.utils.device_cache import note_upload
+    t_start = time.perf_counter()
     pad = _gather_layout(mesh, rank, factor_sharding) != "rows"
     axes = plan_axes(mesh, factor_sharding)
     table_shards = (mesh.model_parallelism
                     if _rows_sharded(mesh, factor_sharding) else 1)
-    groups = tuple(
-        tuple(mesh.put_stacked(x, axes) for x in tensors)
-        for tensors in _host_groups(
-            plan, chunk, pad, batch_shards(mesh, factor_sharding),
-            table_shards))
+    host = _host_groups(plan, chunk, pad, batch_shards(mesh, factor_sharding),
+                        table_shards)
+    groups, prep_s = [], 0.0
+    while True:
+        t0 = time.perf_counter()
+        tensors = next(host, None)
+        prep_s += time.perf_counter() - t0
+        if tensors is None:
+            break
+        groups.append(tuple(mesh.put_stacked(x, axes) for x in tensors))
     # host->device transfer accounting (obs.jaxmon): the plan upload is
     # the largest per-train / per-fold-in host->device transfer
-    from predictionio_tpu.obs import jaxmon
-    jaxmon.record_h2d(jaxmon.nbytes_of(
-        t for group in groups for t in group))
-    return groups
+    nbytes = jaxmon.nbytes_of(t for group in groups for t in group)
+    jaxmon.record_h2d(nbytes)
+    note_upload(groups, table, nbytes, t_start, time.perf_counter(), prep_s)
+    return tuple(groups)
 
 
 def _host_groups(plan: SolvePlan, chunk: int, pad: bool, shards: int,
@@ -1146,13 +1164,16 @@ def sweep_exchange(mesh: MeshContext, device_groups, factors,
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=x.sharding), tree)
 
+    from predictionio_tpu.obs import costmon
     scalar = jax.ShapeDtypeStruct((), np.float32,
                                   sharding=mesh.replicated())
-    stats = merged_stats(
-        executed_collective_stats(_solve_sweep_per_chip.lower(
-            *shaped((factors, counter_factors, gram, groups)), scalar,
-            scalar, **statics).compile())
-        for groups in _sweep_programs(device_groups, cfg.implicit_prefs))
+    with costmon.executable(costmon.ALS_SWEEP_EXCHANGE):
+        stats = merged_stats(
+            executed_collective_stats(_solve_sweep_per_chip.lower(
+                *shaped((factors, counter_factors, gram, groups)), scalar,
+                scalar, **statics).compile())
+            for groups in _sweep_programs(device_groups,
+                                          cfg.implicit_prefs))
     out = {op: ent["bytes"] for op, ent in stats.items() if op != "total"}
     out["sent"] = sent_bytes(stats, batch_shards(mesh, cfg.factor_sharding))
     return out
@@ -1165,8 +1186,10 @@ def _side_gram(cfg: ALSConfig, table, n_live: int, side: str):
     it. None for explicit training."""
     if not cfg.implicit_prefs:
         return None
+    from predictionio_tpu.obs import costmon
     gram_of = _gram_eig if cfg.dual_solve == "auto" else _gram
-    with TRACER.region("train.gram", side=side):
+    with TRACER.region("train.gram", side=side), \
+            costmon.executable(costmon.ALS_GRAM, defer_to_outer=True):
         return gram_of(table, n_live=n_live)
 
 
@@ -1181,16 +1204,34 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
     compute_dtype, sweep_chunk, n_devices, and gather_layout: whether the
     uploaded batches are padded for the gather, `_gather_layout`) and
     per-phase wall times
-    (plan_s, upload_s, iters_s, s_per_iter, fetch_s; with two or more
-    iterations also iters_s = compile_s + sweeps_s, where compile_s is
-    what the first iteration took beyond a steady one: trace, lowering
-    and compilation or persistent-cache load). Each timing is closed by
-    a hard one-element host fetch (a dispatch-queue timer would lie on
-    asynchronous backends), which costs one extra tiny transfer — only
-    paid when telemetry is requested. It also receives cg_iters_run and
-    cg_iters_budget: the CG iterations the last iteration's Pallas solves
-    ran and were allowed, summed over their systems (0 and 0 under a
-    solver that is not cg_pallas)."""
+    (plan_s, upload_s, iters_s, s_per_iter, fetch_s). Each timing is
+    closed by a hard one-element host fetch (a dispatch-queue timer would
+    lie on asynchronous backends), which costs one extra tiny transfer —
+    only paid when telemetry is requested. It also receives cg_iters_run
+    and cg_iters_budget: the CG iterations the last iteration's Pallas
+    solves ran and were allowed, summed over their systems (0 and 0 under
+    a solver that is not cg_pallas), and `setup`: the train's set-up
+    account (`obs/costmon.setup_account`): each executable's trace,
+    lowering, compile or cache-load seconds and each plan upload's bytes,
+    host preparation and seconds to ready. Every compile of the train is
+    booked under a label: its executables' own, `als_train` for the rest
+    (`costmon.phase`)."""
+    import time as _time
+
+    from predictionio_tpu.obs import costmon
+    t_start = _time.perf_counter()
+    with costmon.phase(costmon.ALS_TRAIN):
+        model = _als_train(ratings, cfg, mesh, telemetry)
+    if telemetry is not None:
+        from predictionio_tpu.utils.device_cache import settle_uploads
+        settle_uploads()
+        telemetry["setup"] = costmon.setup_account(since=t_start)
+    return model
+
+
+def _als_train(ratings: RatingsCOO, cfg: ALSConfig,
+               mesh: Optional[MeshContext], telemetry: Optional[dict]
+               ) -> ALSModel:
     import dataclasses
     import time as _time
 
@@ -1234,9 +1275,9 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
     chunk = resolve_sweep_chunk(cfg.sweep_chunk, mesh.n_devices)
     _route_log.clear()
     user_batches = _upload_plan(mesh, user_plan, chunk, cfg.rank,
-                                cfg.factor_sharding)
+                                cfg.factor_sharding, table="user_plan")
     item_batches = _upload_plan(mesh, item_plan, chunk, cfg.rank,
-                                cfg.factor_sharding)
+                                cfg.factor_sharding, table="item_plan")
     if telemetry is not None:
         n_table, n_batch = sweep_shards(U, user_batches)
         telemetry.update(_routed())
@@ -1304,11 +1345,6 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
         U, V = last_good
         return False
 
-    def _first_iteration_done(it: int):
-        if telemetry is not None and it == 0:
-            float(np.asarray(jax.device_get(V[:1, :1]))[0, 0])
-            telemetry["first_iter_s"] = _time.perf_counter() - t0
-
     _cg_iters_log.clear()          # an earlier train's, or a fold tick's
     for it in range(cfg.iterations):
         gram_v = _side_gram(cfg, V, ratings.n_items, "item")
@@ -1319,17 +1355,10 @@ def als_train(ratings: RatingsCOO, cfg: ALSConfig,
                       alpha_dev, side="item", mesh=mesh)
         if not _checked(it):
             break
-        _first_iteration_done(it)
     if telemetry is not None:
         # hard sync again: the loop above only enqueues device work
         float(np.asarray(jax.device_get(V[:1, :1]))[0, 0])
         telemetry["iters_s"] = _time.perf_counter() - t0
-        first = telemetry.pop("first_iter_s", None)
-        if first is not None and cfg.iterations > 1:
-            steady = (telemetry["iters_s"] - first) / (cfg.iterations - 1)
-            telemetry["compile_s"] = max(first - steady, 0.0)
-            telemetry["sweeps_s"] = (telemetry["iters_s"]
-                                     - telemetry["compile_s"])
         telemetry["s_per_iter"] = (telemetry["iters_s"]
                                    / max(cfg.iterations, 1))
         # what a half-sweep of each side exchanges between the chips

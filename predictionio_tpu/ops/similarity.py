@@ -640,7 +640,8 @@ def composed_top_k_batch_begin(item_table: np.ndarray,
         sent += bits.nbytes
     _filter_h2d_counter().inc(sent)
     args = (qp, cached_put_rows(item_table, i_b, table="item"),
-            cached_put_rows(filters.categories.ids, i_b), cached_put(bits),
+            cached_put_rows(filters.categories.ids, i_b),
+            cached_put(bits, table="available_bits"),
             np.int32(n_items), q_cats, rows, cols, vals, white)
     k_eff, p = dims["k"], dims["p"]
     if p:

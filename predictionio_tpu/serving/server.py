@@ -1357,6 +1357,10 @@ class EngineServer:
             # live rows, bucket rows and the padded share of each
             # resident factor table: what every scan reads for nothing
             out["tableRows"] = device_cache.table_rows()
+            # the set-up account (ISSUE 37): what each executable's
+            # acquisition and each table's upload took in this process
+            from predictionio_tpu.obs import costmon
+            out["setup"] = costmon.setup_account()
             out["xlaCache"] = cache_status()
         except Exception:
             logger.debug("aot stats unavailable", exc_info=True)

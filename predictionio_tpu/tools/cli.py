@@ -162,6 +162,30 @@ def _print_hist(name: str, h) -> None:
            f"p99={h.get('p99', 0) * 1000:.3f}ms")
 
 
+def _print_setup(setup: dict) -> None:
+    """The engine's set-up account (``/stats.json`` ``setup``): how to
+    read it is in docs/operations.md."""
+    total = setup.get("total")
+    if not total:
+        return
+    _print(f"  setup: trace={total['trace_s']}s lower={total['lower_s']}s "
+           f"compile={total['compile_s']}s "
+           f"cacheLoad={total['cache_load_s']}s "
+           f"(compiled={total['compiled']} "
+           f"cacheLoaded={total['cache_loaded']}) "
+           f"upload={total['upload_bytes']}B "
+           f"ready={total['upload_ready_s']}s")
+    for label, e in (setup.get("executables") or {}).items():
+        _print(f"    {label}: trace={e['trace_s']}s lower={e['lower_s']}s "
+               f"compile={e['compile_s']}s "
+               f"cacheLoad={e['cache_load_s']}s "
+               f"compiled={e['compiled']} cacheLoaded={e['cache_loaded']}")
+    for table, t in (setup.get("tables") or {}).items():
+        _print(f"    table {table}: uploads={t['uploads']} "
+               f"bytes={t['bytes']} hostPrep={t['host_prep_s']}s "
+               f"ready={t['ready_s']}s")
+
+
 def _print_telemetry(args) -> None:
     from predictionio_tpu.utils.http import fetch_json as _fetch_json
     targets = dict(_status_targets(args))
@@ -187,7 +211,7 @@ def _print_telemetry(args) -> None:
         if aot:
             _print(f"  aot: resident={aot.get('executablesResident')} "
                    f"hitRate={aot.get('hitRate')} "
-                   f"compiles={aot.get('compileCount')} "
+                   f"compileRequests={aot.get('compileCount')} "
                    f"({aot.get('compileSeconds')}s) "
                    f"sharedJits={len(aot.get('sharedJits', []))}")
             for label, bks in sorted(
@@ -195,6 +219,7 @@ def _print_telemetry(args) -> None:
                 _print(f"    {label}: {len(bks)} bucket(s) "
                        f"[{', '.join(bks[:4])}"
                        f"{', ...' if len(bks) > 4 else ''}]")
+        _print_setup(st.get("setup") or {})
         xc = st.get("xlaCache") or {}
         if xc:
             _print(f"  xlaCache: entries={xc.get('entries')} "

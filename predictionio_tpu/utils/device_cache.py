@@ -9,10 +9,13 @@ is garbage-collected.
 
 from __future__ import annotations
 
+import atexit
 import contextlib
 import contextvars
 import os
+import queue
 import threading
+import time
 import weakref
 from typing import Any, Dict, Optional, Tuple
 
@@ -127,27 +130,97 @@ def check_table_budget(per_device_bytes: int, table: str = "table"):
             f"model axis (factor_sharding='model') or raise the budget")
 
 
-def _record_upload(arr):
-    """Host->device transfer accounting (obs.jaxmon): only cache MISSES
-    move bytes, so counting here — not per call — is what makes the
-    counter mean actual link traffic."""
-    from predictionio_tpu.obs import jaxmon
-    jaxmon.record_h2d(int(getattr(arr, "nbytes", 0) or 0))
+def _put_rows(arr, target: int, sharding, table: str):
+    """Upload ``arr`` with dim 0 zero-padded to ``target`` rows, inside a
+    ``pio.setup.upload`` region (attribute ``table``), counting its bytes
+    (obs.jaxmon: only cache MISSES get here, so the counter means actual
+    link traffic) and leaving its ``setup.upload`` record to be written
+    once the device copy is ready (``note_upload``)."""
+    import jax
+    import numpy as np
+
+    from predictionio_tpu.obs import TRACER, jaxmon
+    with TRACER.region("setup.upload", table=table):
+        t_start = time.perf_counter()
+        padded = arr
+        if target and target > arr.shape[0]:
+            padded = np.concatenate(
+                [arr, np.zeros((target - arr.shape[0],) + arr.shape[1:],
+                               arr.dtype)])
+        t_prep = time.perf_counter()
+        dev = jax.device_put(padded, sharding) if sharding is not None \
+            else jax.device_put(padded)
+        t_put = time.perf_counter()
+    nbytes = int(getattr(padded, "nbytes", 0) or 0)
+    jaxmon.record_h2d(nbytes)
+    note_upload(dev, table, nbytes, t_start, t_put, t_prep - t_start)
+    return dev
 
 
-def cached_put(arr, sharding=None):
-    """device_put with identity-based memoization. `arr` must be a
-    weakref-able host array (numpy ndarray)."""
+# -- the set-up account's upload records (ISSUE 37) -------------------
+# One waiter thread stamps each upload's readiness: the uploading thread
+# hands it the device arrays and goes on, so no upload path (a serve-time
+# cache miss, a fold tick, a promotion) waits on the transfer for the
+# sake of the record.
+_ready_queue: "Optional[queue.SimpleQueue]" = None
+_ready_cv = threading.Condition()
+_ready_pending = 0
+
+
+def note_upload(arrays, table: str, nbytes: int, t_start: float,
+                t_put: float, host_prep_s: float) -> None:
+    """Have the ``setup.upload`` record of an upload (``UPLOAD_FIELDS``;
+    ``time.perf_counter()`` stamps) written once ``arrays`` are ready on
+    the device; returns at once."""
+    global _ready_queue, _ready_pending
+    with _ready_cv:
+        if _ready_queue is None:
+            _ready_queue = queue.SimpleQueue()
+            threading.Thread(target=_await_ready, args=(_ready_queue,),
+                             name="pio-upload-ready", daemon=True).start()
+            atexit.register(settle_uploads)
+        _ready_pending += 1
+    _ready_queue.put((arrays, (t_start, t_put), table, int(nbytes),
+                      float(host_prep_s)))
+
+
+def _await_ready(q) -> None:
+    global _ready_pending
     import jax
 
+    from predictionio_tpu.obs.trace import TRACER, UPLOAD
+    while True:
+        arrays, stamps, table, nbytes, prep = q.get()
+        try:
+            jax.block_until_ready(arrays)
+        except Exception:
+            pass   # donated or deleted since: its transfer had landed
+        TRACER.record(UPLOAD, stamps + (time.perf_counter(), table,
+                                        nbytes, prep))
+        del arrays
+        with _ready_cv:
+            _ready_pending -= 1
+            _ready_cv.notify_all()
+
+
+def settle_uploads(timeout: float = 30.0) -> bool:
+    """Wait until every noted upload's record is written (``als_train``'s
+    telemetry, after its own syncs; interpreter exit). True if none is
+    left."""
+    with _ready_cv:
+        return _ready_cv.wait_for(lambda: _ready_pending == 0, timeout)
+
+
+def cached_put(arr, sharding=None, table: str = "cached_put"):
+    """device_put with identity-based memoization. `arr` must be a
+    weakref-able host array (numpy ndarray); ``table`` names its upload
+    record."""
     key = (id(arr), _sharding_key(sharding))
     with _lock:
         entry = _cache.get(key)
         if entry is not None and entry[0]() is arr:
             return entry[1]
-    dev = jax.device_put(arr, sharding) if sharding is not None \
-        else jax.device_put(arr)
-    _record_upload(arr)
+    dev = _put_rows(arr, 0, sharding, table)
     try:
         ref = weakref.ref(arr, lambda r, k=key: _evict_cache_key(k))
     except TypeError:
@@ -171,9 +244,6 @@ def cached_put_padded(arr, sharding, row_multiple: int):
     size: pads rows with zeros before upload, memoized on
     (array identity, sharding, multiple) so per-query serve calls reuse
     the resident padded table."""
-    import jax
-    import numpy as np
-
     key = (id(arr), _sharding_key(sharding), "pad", row_multiple)
     with _lock:
         entry = _cache.get(key)
@@ -181,10 +251,7 @@ def cached_put_padded(arr, sharding, row_multiple: int):
             return entry[1]
     n = arr.shape[0]
     target = ((n + row_multiple - 1) // row_multiple) * row_multiple
-    padded = arr if target == n else np.concatenate(
-        [arr, np.zeros((target - n,) + arr.shape[1:], arr.dtype)])
-    dev = jax.device_put(padded, sharding)
-    _record_upload(padded)
+    dev = _put_rows(arr, target, sharding, "cached_put_padded")
     try:
         ref = weakref.ref(arr, lambda r, k=key: _evict_cache_key(k))
     except TypeError:
@@ -224,8 +291,8 @@ def cached_put_rows(arr, target_rows: int, sharding=None,
     executable that reads it. Memoized on (array identity, rows,
     sharding); a smaller ``target_rows`` than the array has rows
     uploads unpadded (callers pass a covering bucket). ``table`` names
-    the upload for ``pio_table_rows`` ("user", "item")."""
-    import jax
+    the upload for ``pio_table_rows`` ("user", "item") and its upload
+    record."""
     import numpy as np
 
     target = max(int(target_rows), arr.shape[0])
@@ -243,12 +310,7 @@ def cached_put_rows(arr, target_rows: int, sharding=None,
         else arr.dtype.itemsize
     check_table_budget(target * row_bytes // _row_shards(sharding),
                        table="cached_put_rows")
-    padded = arr if target == arr.shape[0] else np.concatenate(
-        [arr, np.zeros((target - arr.shape[0],) + arr.shape[1:],
-                       arr.dtype)])
-    dev = jax.device_put(padded, sharding) if sharding is not None \
-        else jax.device_put(padded)
-    _record_upload(padded)
+    dev = _put_rows(arr, target, sharding, table or "cached_put_rows")
     if table is not None:
         note_table_rows(table, arr.shape[0], target)
     try:

@@ -103,7 +103,7 @@ def test_host_only_servers_create_no_backend(tmp_path):
         for port in (es.config.port, db.config.port):
             body = urllib.request.urlopen(
                 f"http://127.0.0.1:{port}/metrics", timeout=30).read()
-            assert b"pio_jax_compiles_total" in body, body[:200]
+            assert b"pio_compile_requests_total" in body, body[:200]
             assert b"pio_jax_device_memory_bytes" not in body
         es.stop(); db.stop()
         from jax._src import xla_bridge

@@ -4,8 +4,8 @@ warm-before-traffic contracts on the serve and fold paths.
 
 The acceptance criteria these tests pin:
 - growth inside a shape bucket across >= 3 consecutive fold ticks
-  triggers zero recompiles (asserted via the costmon
-  ``pio_compile_executable_seconds_total`` deltas);
+  triggers zero recompiles (asserted via the backend stages of costmon's
+  ``pio_compile_seconds_total``);
 - a canary-staged candidate's first served request runs zero XLA
   compiles (the stage-time warm already compiled its buckets);
 - the persistent cache answers a simulated process restart (in-memory

@@ -368,7 +368,8 @@ class TestIssue6FamiliesPresent:
         ("pio_lock_wait_seconds", "process"),
         ("pio_flight_records_total", "process"),
         ("pio_flight_dropped_total", "process"),
-        ("pio_compile_executable_seconds_total", "process"),
+        ("pio_compile_seconds_total", "process"),
+        ("pio_compile_requests_total", "process"),
         ("pio_compile_cache_hits_total", "process"),
         ("pio_compile_cache_misses_total", "process"),
         ("pio_hbm_table_bytes", "process"),

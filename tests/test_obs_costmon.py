@@ -78,11 +78,38 @@ class TestAttribution:
 
     def test_listener_ignores_non_compile_events(self):
         before = _seconds("unlabeled")
+        stages = costmon.compile_stage_seconds().get("unlabeled", {})
         costmon._on_duration("/jax/core/some_trace_duration", 5.0)
         assert _seconds("unlabeled") == before
         costmon._on_duration("/jax/core/compile/"
                              "backend_compile_duration", 0.25)
         assert _seconds("unlabeled") == pytest.approx(before + 0.25)
+        # a backend compile with no cache event is a compile, all of it
+        after = costmon.compile_stage_seconds()["unlabeled"]
+        assert after["compile"] == pytest.approx(
+            stages.get("compile", 0.0) + 0.25)
+        assert after["cache_load"] == stages.get("cache_load", 0.0)
+
+    def test_one_family_by_stage_and_one_counter_by_outcome(self):
+        import jax
+        import jax.numpy as jnp
+        x = jnp.ones((13, 7))
+        with costmon.executable("stage_exec"):
+            jax.jit(lambda a: (a * 3.0).max())(x).block_until_ready()
+        fam = get_registry().get("pio_compile_seconds_total")
+        stages = {labels["stage"] for labels, _ in fam.samples()
+                  if labels and labels["executable"] == "stage_exec"}
+        assert stages == {"trace", "lower", "compile", "cache_load"}
+        req = get_registry().get("pio_compile_requests_total")
+        got = {labels["outcome"]: v for labels, v in req.samples()
+               if labels and labels["executable"] == "stage_exec"}
+        assert got == {"compiled": 1.0}
+        # the duplicates are gone
+        for gone in ("pio_compile_executable_seconds_total",
+                     "pio_compile_pcache_hits_total",
+                     "pio_jax_compiles_total",
+                     "pio_jax_compile_seconds_total"):
+            assert get_registry().get(gone) is None
 
 
 class TestCostAnalysis:

@@ -167,8 +167,12 @@ class TestServerRoundTrip:
             assert "# TYPE pio_engine_query_seconds histogram" in text
             assert ("# TYPE pio_engine_batch_wait_seconds histogram"
                     in text)
-            # process-wide families ride the parent chain
+            # process-wide families ride the parent chain, the compile
+            # account's among them (ISSUE 37)
             assert "pio_jax_host_to_device_bytes_total" in text
+            assert "# TYPE pio_compile_seconds_total counter" in text
+            assert "# TYPE pio_compile_requests_total counter" in text
+            assert "pio_jax_compiles_total" not in text
         finally:
             if s.batcher is not None:
                 s.batcher.stop()

@@ -156,6 +156,9 @@ class TestMetricsSurfaces:
         # process-wide families ride the parent chain
         assert "pio_jax_host_to_device_bytes_total" in text
         assert "pio_fold_events_total" in text
+        # one compile family, by stage (ISSUE 37)
+        assert 'pio_compile_seconds_total{executable="' in text
+        assert "pio_jax_compile_seconds_total" not in text
 
     def test_event_metrics_write_histogram(self, stack):
         es, srv, sched = stack
