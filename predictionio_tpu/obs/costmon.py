@@ -134,6 +134,7 @@ _g_flops = None
 _g_bytes = None
 _g_cg_ratio = None
 _g_exchange = None
+_g_deal_fill = None
 _c_dispatch_s = None
 _c_device_s = None
 _c_device_syncs = None
@@ -146,7 +147,7 @@ def install(registry=None):
     global _installed, _c_stage_s, _c_requests, _c_hits, _c_misses, \
         _g_flops, _g_bytes, _c_dispatch_s, _c_device_s, \
         _c_device_syncs, _g_occupancy, _g_tenant_occ, _g_cg_ratio, \
-        _g_exchange
+        _g_exchange, _g_deal_fill
     with _lock:
         if _installed:
             return
@@ -192,6 +193,13 @@ def install(registry=None):
             "compiled program (parallel/collective_stats), by side and "
             "collective kind; absent where tables are not sharded",
             labelnames=("side", "op"))
+        _g_deal_fill = reg.gauge(
+            "pio_als_deal_fill",
+            "real systems over the system slots the chips solve in the "
+            "last ALS train over row-sharded tables, whose plan is dealt "
+            "so that each chip solves rows of its own shard (the plan's "
+            "padding and the deal's), by side; absent where tables are "
+            "not sharded", labelnames=("side",))
         reg.gauge_func(
             "pio_hbm_table_bytes",
             "Device bytes held by each named residency slot in "
@@ -737,6 +745,14 @@ def record_exchange_bytes(side: str, by_op: dict) -> None:
     for op, n_bytes in by_op.items():
         if op != "sent":
             _g_exchange.labels(side=side, op=op).set(n_bytes)
+
+
+def record_deal_fill(side: str, fill: float) -> None:
+    """Bank how full the chips' dealt steps of one side's plan run over
+    row-sharded tables (`ops/als._deal_fills`)."""
+    if not _installed:
+        install()
+    _g_deal_fill.labels(side=side).set(fill)
 
 
 def analyze_jit(label: str, fn, *args, **kwargs) -> Optional[dict]:

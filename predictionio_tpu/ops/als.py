@@ -23,22 +23,26 @@ divided follows from the mesh and `factor_sharding` (`_rows_sharded`):
   `shard_map` over the half-sweep. Every chip holds a contiguous quarter of
   each table's rows and a quarter of every batch of the plan
   (`plan_axes`), and solves its quarter of the systems with the one-chip
-  body. Which chip owns the counterpart row of which slot is a fact of the
-  plan, so `_upload_plan` routes it once on the host (`_route_group`): a
-  chip holds, beside its quarter of the plan, the local numbers of the
-  rows the others need of it (`send`), and for each of its own slots where
-  the row will stand in what it receives (`place`, in `idx`'s seat: the
-  indices themselves never reach a device). Three exchanges cross the
-  chips in a scan step, and nothing else: the rated rows, each shard's
-  gathered from its own rows in the compute dtype and handed to the chips
-  that rate them by one all-to-all (real slots only; a received row is a
-  copy of its owner's), the solved float32 rows (all-gather, each shard
-  keeping its own), and their row ids with them. No chip ever holds a
+  body. `_upload_plan` deals each K rung's systems on the host so that a
+  chip's quarter of a step holds only rows of the shard it holds
+  (`_dealt_groups`): it writes what it solves into its own shard, and no
+  solved row crosses the chips. Which chip owns the counterpart row of
+  which slot is a fact of the plan too, so `_upload_plan` routes it once
+  on the host (`_route_group`): a chip holds, beside its quarter of the
+  plan, the local numbers of the rows the others need of it (`send`), and
+  for each of its own slots where the row will stand in what it receives
+  (`place`, in `idx`'s seat: the indices themselves never reach a device).
+  One exchange crosses the chips in a scan step, and nothing else: the
+  rated rows, each shard's gathered from its own rows in the compute dtype
+  and handed to the chips that rate them by one all-to-all (real slots
+  only; a received row is a copy of its owner's). No chip ever holds a
   whole table or another chip's systems. What the compiled programs
   exchange is read from their HLO when telemetry is asked for
   (`sweep_exchange`, telemetry `exchange_bytes`, gauge
-  `pio_als_exchange_bytes{side, op}`), and what the routing cost and how
-  full the exchanged blocks run is beside it (`route_s`, `route_fill`).
+  `pio_als_exchange_bytes{side, op}`), and what the dealing and routing
+  cost and how full the dealt steps and the exchanged blocks run is beside
+  it (`route_s`, `deal_fill`, `route_fill`; gauge
+  `pio_als_deal_fill{side}`).
 
 Math parity with MLlib 1.3:
   explicit  — ALS-WR: minimize sum (r - x.v)^2 + lambda * (n_u |x|^2 + ...)
@@ -460,17 +464,19 @@ def sweep_shards(table, device_groups):
 
 def _solve_batch_per_chip(f_local, counter_local, gram, rows, place, val,
                           mask, send, lam, alpha, *, table_axis: str,
-                          batch_axes, **statics):
+                          data_axes: tuple, **statics):
     """One scan step of a half-sweep as ONE chip of a row-sharded mesh runs
     it (the ALX arrangement, PAPERS.md arXiv:2112.02194): this chip holds
     `f_local` / `counter_local`, its contiguous quarter of each table's
     rows, and `rows` / `place` / `val` / `mask`, its quarter [B/n, ...] of
-    the step's systems. Which chip owns which slot's counterpart row is a
-    fact of the plan, so the host has routed it (`_route_group`, once a
-    train): `send` [n, L] holds, for each chip of the table axis, the local
-    numbers of the rows of THIS shard that that chip's systems rate, and
-    `place` [B/n, K], where `idx` sat, where each slot's row stands in what
-    this chip receives. Three exchanges cross the chips, and nothing else:
+    the step's systems, every one a row of its own shard of `f_local` or
+    padding (the host dealt them so: `_dealt_groups`). Which chip owns
+    which slot's counterpart row is a fact of the plan, so the host has
+    routed it (`_route_group`, once a train): `send` [n, L] holds, for
+    each chip of the table axis, the local numbers of the rows of THIS
+    shard that that chip's systems rate, and `place` [B/n, K], where `idx`
+    sat, where each slot's row stands in what this chip receives. One
+    exchange crosses the chips, and nothing else:
 
       rows     each shard gathers the rows the others (and itself) need of
                it, `counter_local[send]` [n, L, R] in the compute dtype: a
@@ -479,34 +485,31 @@ def _solve_batch_per_chip(f_local, counter_local, gram, rows, place, val,
                its own systems, each a copy of its owner's row, which
                `place` spreads to [B/n, K, R] (a padding slot reads
                position 0 and is masked in every route of
-               `_solve_gathered`, as a one-chip plan's padding `idx` 0 is);
-      solved   the solved [B/n, R] float32 rows all-gathered, each shard
-               keeping the rows it owns;
-      indices  the systems' row ids all-gathered with them: which solved
-               row goes where.
+               `_solve_gathered`, as a one-chip plan's padding `idx` 0 is).
 
-    Between `rows` and `solved` the chip runs `_solve_gathered` on local
-    operands: the Gram, the solver routes and their Pallas kernels are the
-    one-chip ones, on B/n systems."""
+    Then the chip runs `_solve_gathered` on local operands (the Gram, the
+    solver routes and their Pallas kernels are the one-chip ones, on B/n
+    systems) and scatters its [B/n, R] solved rows into its own shard. On
+    a mesh whose `data_axes` are wider than 1 the chips of a data column
+    hold the same shard, so there the solved rows and their ids are
+    all-gathered over those axes first, each chip writing its column's."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
-    shard = lax.axis_index(table_axis)
-    with jax.named_scope("pio.sweep.exchange.indices"):
-        rows_all = lax.all_gather(rows, batch_axes, axis=0, tiled=True)
     Vc = _routed_rows(counter_local, send[0], place, table_axis)
     x, cg = _solve_gathered(Vc, gram, val, mask, lam, alpha, **statics)
-    with jax.named_scope("pio.sweep.exchange.solved"):
-        x_all = lax.all_gather(x.astype(f_local.dtype), batch_axes, axis=0,
-                               tiled=True)          # [B, R]
+    x = x.astype(f_local.dtype)
+    if data_axes:
+        with jax.named_scope("pio.sweep.exchange.solved"):
+            rows = lax.all_gather(rows, data_axes, axis=0, tiled=True)
+            x = lax.all_gather(x, data_axes, axis=0, tiled=True)
     with jax.named_scope("pio.sweep.scatter"):
         n_out = f_local.shape[0]
-        at = rows_all - shard * n_out
-        # padding systems (row -1) and other shards' rows: out of range,
-        # so dropped
-        at = jnp.where((rows_all >= 0) & (at >= 0) & (at < n_out), at, n_out)
-        return f_local.at[at].set(x_all, mode="drop"), cg
+        at = rows - lax.axis_index(table_axis) * n_out
+        # padding systems (row -1): out of range, so dropped
+        at = jnp.where((at >= 0) & (at < n_out), at, n_out)
+        return f_local.at[at].set(x, mode="drop"), cg
 
 
 def _routed_rows(counter_local, send, place, table_axis: str):
@@ -538,6 +541,12 @@ def _solve_sweep_per_chip_impl(factors_out, counter_factors, gram, groups,
 
     from predictionio_tpu.ops.solve import no_cg_iterations
 
+    # the batch axes beside the table's over which a shard is held more
+    # than once: its solved rows are gathered there
+    names = (batch_axes,) if isinstance(batch_axes, str) else batch_axes
+    data_axes = tuple(a for a in names
+                      if a != table_axis and mesh.shape[a] > 1)
+
     def per_chip(f_local, counter_local, gram, groups, lam, alpha):
         # the gathers read a copy of the counterpart shard in the compute
         # dtype, as one chip's do; made here once for every scan (left to
@@ -551,7 +560,7 @@ def _solve_sweep_per_chip_impl(factors_out, counter_factors, gram, groups,
             f, cg = carry
             f, cg_batch = _solve_batch_per_chip(
                 f, counter_local, gram, *batch, lam, alpha,
-                table_axis=table_axis, batch_axes=batch_axes, **statics)
+                table_axis=table_axis, data_axes=data_axes, **statics)
             return (f, cg + cg_batch), None
 
         carry = (f_local, no_cg_iterations())
@@ -786,9 +795,11 @@ def _upload_plan(mesh: MeshContext, plan: SolvePlan, chunk: int = 1,
     `_gather_layout` says when, from the mesh, the tables' `rank` where
     the caller gives it, and their sharding).
 
-    Over row-sharded tables (`_rows_sharded`) a group is (rows, place,
-    val, mask, send): the plan's indices are routed here, on the host,
-    once (`_route_group`), and stay here.
+    Over row-sharded tables (`_rows_sharded`) each K rung's systems are
+    dealt anew, so that a chip's share of a step holds rows of its own
+    shard alone (`_dealt_groups`), and a group is (rows, place, val, mask,
+    send): the plan's indices are routed here, on the host, once
+    (`_route_group`), and stay here.
 
     `table` names the upload's record in the set-up account (one for the
     whole plan: its host preparation is the groups' stacking, padding and
@@ -813,7 +824,7 @@ def _upload_plan_now(mesh: MeshContext, plan: SolvePlan, chunk: int,
     table_shards = (mesh.model_parallelism
                     if _rows_sharded(mesh, factor_sharding) else 1)
     host = _host_groups(plan, chunk, pad, batch_shards(mesh, factor_sharding),
-                        table_shards)
+                        table_shards, table)
     groups, prep_s = [], 0.0
     while True:
         t0 = time.perf_counter()
@@ -831,18 +842,18 @@ def _upload_plan_now(mesh: MeshContext, plan: SolvePlan, chunk: int,
 
 
 def _host_groups(plan: SolvePlan, chunk: int, pad: bool, shards: int,
-                 table_shards: int = 1):
+                 table_shards: int = 1, table: str = "plan"):
     """The host arrays of each batch group `_upload_plan` uploads, in its
     order: (rows, idx, val, mask), same-shape batches stacked, merged
     `chunk` at a time and, where `pad`, padded for the gather; for tables
     row-sharded `table_shards` > 1 ways (the batches divided over `shards`
-    chips) each chip gathers its own [B/shards, K] slots, so that count is
-    what is padded for, and the group is (rows, place, val, mask, send):
+    chips) each K rung is dealt so that a chip's [B/shards, K] slots hold
+    systems of its own shard (`_dealt_groups`, each chip's slots padded
+    for its own gather), and the group is (rows, place, val, mask, send):
     `_route_group`'s `place` in `idx`'s seat, `send` appended, and what
-    was routed goes to `_route_log`."""
-    groups = _padded_groups(plan, chunk, pad, shards)
+    was dealt and routed goes to `_route_log` under `table`."""
     if table_shards == 1:
-        yield from groups
+        yield from _padded_groups(plan, chunk, pad, shards)
         return
     import time
     from concurrent.futures import ThreadPoolExecutor
@@ -854,18 +865,103 @@ def _host_groups(plan: SolvePlan, chunk: int, pad: bool, shards: int,
     t0 = time.perf_counter()
     rows_a_shard = table_rows(plan.n_counter, table_shards) // table_shards
     degrees = _rung_degrees(plan)
-    seconds, real, room = time.perf_counter() - t0, 0, 0
+    groups = _dealt_groups(plan, chunk, pad, shards, table_shards)
+    seconds = time.perf_counter() - t0
+    real = room = systems = slots = 0
     with ThreadPoolExecutor(os.cpu_count() or 1) as pool:
-        for rows, idx, val, mask in groups:
+        while True:
             t0 = time.perf_counter()
             with TRACER.region("train.route"):
+                group = next(groups, None)
+                if group is None:
+                    break
+                block, (rows, idx, val, mask) = group
                 place, send, needed, made = _route_group(
-                    rows, idx, mask, degrees[idx.shape[2]], rows_a_shard,
+                    block, idx, mask, degrees[idx.shape[2]], rows_a_shard,
                     table_shards, shards, pad, pool)
             seconds += time.perf_counter() - t0
             real, room = real + needed, room + made
+            systems += int(np.count_nonzero(rows >= 0))
+            slots += rows.size
             yield rows, place, val, mask, send
-    _route_log.append((seconds, real, room))
+    _route_log.append((table, seconds, real, room, systems, slots))
+
+
+def _deal_room(systems: int, table_shards: int, observed: int) -> int:
+    """How many systems of one K rung of `systems` each of `table_shards`
+    shards is dealt room for (`_dealt_groups`): its even share and four
+    times that share's square root (a fair draw of owners spreads a share
+    by less: its standard deviation is √(share (1 − 1/n))), never more
+    than the rung holds. From the plan's counts alone, so the same for
+    every pairing of the same degree sequences, and with it the steps'
+    shapes and the compiled program; over `observed`, the most systems any
+    shard owns, by steps of an eighth (a catalogue whose rows of one rung
+    crowd one shard pays in padding and in a program of its own, and
+    stays exact)."""
+    even = systems / table_shards
+    room = min(systems, int(np.ceil(even + 4 * np.sqrt(even))))
+    while room < observed:
+        room = min(systems, -(-room * 9 // 8))
+    return room
+
+
+def _dealt_groups(plan: SolvePlan, chunk: int, pad: bool, shards: int,
+                  table_shards: int):
+    """`_host_groups`' groups over tables row-sharded `table_shards` ways,
+    the batches divided over `shards` chips, chip (d, m) of the data and
+    table axes solving block d * table_shards + m of a step and holding
+    shard m: each K rung of the plan dealt anew as one group, its real
+    systems, over all of its batches, split by the shard that owns their
+    row (row // rows a shard of the solved table) and laid out so that a
+    chip's block of a step holds rows of its own shard, in the plan's
+    order, and then padding systems (row -1, `idx` 0, mask 0). Each shard
+    is given the same room, `_deal_room` systems, in as few steps as the
+    rung's widest batch allows (merged `chunk` at a time, as
+    `_padded_groups` merges), every step alike: a rung of one system takes
+    one system a chip. Where `pad`, each chip's block is padded at its own
+    tail for its gather (`_gather_pad_rows`). Yields, rungs in ascending
+    K, (block, (rows, idx, val, mask)): `block` is the most real systems a
+    chip's block of the group holds, from the plan's counts alone."""
+    n_out = table_rows(plan.n_entities, table_shards) // table_shards
+    data = max(shards // table_shards, 1)
+    by_k = {}
+    for b in plan.batches:
+        by_k.setdefault(b.shape[1], []).append(b)
+    for k in sorted(by_k):
+        bs = by_k[k]
+        widest = max(b.shape[0] for b in bs)
+        most = max(widest * min(chunk, sum(b.shape[0] == widest for b in bs))
+                   // shards, 1)           # a chip's systems in a step
+        # the rung's batches end to end, and one padding system after them
+        arrays = [np.concatenate(
+            [getattr(b, name) for b in bs]
+            + [np.full((1,) + getattr(bs[0], name).shape[1:],
+                       -1 if name == "rows" else 0,
+                       getattr(bs[0], name).dtype)])
+            for name in ("rows", "idx", "val", "mask")]
+        nothing = arrays[0].size - 1
+        real = np.flatnonzero(arrays[0] >= 0)
+        owner = arrays[0][real] // n_out
+        real = real[np.argsort(owner, kind="stable")]
+        owned = np.bincount(owner, minlength=table_shards)
+        room = _deal_room(real.size, table_shards, int(owned.max()))
+        steps = -(-room // (data * most))
+        block = -(-room // (data * steps))
+        # each shard's systems and then padding, in the order its steps
+        # take them: step, data row, place in the block
+        seats = np.full((table_shards, steps * data * block), nothing,
+                        np.int64)
+        first = np.cumsum(owned) - owned
+        for m in range(table_shards):
+            seats[m, :owned[m]] = real[first[m]:first[m] + owned[m]]
+        seats = seats.reshape(table_shards, steps, data, block).transpose(
+            1, 2, 0, 3)
+        extra = _gather_pad_rows(block, k) if pad else 0
+        if extra:
+            seats = np.pad(seats, [(0, 0)] * 3 + [(0, extra)],
+                           constant_values=nothing)
+        seats = seats.reshape(steps, -1)
+        yield block, tuple(x[seats] for x in arrays)
 
 
 def _padded_groups(plan: SolvePlan, chunk: int, pad: bool, shards: int):
@@ -910,22 +1006,33 @@ def _padded_groups(plan: SolvePlan, chunk: int, pad: bool, shards: int):
 #: whole-array numpy over 4 MB of indices each, or one scan step's.
 _ROUTE_SLOTS = 1 << 20
 
-#: What each `_upload_plan` over row-sharded tables routed, (seconds, real
-#: rows, rows of room in `send`): `als_train` clears it before its two
-#: uploads and reads `route_s` / `route_fill` after them (`_routed`).
+#: What each `_upload_plan` over row-sharded tables dealt and routed,
+#: (table, seconds, real rows, rows of room in `send`, real systems, system
+#: slots dealt): `als_train` clears it before its two uploads and reads
+#: `route_s` / `route_fill` / `deal_fill` after them (`_routed`,
+#: `_deal_fills`).
 _route_log: list = []
 
 
 def _routed() -> dict:
     """Telemetry of the uploads in `_route_log`: `route_s`, the host's
-    seconds routing them (a part of `upload_s`), and `route_fill`, the real
-    rows over the rows of room the chips exchange for them (padding in `L`:
-    an uneven spread of owners, and the room that keeps `L` off the seed).
-    Empty where nothing was routed."""
+    seconds dealing and routing them (a part of `upload_s`); `route_fill`,
+    the real rows over the rows of room the chips exchange for them
+    (padding in `L`: an uneven spread of owners, and the room that keeps
+    `L` off the seed); and `deal_fill`, the real systems over the system
+    slots the chips solve (the plan's own padding, the room each shard is
+    dealt, and the gathers' padding). Empty where nothing was routed."""
     if not _route_log:
         return {}
-    seconds, real, room = (sum(x) for x in zip(*_route_log))
-    return {"route_s": seconds, "route_fill": real / max(room, 1)}
+    seconds, real, room, systems, slots = (
+        sum(x) for x in zip(*(entry[1:] for entry in _route_log)))
+    return {"route_s": seconds, "route_fill": real / max(room, 1),
+            "deal_fill": systems / max(slots, 1)}
+
+
+def _deal_fills() -> dict:
+    """{table of the upload: its `deal_fill`} over `_route_log`."""
+    return {entry[0]: entry[4] / max(entry[5], 1) for entry in _route_log}
 
 
 def _rung_degrees(plan: SolvePlan) -> dict:
@@ -1000,15 +1107,16 @@ def _place_steps(idx, owner, counts, place, send, rows_a_shard: int):
             send.shape[:2] + (-1, 1))] = idx[at] - s * rows_a_shard
 
 
-def _route_group(rows, idx, mask, degree: float, rows_a_shard: int,
+def _route_group(systems: int, idx, mask, degree: float, rows_a_shard: int,
                  table_shards: int, blocks: int, pad: bool, pool=None):
     """The static routing of one padded batch group over row-sharded
     tables, on the host: whole-array passes, no loop over systems, no sort;
     `pool`'s threads take some scan steps each (numpy releases the
-    interpreter). `rows` [N, B], `idx` / `mask` [N, B, K], the B systems of
-    a step dealt to `blocks` chips in order, chip (d, m) of the data and
-    table axes holding block d * table_shards + m; `degree` the rung's
-    `_rung_degrees`. Returns
+    interpreter). `idx` / `mask` [N, B, K], the B systems of a step dealt
+    to `blocks` chips in order, chip (d, m) of the data and table axes
+    holding block d * table_shards + m, of which `systems` at most are
+    real (from the plan's counts, not the pairing: `_dealt_groups`);
+    `degree` the rung's `_rung_degrees`. Returns
 
       place  [N, B, K] int32, `idx`'s seat on the device: where the slot's
              row stands among the [table_shards * L] rows its chip
@@ -1029,7 +1137,6 @@ def _route_group(rows, idx, mask, degree: float, rows_a_shard: int,
     counts = np.empty((n, blocks, table_shards), np.int64)
     list(each(lambda c: _route_steps(idx[c], mask[c], owner[c], counts[c],
                                      rows_a_shard), cuts))
-    systems = int((rows.reshape(n, blocks, -1) >= 0).sum(axis=-1).max())
     L = _route_rows(systems * degree / table_shards, int(counts.max()),
                     table_shards, pad)
     place = np.zeros(idx.shape, np.int32)
@@ -1278,6 +1385,9 @@ def _als_train(ratings: RatingsCOO, cfg: ALSConfig,
                                 cfg.factor_sharding, table="user_plan")
     item_batches = _upload_plan(mesh, item_plan, chunk, cfg.rank,
                                 cfg.factor_sharding, table="item_plan")
+    from predictionio_tpu.obs import costmon
+    for table, fill in _deal_fills().items():     # none on one chip
+        costmon.record_deal_fill(table.removesuffix("_plan"), fill)
     if telemetry is not None:
         n_table, n_batch = sweep_shards(U, user_batches)
         telemetry.update(_routed())
@@ -1365,7 +1475,6 @@ def _als_train(ratings: RatingsCOO, cfg: ALSConfig,
         # (none where no sweep ran per chip); outside every phase's time
         exchanged = {}
         if cfg.iterations > 0 and _rows_sharded(mesh, cfg.factor_sharding):
-            from predictionio_tpu.obs import costmon
             exchanged = {
                 "user": sweep_exchange(mesh, user_batches, U, V, cfg,
                                        gram_v),

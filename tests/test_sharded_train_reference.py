@@ -1,10 +1,11 @@
 """ALS training over row-sharded factor tables (`factor_sharding="model"`)
 on a forced CPU mesh at small sizes, seeded ratings: the half-sweep written
-per chip (ops/als._solve_sweep_per_chip: each shard gathers the rows the
+per chip (ops/als._solve_sweep_per_chip: the plan dealt on the host so that
+each chip solves rows of its own shard; each shard gathers the rows the
 plan's routing says the others need of it, one all-to-all hands every chip
 the rows of its own systems, each a copy of its owner's row, placed by the
-plan; the one-chip solve on B/n systems; solved rows all-gathered and kept
-by their owner).
+plan; the one-chip solve on B/n systems; the solved rows scattered into the
+chip's own shard, where they lie).
 
 Every case runs explicit and implicit training on mesh shapes 1x4 and 1x2
 (data x model); `layout` and `rows` also on 2x2, where a data axis wider
@@ -23,10 +24,14 @@ than 1 divides the batches further and the rows cross within a data row:
   program     each device's compiled half-sweep holds Gram and solve
               operands of leading dimension B/n, no operand or temporary
               with a whole table's rows or a whole step's slots, an
-              all-to-all and no reduce-scatter or gathered indices;
+              all-to-all and no reduce-scatter or all-gather;
   exchange    `telemetry["exchange_bytes"]` is what the compiled programs'
               collectives say (parallel/collective_stats), and so is the
               gauge `pio_als_exchange_bytes`;
+  uneven      a catalogue whose rated rows crowd one shard is dealt with
+              more padding and trains the same rows;
+  deal_fill   `telemetry["deal_fill"]` and the gauge `pio_als_deal_fill`
+              on a row-sharded mesh, neither on one device;
   sentinel    with the sentinel on, row-sharded tables are checked every
               iteration and never copied, and a breach raises.
 """
@@ -84,7 +89,8 @@ def _mesh(name):
 
 def _cfg(implicit, **kw):
     from predictionio_tpu.ops.als import ALSConfig
-    return ALSConfig(rank=RANK, iterations=2, lam=LAM, alpha=ALPHA, seed=3,
+    kw = dict(dict(iterations=2), **kw)
+    return ALSConfig(rank=RANK, lam=LAM, alpha=ALPHA, seed=3,
                      work_budget=512, implicit_prefs=implicit,
                      sentinel=False, **kw)
 
@@ -121,9 +127,10 @@ def test_same_rows_on_one_device_and_on_the_mesh(trained, kind, mesh_name):
                                rtol=0, atol=2e-5)
     np.testing.assert_allclose(many.item_factors, one.item_factors,
                                rtol=0, atol=2e-5)
-    # the routing engaged, and says what it cost and how full it ran
+    # the deal and the routing engaged, and say what they cost and how
+    # full they ran
     assert tel["route_s"] > 0 and 0 < tel["route_fill"] <= 1
-    assert "route_s" not in (trained[kind, "one"][1] or {})
+    assert 0 < tel["deal_fill"] <= 1
 
 
 @pytest.mark.parametrize("mesh_name", WIDE)
@@ -158,10 +165,13 @@ def test_received_rows_are_copies_of_their_owners(ratings, mesh_name):
             out_specs=P(None, axes, None, None), check_vma=False)(
                 V, place, send)
 
-    plain = list(als._host_groups(plan, 1, False, chips))
+    dealt = [group for _block, group
+             in als._dealt_groups(plan, 1, False, chips, mp)]
     groups = als._upload_plan(mesh, plan, 1, RANK, "model")
-    assert len(groups) == len(plain) > 3
-    for (_rows, idx, _val, mask), group in zip(plain, groups):
+    # one group a rung
+    assert len(groups) == len(dealt) == len(
+        {b.shape[1] for b in plan.batches}) > 2
+    for (_rows, idx, _val, mask), group in zip(dealt, groups):
         _r, place, _v, _m, send = group
         got = np.asarray(fetch(V, place, send))
         live = mask.astype(bool)
@@ -234,6 +244,79 @@ def test_rows_of_every_shard_are_written_after_one_half_sweep(
             rated[lo:lo + per_shard])
 
 
+@pytest.fixture(scope="module")
+def uneven():
+    """Ratings whose heavy users crowd the first shard of a table dealt
+    four ways: nine in ten of the ratings come from its rows, 40 a user,
+    and a rating or two from each user of the others."""
+    from predictionio_tpu.ops.ratings import RatingsCOO
+    rng = np.random.default_rng(35)
+    crowd = rng.integers(0, N_USERS // 4, 3000)
+    rest = rng.integers(N_USERS // 4, N_USERS, 300)
+    key = np.unique(np.concatenate([crowd, rest]) * N_ITEMS
+                    + rng.integers(0, N_ITEMS, 3300))
+    return RatingsCOO((key // N_ITEMS).astype(np.int32),
+                      (key % N_ITEMS).astype(np.int32),
+                      rng.integers(1, 6, key.size).astype(np.float32),
+                      N_USERS, N_ITEMS)
+
+
+@pytest.mark.parametrize("mesh_name", WIDE)
+def test_uneven_owners_are_dealt_and_train_the_same_rows(uneven,
+                                                         mesh_name):
+    import jax
+    from predictionio_tpu.ops import als
+    from predictionio_tpu.ops.ratings import plan_for_users
+    from predictionio_tpu.parallel.mesh import make_mesh
+    one = als.als_train(uneven, _cfg(False),
+                        make_mesh(devices=jax.devices()[:1]))
+    tel = {}
+    many = als.als_train(uneven, _cfg(False, factor_sharding="model"),
+                         _mesh(mesh_name), telemetry=tel)
+    np.testing.assert_allclose(many.user_factors, one.user_factors,
+                               rtol=0, atol=2e-5)
+    np.testing.assert_allclose(many.item_factors, one.item_factors,
+                               rtol=0, atol=2e-5)
+    # a crowded rung is paid for in padding: the chips of every shard are
+    # dealt the room the crowded one needs
+    mp = WIDE[mesh_name][1]
+    heavy = [group for _most, group in als._dealt_groups(
+        plan_for_users(uneven, work_budget=512,
+                       batch_multiple=als.batch_shards(_mesh(mesh_name),
+                                                       "model")),
+        1, False, WIDE[mesh_name][0] * mp, mp) if group[1].shape[2] >= 32]
+    assert heavy
+    for rows, *_ in heavy:
+        blocks = rows.reshape(rows.shape[0], -1, mp, rows.shape[1]
+                              // WIDE[mesh_name][0] // mp)
+        assert (blocks[:, :, 0] >= 0).any()
+        assert (blocks[:, :, 1:] == -1).all()
+    assert 0 < tel["deal_fill"] < 1
+
+
+def test_deal_fill_is_told_on_a_row_sharded_mesh_alone(ratings):
+    import jax
+    from predictionio_tpu.obs.metrics import get_registry
+    from predictionio_tpu.ops import als
+    from predictionio_tpu.parallel.mesh import make_mesh
+    one = {}
+    als.als_train(ratings, _cfg(False, iterations=1),
+                  make_mesh(devices=jax.devices()[:1]), telemetry=one)
+    assert "deal_fill" not in one and "route_fill" not in one
+    tel = {}
+    als.als_train(ratings, _cfg(False, iterations=1,
+                                factor_sharding="model"),
+                  _mesh("1x4"), telemetry=tel)
+    fills = als._deal_fills()
+    assert set(fills) == {"user_plan", "item_plan"}
+    assert all(0 < fill <= 1 for fill in fills.values())
+    # the train's own is over both sides' slots together
+    assert min(fills.values()) <= tel["deal_fill"] <= max(fills.values())
+    gauge = dict((labels["side"], value) for labels, value
+                 in get_registry().get("pio_als_deal_fill").samples())
+    assert gauge == {"user": fills["user_plan"], "item": fills["item_plan"]}
+
+
 def _compiled(mesh, cfg, table, counter, gram, groups):
     """The per-chip half-sweep of these operands, compiled as `_run_side`
     dispatches it."""
@@ -260,7 +343,7 @@ def test_each_chip_solves_its_share_and_holds_no_whole_table(
     idx = rng.integers(0, N_ITEMS, (1, B, K)).astype(np.int32)
     mask = np.ones((1, B, K), np.float32)
     place, send, _real, _room = als._route_group(
-        rows, idx, mask, K, rows_v // n, n, n, False)
+        B // n, idx, mask, K, rows_v // n, n, n, False)
     L = send.shape[-1]
     group = (rows, place, rng.integers(1, 6, (1, B, K)).astype(np.float32),
              mask, send)
@@ -284,11 +367,11 @@ def test_each_chip_solves_its_share_and_holds_no_whole_table(
     assert not {d for d in held
                 if d.startswith((f"{B},{K}", f"{B * K},", f"1,{B},{K}"))
                 or d == f"{B * K}"}
-    # one all-to-all of rows; nothing is reduce-scattered and no index
-    # crosses the chips (the row ids of the solved rows do: [.., B])
+    # one all-to-all of rows; nothing is reduce-scattered or gathered: no
+    # index and no solved row crosses the chips
     assert " all-to-all(" in text
     assert "reduce-scatter" not in text
-    assert not re.search(r"s32\[[\d,]*\b%d\]\S* all-gather" % K, text)
+    assert "all-gather" not in text
     # a chip holds its shard of each table and never a whole one
     assert f"{rows_u // n},{RANK}" in shapes
     assert f"{rows_v // n},{RANK}" in shapes
@@ -335,10 +418,10 @@ def test_exchange_bytes_are_the_compiled_programs_collectives(
         assert got.pop("sent") == sent_bytes(want, n) > 0
         assert got == {op: ent["bytes"] for op, ent in want.items()
                        if op != "total"}
-        # what crosses: row ids and solved rows gathered, the rated rows
-        # all-to-all from their owners
-        assert got["all-gather"] > 0 and got["all-to-all"] > 0
-        assert "reduce-scatter" not in got
+        # what crosses: the rated rows all-to-all from their owners, and
+        # the CG counts summed; no solved row and no row id
+        assert got["all-to-all"] > 0
+        assert set(got) <= {"all-to-all", "all-reduce"}
     # the gauge holds the last train's: this module's last is implicit 1x2
     gauge = get_registry().get("pio_als_exchange_bytes")
     assert gauge is not None

@@ -184,6 +184,10 @@ def _routed_gathers(compiled):
 # for these rungs of each side: the [B, K, 256] block every chip gathered
 # of a whole step's slots is gone (off-chip compiles, PR 35)
 PARENT_PEAK = {"user": 14_005_321_216, "item": 12_073_402_368}
+# and of the program that still all-gathered the solved rows and their ids
+# (8e40b8a): without those gathers the compiler schedules the step a little
+# otherwise, within a few MB either way
+GATHERED_PEAK = {"user": 13_214_279_680, "item": 11_896_873_472}
 
 
 @pytest.mark.parametrize("side", ["user", "item"])
@@ -198,6 +202,7 @@ def test_sharded_half_sweep_fits_a_chip_and_holds_no_whole_table(mesh4,
     peak = m.argument_size_in_bytes + m.temp_size_in_bytes
     assert peak + QUARTER_OF_BOTH_PLANS <= HBM
     assert peak < PARENT_PEAK[side]
+    assert peak <= GATHERED_PEAK[side] + ROOM
     n_counter = SHARDED_ITEMS if side == "user" else SHARDED_USERS
     whole = {als.table_rows(n, CHIPS) for n in (SHARDED_USERS,
                                                 SHARDED_ITEMS)}
@@ -225,20 +230,20 @@ def test_sharded_half_sweep_fits_a_chip_and_holds_no_whole_table(mesh4,
     assert found["pio.sweep.gather.place"] == [
         (b // CHIPS * k, 256 if CHIPS * L * 400 > 80e6 else 0)
         for b, k, L in padded]
-    # only the exchanges the algorithm needs: the rated rows all-to-all
-    # from their owners, the solved rows all-gathered, the row ids and the
-    # CG counts summed; nothing is reduce-scattered, no index crosses
+    # only the exchange the algorithm needs: the rated rows all-to-all
+    # from their owners, and the CG counts summed once a program; nothing
+    # is reduce-scattered or gathered: each chip solves rows of its own
+    # shard and scatters them where they lie, so no solved row and no row
+    # id crosses the chips
     ran = executed_collective_stats(compiled)
-    assert set(ran) == {"all-to-all", "all-gather", "all-reduce", "total"}
+    assert set(ran) == {"all-to-all", "all-reduce", "total"}
+    assert "all-gather" not in text
     steps = 2
     # every chip receives CHIPS * L bfloat16 rows a step, at their 200
     # columns (the compiler does not pad them to 256 lanes)
     assert ran["all-to-all"]["bytes"] == steps * sum(
         CHIPS * L * 200 * 2 for _b, _k, L in padded)
-    assert ran["all-gather"]["bytes"] == steps * sum(
-        b * 200 * 4 for b, _k, _L in padded)
-    small = sum(b + 32 for b, _k, _L in padded) * 4 * steps + 64
-    assert ran["all-reduce"]["bytes"] <= small
+    assert ran["all-reduce"] == {"count": 1, "bytes": 8}
 
 
 def test_the_sentinel_checks_the_sharded_tables_in_place(mesh4):

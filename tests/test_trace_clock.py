@@ -185,16 +185,16 @@ def test_the_pallas_solves_say_primal_or_dual_and_their_size(K, scope,
     assert scope in text and kernel in text
 
 
-_EXCHANGE = {"pio.sweep.exchange.indices", "pio.sweep.exchange.rows",
-             "pio.sweep.exchange.solved", "pio.sweep.gather.place"}
+_EXCHANGE = {"pio.sweep.exchange.rows", "pio.sweep.gather.place"}
 
 
 @pytest.mark.parametrize("K,solve_scope", [
     (16, "pio.sweep.solve.primal"), (4, "pio.sweep.solve.dual")])
 def test_the_per_chip_sweep_names_its_exchanges(K, solve_scope):
     """The half-sweep over row-sharded tables: the one-chip stages, the
-    three exchanges that cross the chips beside them, and the placing of
-    the rows a chip received (`pio.sweep.gather` is the owners' gather)."""
+    one exchange that crosses the chips beside them (each chip solves and
+    scatters rows of its own shard), and the placing of the rows a chip
+    received (`pio.sweep.gather` is the owners' gather)."""
     import jax
     import jax.numpy as jnp
     from predictionio_tpu.ops import als
@@ -205,7 +205,7 @@ def test_the_per_chip_sweep_names_its_exchanges(K, solve_scope):
     rows = np.arange(N * B, dtype=np.int32).reshape(N, B)
     mask = np.ones((N, B, K), np.float32)
     place, send, _real, _room = als._route_group(
-        rows, rng.integers(0, 50, (N, B, K)).astype(np.int32), mask, K,
+        B // 4, rng.integers(0, 50, (N, B, K)).astype(np.int32), mask, K,
         52 // 4, 4, 4, False)
     group = (rows, place, rng.uniform(1, 5, (N, B, K)).astype(np.float32),
              mask, send)
