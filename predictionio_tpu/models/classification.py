@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import logging
 from dataclasses import dataclass
-from typing import List, Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -38,10 +38,25 @@ class LabeledPoint:
 
 @dataclass
 class TrainingData(SanityCheck):
-    labeled_points: List[LabeledPoint]
+    """Columnar: `features` [n, F] float32 and `labels` [n] float64, one
+    row a user, as the algorithms take them (no Python object a row)."""
+    features: np.ndarray
+    labels: np.ndarray
+
+    @classmethod
+    def from_points(cls, points: Sequence[LabeledPoint]) -> "TrainingData":
+        return cls(np.array([p.features for p in points],
+                            np.float32).reshape(len(points), -1),
+                   np.array([p.label for p in points], np.float64))
+
+    @property
+    def labeled_points(self) -> List[LabeledPoint]:
+        return [LabeledPoint(label=float(lab), features=tuple(
+                    float(v) for v in row))
+                for row, lab in zip(self.features, self.labels)]
 
     def sanity_check(self):
-        if not self.labeled_points:
+        if not len(self.labels):
             raise ValueError("labeled_points is empty; check the data source")
 
 
@@ -88,34 +103,34 @@ class ClassificationDataSource(DataSource):
     def __init__(self, params=None):
         super().__init__(params or DataSourceParams())
 
-    def _read_points(self) -> List[LabeledPoint]:
+    def _read_columns(self) -> TrainingData:
         props = PEventStore.aggregate_properties(
             app_name=self.params.app_name,
             channel_name=self.params.channel_name, entity_type="user",
             required=["plan", "attr0", "attr1", "attr2"])
-        points = []
-        for entity_id, pm in props.items():
+        X = np.empty((len(props), 3), np.float32)
+        y = np.empty(len(props), np.float64)
+        for row, (entity_id, pm) in enumerate(props.items()):
             try:
-                points.append(LabeledPoint(
-                    label=pm.get("plan", float),
-                    features=(pm.get("attr0", float), pm.get("attr1", float),
-                              pm.get("attr2", float))))
+                y[row] = pm.get("plan", float)
+                X[row] = (pm.get("attr0", float), pm.get("attr1", float),
+                          pm.get("attr2", float))
             except Exception as e:
                 logger.error("Cannot convert %s to LabeledPoint: %s",
                              entity_id, e)
                 raise
-        return points
+        return TrainingData(X, y)
 
     def read_training(self) -> TrainingData:
-        return TrainingData(self._read_points())
+        return self._read_columns()
 
     def read_eval(self):
         if not self.params.eval_k:
             return []
-        points = self._read_points()
+        points = self._read_columns().labeled_points
         return split_data(
             self.params.eval_k, points, None,
-            training_data_creator=TrainingData,
+            training_data_creator=TrainingData.from_points,
             query_creator=lambda p: Query(*p.features),
             actual_creator=lambda p: ActualResult(p.label))
 
@@ -139,10 +154,8 @@ class NaiveBayesAlgorithm(P2LAlgorithm):
         super().__init__(params or NaiveBayesAlgorithmParams())
 
     def train(self, td: TrainingData) -> MultinomialNBModel:
-        X = np.array([p.features for p in td.labeled_points],
-                     dtype=np.float32)
-        y = np.array([p.label for p in td.labeled_points], dtype=np.float64)
-        return multinomial_nb_train(X, y, lam=self.params.lam)
+        return multinomial_nb_train(td.features, td.labels,
+                                    lam=self.params.lam)
 
     def predict(self, model: MultinomialNBModel, query: Query
                 ) -> PredictedResult:
@@ -183,12 +196,10 @@ class RandomForestAlgorithm(P2LAlgorithm):
         super().__init__(params or RandomForestAlgorithmParams())
 
     def train(self, td: TrainingData) -> ForestModel:
-        X = np.array([p.features for p in td.labeled_points],
-                     dtype=np.float32)
-        y = np.array([p.label for p in td.labeled_points], dtype=np.float64)
         p = self.params
         return forest_train(
-            X, y, num_classes=p.num_classes, num_trees=p.num_trees,
+            td.features, td.labels, num_classes=p.num_classes,
+            num_trees=p.num_trees,
             feature_subset_strategy=p.feature_subset_strategy,
             impurity=p.impurity, max_depth=p.max_depth,
             max_bins=p.max_bins, seed=p.seed)

@@ -110,6 +110,12 @@ ALS_UPLOAD = "als_upload"
 ALS_GRAM = "als_gram"
 ALS_SWEEP_EXCHANGE = "als_sweep_exchange"
 ALS_TRAIN = "als_train"
+#: the forest's level programs (ops/forest): the blocked class histogram,
+#: and the split search with the routing; the draws and the rest of a
+#: forest's set-up and growth under the phase
+FOREST_HIST = "forest_hist"
+FOREST_SPLIT = "forest_split"
+FOREST_TRAIN = "forest_train"
 
 _label_ctx: contextvars.ContextVar = contextvars.ContextVar(
     "pio_exec_label", default=None)
@@ -135,6 +141,8 @@ _g_bytes = None
 _g_cg_ratio = None
 _g_exchange = None
 _g_deal_fill = None
+_g_forest_split = None
+_g_forest_live = None
 _c_dispatch_s = None
 _c_device_s = None
 _c_device_syncs = None
@@ -147,7 +155,7 @@ def install(registry=None):
     global _installed, _c_stage_s, _c_requests, _c_hits, _c_misses, \
         _g_flops, _g_bytes, _c_dispatch_s, _c_device_s, \
         _c_device_syncs, _g_occupancy, _g_tenant_occ, _g_cg_ratio, \
-        _g_exchange, _g_deal_fill
+        _g_exchange, _g_deal_fill, _g_forest_split, _g_forest_live
     with _lock:
         if _installed:
             return
@@ -200,6 +208,16 @@ def install(registry=None):
             "so that each chip solves rows of its own shard (the plan's "
             "padding and the deal's), by side; absent where tables are "
             "not sharded", labelnames=("side",))
+        _g_forest_split = reg.gauge(
+            "pio_forest_nodes_split_ratio",
+            "nodes that split over the nodes the depth allows (trees x "
+            "(2^max_depth - 1)) in the last forest grown")
+        _g_forest_live = reg.gauge(
+            "pio_forest_live_pairs_ratio",
+            "live (tree, example) pairs over trees x examples, averaged "
+            "over the split levels of the last forest grown: the share of "
+            "the histogram's weighted work that is not bootstrap zeros or "
+            "examples of frozen leaves")
         reg.gauge_func(
             "pio_hbm_table_bytes",
             "Device bytes held by each named residency slot in "
@@ -753,6 +771,16 @@ def record_deal_fill(side: str, fill: float) -> None:
     if not _installed:
         install()
     _g_deal_fill.labels(side=side).set(fill)
+
+
+def record_forest_levels(nodes_split: float, live_pairs: float) -> None:
+    """Bank what the last forest's levels counted on the device
+    (`ops/forest.forest_grow`): both as shares of what the depth and the
+    data allow."""
+    if not _installed:
+        install()
+    _g_forest_split.set(nodes_split)
+    _g_forest_live.set(live_pairs)
 
 
 def analyze_jit(label: str, fn, *args, **kwargs) -> Optional[dict]:

@@ -322,6 +322,59 @@ def test_the_composed_mask_kernel_names_its_stages():
         "pio.serve.score", "pio.serve.topk", "pio.serve.pack"}
 
 
+def test_the_forest_programs_name_their_stages():
+    """The random forest's level programs: `pio.forest.hist` in the
+    blocked histogram (its module `jit__forest_hist`), `pio.forest.split`
+    and `pio.forest.route` in the split program (`jit__forest_split`); a
+    last level routes nothing."""
+    import jax
+    import jax.numpy as jnp
+    from predictionio_tpu.ops import forest as FO
+    T, F, B, C, n = 2, 3, 4, 2, 256
+    xb, y = jnp.zeros((F, n), jnp.int32), jnp.zeros((n,), jnp.int32)
+    w, node = jnp.ones((T, n)), jnp.zeros((T, n), jnp.int32)
+    live = jnp.ones((T, n), bool)
+    hist = FO._forest_hist.trace(xb, y, w, node, live, stride=2, count=1,
+                                 num_classes=C, max_bins=B, block=128)
+    assert set(re.findall(r"pio\.[a-z_.]+", hist.lower().as_text(
+        debug_info=True))) == {"pio.forest.hist"}
+    assert hist.fun_name == "_forest_hist"
+    key = jax.random.PRNGKey(0)
+    for last, scopes in ((False, {"pio.forest.split", "pio.forest.route"}),
+                         (True, {"pio.forest.split"})):
+        text = FO._forest_split.trace(
+            jnp.zeros((T, 1, F, B, C)), jnp.zeros((T, 1, F, B, C)),
+            jnp.ones((T, 1), bool), xb, node, live, jnp.zeros((F, B - 1)),
+            key, depth=1, impurity="gini", subset_k=2, block=128,
+            last=last).lower().as_text(debug_info=True)
+        assert set(re.findall(r"pio\.[a-z_.]+", text)) == scopes
+
+
+def test_growing_a_forest_puts_each_level_under_a_host_region(monkeypatch):
+    """`pio.forest.bin` and `pio.forest.upload` (with the set-up account's
+    `pio.setup.upload` of `forest_bins` and `forest_state`) once, then
+    `pio.forest.level` (attr `depth`) around each level's two programs and
+    `pio.forest.fetch` around the trees' fetch."""
+    from predictionio_tpu.ops import forest as FO
+    seen = []
+    real = FO.TRACER.region
+
+    def region(name, **attrs):
+        seen.append((name, attrs.get("depth", attrs.get("table"))))
+        return real(name, **attrs)
+
+    monkeypatch.setattr(FO.TRACER, "region", region)
+    rng = np.random.default_rng(0)
+    X = rng.normal(size=(300, 4)).astype(np.float32)
+    FO.forest_train(X, (X[:, 0] > 0).astype(int), num_classes=2,
+                    num_trees=2, max_depth=3)
+    assert seen == [("forest.bin", None), ("forest.upload", None),
+                    ("setup.upload", "forest_bins"),
+                    ("setup.upload", "forest_state"),
+                    ("forest.level", 0), ("forest.level", 1),
+                    ("forest.level", 2), ("forest.fetch", None)]
+
+
 @pytest.mark.parametrize("name", ["filter.seen_read",
                                   "filter.constraint_read", "filter.lists"])
 def test_the_filter_spans_are_regions_of_the_one_tracer(name, monkeypatch):
