@@ -23,8 +23,11 @@ with static shapes at every level:
     blocks of ``HIST_BLOCK`` rows and accumulates the class histogram of
     every (tree, node, feature, bin) cell in float32: per block one bf16
     MXU product of the nodes' weighted one-hot [T * nodes, block] with the
-    bins' and classes' one-hot [F * B * C, block] (both exact in bf16). No
-    one-hot of the whole data and no [T, n, nodes] array is ever made.
+    bins' and classes' one-hot [C * F * B, block] (both exact in bf16),
+    classes-major so that the class axis, two or three long, is not the
+    one the TPU tiles and pads: the one-hot is made in the operand's own
+    layout. No one-hot of the whole data and no [T, n, nodes] array is
+    ever made.
     Below the root only the left children are counted: a right child's
     histogram is its parent's less its sibling's, exact on integer counts
     under 2^24;
@@ -290,7 +293,11 @@ def _forest_hist(xb, y, w, node, live, *, stride: int, count: int,
     stride * j, j < count: the bootstrap weight of every live example of
     that node, by feature, bin and class. Blocks of examples in a loop;
     each block one MXU product of bf16 one-hots (weights are small
-    integers, exact in bf16), accumulated in float32."""
+    integers, exact in bf16), accumulated in float32 as [T * count,
+    C * F * B] and reordered once after the loop. The bins' and classes'
+    one-hot is made classes-major, [C, F, B, block]: with the few classes
+    leading, the (F, B) rows fill the TPU's tiles, so it is the product's
+    operand as it lies (a bitcast, not a relayout in every block)."""
     F, n_pad = xb.shape
     T = w.shape[0]
     B, C = max_bins, num_classes
@@ -305,19 +312,19 @@ def _forest_hist(xb, y, w, node, live, *, stride: int, count: int,
             a = jnp.where(slot[:, None, :] == ids[None, :, None],
                           wb[:, None, :], 0).reshape(T * count, block)
             xbb, yb = _block(xb, i, block), _block(y, i, block)
-            bins = ((xbb[:, None, None, :]
-                     == jnp.arange(B, dtype=jnp.int32)[None, :, None, None])
-                    & (yb[None, None, None, :]
-                       == jnp.arange(C, dtype=jnp.int32)[None, None, :,
+            bins = ((yb[None, None, None, :]
+                     == jnp.arange(C, dtype=jnp.int32)[:, None, None, None])
+                    & (xbb[None, :, None, :]
+                       == jnp.arange(B, dtype=jnp.int32)[None, None, :,
                                                           None]))
-            bins = bins.astype(jnp.bfloat16).reshape(F * B * C, block)
+            bins = bins.astype(jnp.bfloat16).reshape(C * F * B, block)
             return acc + jax.lax.dot_general(
                 a, bins, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
     acc = jax.lax.fori_loop(0, n_pad // block, step,
-                            jnp.zeros((T * count, F * B * C), jnp.float32))
-    return acc.reshape(T, count, F, B, C)
+                            jnp.zeros((T * count, C * F * B), jnp.float32))
+    return acc.reshape(T, count, C, F, B).transpose(0, 1, 3, 4, 2)
 
 
 def _subset_mask(k_f, depth: int, num_trees: int, nd: int, F: int, k: int):

@@ -3,10 +3,11 @@ benchmark holds it to (benchmark/references/forest.py), on the CPU at small
 sizes: the same draws, exact class counts at every node, the same splits
 (ties within 1e-6 of a node's impurity adopted), the same majority
 classes and the same predictions; a reference whose histograms add up in
-bfloat16 fails. At the end, the grow programs compiled off the chip at the
-published shapes (10.5M x 28, 64 trees, depth 8) for a described v5e,
-against the memory the compiler allows. Nothing runs there: bytes, not
-times."""
+bfloat16 fails; one level's histogram against np.add.at. At the end, the
+grow programs compiled off the chip at the published shapes (10.5M x 28,
+64 trees, depth 8) for a described v5e, against the memory the compiler
+allows, and the histogram's one-hot checked to reach its product without
+a relayout. Nothing runs there: bytes and ops, not times."""
 
 import math
 import os
@@ -220,6 +221,40 @@ def test_many_blocks_and_a_stump(monkeypatch):
     assert (stump.node_counts[:, 0] == one.node_counts[:, 0]).all()
 
 
+@pytest.mark.parametrize("classes,bins,stride,count", [
+    (2, 32, 1, 1), (2, 32, 2, 4), (3, 7, 2, 2)])
+def test_the_histogram_is_the_sum_of_live_weights(classes, bins, stride,
+                                                  count):
+    """`_forest_hist` over several blocks, with dead examples, nodes outside
+    the counted slots and a padded tail, against np.add.at of the live
+    weights by tree, node slot, feature, bin and class."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(100 * classes + bins + stride + count)
+    trees, feats, block, n = 3, 5, 128, 450
+    n_pad = 4 * block
+    xb = np.zeros((feats, n_pad), np.int32)
+    xb[:, :n] = rng.integers(0, bins, (feats, n))
+    y = np.zeros(n_pad, np.int32)
+    y[:n] = rng.integers(0, classes, n)
+    w = np.zeros((trees, n_pad), np.float32)
+    w[:, :n] = rng.poisson(1.0, (trees, n))
+    node = rng.integers(0, 2 * stride * count, (trees, n_pad)).astype(
+        np.int32)
+    live = (w > 0) & (rng.random((trees, n_pad)) < 0.8)
+    got = np.asarray(FO._forest_hist(
+        jnp.asarray(xb), jnp.asarray(y), jnp.asarray(w), jnp.asarray(node),
+        jnp.asarray(live), stride=stride, count=count, num_classes=classes,
+        max_bins=bins, block=block))
+    want = np.zeros((trees, count, feats, bins, classes), np.float32)
+    t, i = np.nonzero(live & (node % stride == 0) & (node // stride < count))
+    for f in range(feats):
+        np.add.at(want, (t, node[t, i] // stride, f, xb[f, i], y[i]),
+                  w[t, i])
+    assert got.shape == want.shape and got.dtype == np.float32
+    assert np.array_equal(got, want)
+    assert 0 < want.sum() < w.sum() * feats
+
+
 # -- the grow programs at the published shapes, off the chip ---------------
 
 HBM = 15.75 * 2**30    # what the compiler itself allows of the chip's 16 GiB
@@ -286,6 +321,23 @@ def test_the_histogram_program_fits_at_the_published_shapes(sds, depth):
     # the [T, nodes, F, B, C] float32 histogram is the program's output
     out = compiled.memory_analysis().output_size_in_bytes
     assert out >= T * max(1, nd // 2) * F * B * C * 4
+
+
+def test_the_histograms_one_hot_reaches_the_product_as_it_lies(sds):
+    """At depth 7 and the published shapes the bins' and classes' one-hot
+    of a block, [C * F * B, block] = [1792, 4096], is no reshape, copy or
+    transpose's result: made classes-major, it is the product's operand
+    by a bitcast, and no block pays a relayout of it."""
+    block, a = _resident(sds)
+    text = FO._forest_hist.lower(
+        a["xb"], a["y"], a["w"], a["node"], a["live"], stride=2, count=64,
+        num_classes=C, max_bins=B, block=block).compile().as_text()
+    rows = C * F * B
+    assert re.search(rf"\[{rows},{block}\]", text)
+    moved = re.findall(
+        rf"= (?:pred|bf16)\[{rows},{block}\]\S* (reshape|copy|transpose)\(",
+        text)
+    assert not moved
 
 
 @pytest.mark.parametrize("depth", [0, 6, 7])
